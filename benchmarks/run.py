@@ -95,6 +95,8 @@ def main(argv=None) -> None:
                          "(fig8_serve: host vs mega tokens/sec + "
                          "launches-per-tick) to PATH")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
     figs = args.fig or FIGS
     backends = (("jnp", "pallas") if args.backend == "both"
                 else (args.backend,))

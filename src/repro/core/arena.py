@@ -73,6 +73,10 @@ QUEUE_FAMILIES = ("ring", "va", "vl")
 # every deeper attempt (walks are bounded by num_shards - 1 anyway).
 TELE_WALK_BINS = 8
 
+# Words per SMEM window of the blocked lowering (DESIGN.md §8): the
+# tiling of a 1-D int32 HBM array, so every window DMA is tile-aligned.
+WINDOW_WORDS = 1024
+
 
 class Arena(NamedTuple):
     """The flat device-resident allocator state (see module docstring)."""
@@ -84,17 +88,14 @@ class Arena(NamedTuple):
 class Region:
     """One named window of ``mem``: ``[offset, offset + words)``.
 
-    ``blocking`` records how the region-blocked compiled lowering
-    (kernels/alloc_txn_blocked.py) stages this region per grid step:
+    ``blocking`` records how the blocked compiled lowering
+    (kernels/alloc_txn_blocked.py) reaches this region in an alloc or
+    free transaction:
 
-    - ``"row"``       one (1, shape[1]) row per size-class grid step,
-                      selected by the BlockSpec index map;
-    - ``"resident"``  the whole region as one VMEM block with a
-                      constant index map (fetched once, revisited —
-                      Pallas keeps an unchanged block on-chip);
-    - ``"hbm"``       the region stays in HBM (``memory_space=ANY``);
-                      the kernel DMAs only the touched rows/words
-                      through VMEM scratch (heap segments, bitmap rows);
+    - ``"window"``    the region stays in HBM (``memory_space=ANY``)
+                      and the kernel reads and writes single words
+                      through one ``WINDOW_WORDS``-word SMEM block,
+                      DMA'd in and out as accesses move;
     - ``"untouched"`` the transaction can never write it, so the
                       blocked lowering does not even pass it to the
                       kernel.
@@ -102,7 +103,7 @@ class Region:
     name: str
     offset: int
     shape: Tuple[int, ...]
-    blocking: str = "resident"
+    blocking: str = "window"
 
     @property
     def words(self) -> int:
@@ -114,12 +115,10 @@ class Region:
 
     @property
     def block_shape(self) -> Optional[Tuple[int, ...]]:
-        """VMEM block staged per grid step by the blocked lowering
-        (None when the region never enters VMEM wholesale)."""
-        if self.blocking == "row":
-            return (1,) + self.shape[1:]
-        if self.blocking == "resident":
-            return self.shape
+        """SMEM block the blocked lowering stages this region through
+        (None when the region bypasses the kernel)."""
+        if self.blocking == "window":
+            return (WINDOW_WORDS,)
         return None
 
 
@@ -305,9 +304,9 @@ def layout(cfg: HeapConfig, kind: str, family: str) -> ArenaLayout:
     # Region.blocking and DESIGN.md §8).  Transactions never write the
     # heap for ring-family variants (segment traffic is what touches
     # it), and never write the pool for the plain page variant.
-    heap_blk = "untouched" if family == "ring" else "hbm"
+    heap_blk = "untouched" if family == "ring" else "window"
     pool_blk = ("untouched" if (family == "ring" and kind == "page")
-                else "resident")
+                else "window")
     regions = [Region("heap", 0, (cfg.total_words,), heap_blk)]
 
     def add(name, shape, blocking):
@@ -315,13 +314,14 @@ def layout(cfg: HeapConfig, kind: str, family: str) -> ArenaLayout:
 
     add("pool_store", (1, cfg.num_chunks), pool_blk)
     if family == "ring":
-        add("queue_store", (C, cap), "row")
+        add("queue_store", (C, cap), "window")
     else:
-        add("directory", (C, max_segs), "row")
+        add("directory", (C, max_segs), "window")
     if kind == "chunk":
-        add("bitmap", (cfg.num_chunks, cfg.bitmap_words_per_chunk), "hbm")
-        add("free_count", (cfg.num_chunks,), "resident")
-        add("chunk_class", (cfg.num_chunks,), "resident")
+        add("bitmap", (cfg.num_chunks, cfg.bitmap_words_per_chunk),
+            "window")
+        add("free_count", (cfg.num_chunks,), "window")
+        add("chunk_class", (cfg.num_chunks,), "window")
 
     return ArenaLayout(cfg=cfg, kind=kind, family=family,
                        regions=tuple(regions), num_classes=C,
@@ -420,7 +420,7 @@ def with_heap(lay: ArenaLayout, arena: Arena, heap) -> Arena:
 #
 # The blocked lowering never hands the kernel the whole ``mem`` image as
 # one ref; the wrapper splits it into its regions (static slices — XLA
-# fuses them away) so each region can ride its own BlockSpec, and joins
+# fuses them away) so each region can be its own HBM operand, and joins
 # the touched regions back afterwards.  Regions the transaction cannot
 # write (Region.blocking == "untouched") bypass the kernel entirely and
 # are reused verbatim in the join.

@@ -27,8 +27,8 @@ the first subsystem where the allocator rewrites its own heap:
              from the surviving live chunks.  An empty plan degenerates
              to exactly a ``compact``-style rebuild.  This math is the
              jnp oracle AND the body of the whole-lowering kernel
-             (kernels/defrag_txn.py); the region-blocked lowering
-             re-expresses it per class under the §8 discipline, and a
+             (kernels/defrag_txn.py); the blocked lowering
+             re-expresses it as §8 scalar-core code, and a
              wave is ONE ``pallas_call`` under both (DESIGN.md §10,
              tests/test_defrag.py).
 
@@ -328,8 +328,8 @@ def insert_rebuild_math(cfg: HeapConfig, kind: str, family: str, mem,
         rank, ids, unbound)
     ctx = queues.AllocCtx(heap=ctx.heap, pool=pool)
 
-    # class-major queue rebuild (matches the blocked lowering's grid
-    # order step for step — every pool pop happens in class order)
+    # class-major queue rebuild (matches the blocked lowering's class
+    # loop step for step — every pool pop happens in class order)
     fam = queues.FAMILIES[family]
     if family == "ring":
         q = queues.ring_init(C, lay.queue_capacity)

@@ -70,8 +70,7 @@ class Ouroboros:
     ``backend`` picks the transaction implementation (jnp reference
     path vs fused Pallas kernels) and — for the Pallas backend —
     ``lowering`` the kernel shape: ``"whole"`` (full-arena refs),
-    ``"blocked"`` (the region-blocked compiled lowering, DESIGN.md
-    §8), or ``"auto"`` (kernels/ops picks per platform /
+    ``"blocked"`` (the compiled lowering, DESIGN.md §8), or ``"auto"`` (kernels/ops picks per platform /
     REPRO_ALLOC_LOWERING).  Both lowerings are bit-identical to the
     jnp oracle and to each other (tests/test_alloc_txn_parity).
 

@@ -30,7 +30,7 @@ offsets: ``global = shard * shard_words + local``.
 The replay order is the correctness contract: the jnp oracle
 (``transactions.sharded_alloc_math``) literally replays the wavefront
 through the per-shard single-arena math in that order, and both Pallas
-lowerings grid the SAME schedule into one ``pallas_call``
+lowerings run the SAME schedule inside one ``pallas_call``
 (kernels/alloc_txn.sharded_* and alloc_txn_blocked.sharded_*), so all
 implementations are bit-identical to a serial single-shard oracle
 replay (tests/test_alloc_txn_parity.py).

@@ -26,12 +26,12 @@ Two execution paths share one body:
              ``whole``    the kernel body IS this module's math over
                           full ``mem``/``ctl`` refs (kernels/alloc_txn)
                           — parity with the oracle is structural;
-             ``blocked``  the region-blocked compiled lowering
-                          (kernels/alloc_txn_blocked, DESIGN.md §8):
-                          the same math split into per-region,
-                          per-class bodies driven by the ArenaLayout
-                          region table — parity is enforced word for
-                          word by the three-way differential matrix.
+             ``blocked``  the compiled lowering (kernels/
+                          alloc_txn_blocked, DESIGN.md §8): the same
+                          math as scalar-core per-class bodies over
+                          SMEM windows of the arena regions — parity
+                          is enforced word for word by the three-way
+                          differential matrix.
 
              tests/test_alloc_txn_parity.py holds all implementations
              bit-identical and asserts the one-kernel property on the
@@ -168,8 +168,8 @@ def alloc(cfg: HeapConfig, kind: str, family: str, state: arena.Arena,
     """One bulk allocation transaction.  Returns (arena', word_offsets);
     offset −1 marks a failed lane (over-large size / exhausted
     inventory), matching the GPU original's nullptr.  ``lowering``
-    picks the Pallas kernel shape (whole-arena refs vs the
-    region-blocked compiled lowering — kernels/ops.resolve_lowering).
+    picks the Pallas kernel shape (whole-arena refs vs the blocked
+    compiled lowering — kernels/ops.resolve_lowering).
 
     The dispatcher is the layer below the ``Ouroboros`` facade — same
     semantics, explicit (kind, family):

@@ -1,57 +1,45 @@
-"""Region-blocked compiled lowering for the fused arena transactions.
+"""Blocked compiled lowering for the fused arena transactions.
 
 ``kernels/alloc_txn.arena_*_txn`` (the ``whole`` lowering) hands the
-kernel the entire ``mem`` word image as one ref — correct, and ideal
-for interpret mode, but it only lowers to a real compiled TPU kernel
-while the whole arena fits VMEM.  This module is the serving-scale
-story: the *same* transactions as ONE ``pallas_call`` whose refs are
-driven by the :class:`~repro.core.arena.ArenaLayout` region table
-(DESIGN.md §8):
+kernel the entire ``mem`` image and runs the jnp transaction math on
+it.  That is exact in interpret mode, but it never lowers for a TPU:
+Mosaic has no ``cumsum``, and a serving-size arena does not fit VMEM.
+This module is the lowering the chip runs (``auto`` picks it on TPU;
+DESIGN.md §8): the same transactions, each still ONE ``pallas_call``,
+written as scalar-core code.
 
-- the grid iterates the **size classes**; step ``c`` stages only class
-  ``c``'s queue-ring row (or segment-directory row) through VMEM via a
-  ``BlockSpec`` index map — never the whole queue region;
-- the **control block rides as scalar prefetch** (its counters feed
-  loop bounds and DMA addresses) and is accumulated across grid steps
-  in a VMEM-resident output block;
-- small metadata regions (chunk pool ring, free counts, chunk→class
-  bindings) are **VMEM-resident** blocks with constant index maps —
-  fetched once, revisited in place;
-- the **heap** and the **chunk bitmaps** never enter VMEM wholesale:
-  they stay in HBM (``memory_space=ANY``) and the kernel reads/writes
-  only the touched words — segment slots, next pointers, one bitmap
-  row per claimed chunk — through dynamic loads/stores;
-- regions a transaction cannot write (``Region.blocking ==
-  "untouched"``) bypass the kernel entirely.
+- Every word the kernel branches on or uses as an address is read from
+  SMEM.  The control block, the lane vectors and small per-transaction
+  scratch are whole SMEM operands.
+- Every region the transaction touches stays in HBM
+  (``memory_space=ANY``).  The kernel reaches it through a *window*:
+  one 1024-word SMEM block per region, fetched and written back with
+  ``pltpu.make_async_copy`` whenever an access leaves the staged block.
+  1024 words is the tiling of a 1-D int32 HBM array, so every DMA is
+  tile-aligned; the wrapper pads each region to a whole number of
+  blocks.  Regions a transaction can never write
+  (``Region.blocking == "untouched"``) bypass the kernel.
+- State-free lane prep (size class, validity) runs in XLA inside the
+  same jit; the kernel sees one int32 per lane: its class, or -1.
+- The size classes are walked in order by an in-kernel loop.  Class
+  step ``c`` performs the class-``c`` slice of the transaction: the
+  lanes of class ``c`` in lane order (their in-class rank is a running
+  counter), that class's queue traffic and, for chunk kinds, the whole
+  class-``c`` chunk-drain loop.  Cross-class state (the chunk pool,
+  heap pointer words) is mutated in class-major order, which is the
+  oracle's flattened scatter order.
 
-The transaction math is the per-class / per-region decomposition of
-``core/transactions.alloc_math``/``free_math``: every body below
-mirrors one oracle path (``page_alloc``/``chunk_alloc`` over
-``queues``) at row/scalar granularity, and the differential harness
-(tests/test_alloc_txn_parity.py) holds all three implementations —
-jnp oracle, whole lowering, blocked lowering — bit-identical on
-randomized traces, word for word across the arena.
+Every body below mirrors one oracle path (``page_alloc`` /
+``chunk_alloc`` over ``queues``), and the differential harness
+(tests/test_alloc_txn_parity.py) holds the jnp oracle, the whole
+lowering and this lowering word-identical on randomized traces.
+Interpret mode runs the same DMAs and SMEM windows as the chip, but it
+accepts kernels that Mosaic refuses; tests/test_tpu_compile.py compiles
+these kernels for a described v5e chip to catch that.
 
-Predication convention: Pallas has no masked scatter, so conditional
-single-word effects are read-modify-writes at a safe address —
-``addr = where(cond, addr, 0)`` then ``store(where(cond, new, old))``
-— which is exactly a no-op when ``cond`` is false.  Grid steps execute
-sequentially, so read-after-write across steps (pool counters, pool
-ring words, heap pointers) is well-defined; the cross-class orders
-below (class-major pool pops/pushes) replicate the oracle's flattened
-scatter orders.
-
-Mosaic portability note: every HBM(ANY)-ref access goes through the
-``_ld``/``_st``/``_vec_ld``/``_vec_st_if`` vocabulary below, which
-interpret mode executes as direct dynamic loads/stores.  A compiled
-Mosaic build that insists on explicit DMA for ANY-space refs needs
-exactly these four helpers rewritten over ``pltpu.make_async_copy``
-scratch staging — the kernel bodies never touch an HBM ref directly,
-so that swap is local and the word-level access pattern (the §8
-contract) is already the DMA shape.  Validating the blocked lowering
-on real TPU silicon is the ROADMAP's named next step; everything
-CI-visible runs it in interpret mode, which pins the semantics the
-compiled build must reproduce.
+The sharded allocator (DESIGN.md §9) reuses every body unchanged: the
+kernel loops (attempt, shard, class), and each region is one flat
+array of per-shard slabs addressed through a per-shard base.
 """
 from __future__ import annotations
 
@@ -64,410 +52,408 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import arena
 from repro.core.heap import size_to_class_device
-from repro.kernels.alloc_txn import _iota, _member_rank
 
 NULL = -1
+BLOCK = arena.WINDOW_WORDS   # words per SMEM window over an HBM region
+
+
+def _i32(x):
+    return jnp.asarray(x, jnp.int32)
+
+
+def _loop(lo, hi, body, init=0):
+    return jax.lax.fori_loop(_i32(lo), _i32(hi), body, init)
 
 
 # --------------------------------------------------------------------------
-# scalar / row staging helpers (the DMA vocabulary of the blocked kernels)
+# word access: SMEM operands and SMEM-cached windows over HBM regions
 # --------------------------------------------------------------------------
 
-class _ShardView:
-    """A flat HBM(ANY) ref addressed at a per-shard base offset.
-
-    The sharded wrapper (``_txn_call_sharded``) keeps hbm regions as
-    ONE flat (S · words) ref shared by every grid step and hands the
-    per-class bodies a ``_ShardView(ref, s · words)`` instead; the
-    helpers below unwrap it by adding ``base`` to every address, so the
-    bodies stay byte-for-byte identical between the single-arena and
-    the sharded blocked lowering."""
-    __slots__ = ("ref", "base")
-
-    def __init__(self, ref, base):
-        self.ref = ref
-        self.base = base
+def _dma(src, dst, sem):
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
 
 
-def _at(ref, i):
-    """Resolve (ref, index) through an optional :class:`_ShardView`."""
-    if isinstance(ref, _ShardView):
-        return ref.ref, ref.base + i
-    return ref, i
+class _Window:
+    """One HBM region seen through a 1024-word SMEM block.  ``tags``
+    holds (staged block start or -1, dirty flag) at ``2·slot``."""
+    __slots__ = ("ref", "buf", "tags", "slot", "sem", "size")
+
+    def __init__(self, ref, buf, tags, slot, sem):
+        self.ref, self.buf, self.tags = ref, buf, tags
+        self.slot, self.sem = slot, sem
+        self.size = ref.shape[0]
+
+    def _blk(self, start):
+        return self.ref.at[pl.ds(pl.multiple_of(start, BLOCK), BLOCK)]
+
+    def reset(self):
+        self.tags[2 * self.slot] = _i32(-1)
+        self.tags[2 * self.slot + 1] = _i32(0)
+
+    def flush(self):
+        @pl.when(self.tags[2 * self.slot + 1] != 0)
+        def _():
+            _dma(self.buf, self._blk(self.tags[2 * self.slot]), self.sem)
+            self.tags[2 * self.slot + 1] = _i32(0)
+
+    def stage(self, a):
+        """Make word ``a`` resident and return its offset in ``buf``.
+        Addresses are clamped into the region, so no DMA can leave it."""
+        a = jnp.clip(a, 0, self.size - 1)
+        start = (a // BLOCK) * BLOCK
+
+        @pl.when(self.tags[2 * self.slot] != start)
+        def _():
+            self.flush()
+            _dma(self._blk(start), self.buf, self.sem)
+            self.tags[2 * self.slot] = start
+
+        return a - start
 
 
-def _ld(ref, i):
-    """Dynamic scalar load from a flat ref."""
-    ref, i = _at(ref, i)
-    return pl.load(ref, (pl.ds(i, 1),))[0]
+class _View:
+    """A word-addressed operand at a base offset: an SMEM ref
+    (``win is None``) or an HBM region window.  The sharded kernels
+    address shard ``s``'s slab with ``base = s · slab_words``."""
+    __slots__ = ("ref", "base", "win")
+
+    def __init__(self, ref=None, base=0, win=None):
+        self.ref, self.base, self.win = ref, base, win
 
 
-def _st(ref, i, v):
-    """Dynamic scalar store to a flat ref."""
-    ref, i = _at(ref, i)
-    pl.store(ref, (pl.ds(i, 1),),
-             jnp.reshape(v, (1,)).astype(ref.dtype))
+def _ld(v, i):
+    a = v.base + i
+    if v.win is None:
+        return v.ref[a]
+    return v.win.buf[v.win.stage(a)]
 
 
-def _ld_if(ref, i, cond, fill=NULL):
-    """Predicated scalar load: ``ref[i] if cond else fill`` (reads a
-    safe address when masked, mirroring the oracle's fill-gather)."""
-    ref, a = _at(ref, jnp.where(cond, i, 0))
-    v = pl.load(ref, (pl.ds(a, 1),))[0]
-    return jnp.where(cond, v, fill)
+def _st(v, i, x):
+    a = v.base + i
+    x = _i32(x)
+    if v.win is None:
+        v.ref[a] = x
+        return
+    w = v.win
+    w.buf[w.stage(a)] = x
+    w.tags[2 * w.slot + 1] = _i32(1)
 
 
-def _st_if(ref, i, v, cond):
-    """Predicated scalar store as a safe-address read-modify-write
-    (the in-kernel form of the oracle's ``.set(..., mode="drop")``)."""
-    ref, a = _at(ref, jnp.where(cond, i, 0))
-    old = pl.load(ref, (pl.ds(a, 1),))
-    pl.store(ref, (pl.ds(a, 1),),
-             jnp.where(cond, jnp.reshape(v, (1,)).astype(old.dtype), old))
+def _ld_if(v, i, cond, fill=NULL):
+    """``v[i] if cond else fill``; a masked lane touches no memory."""
+    return jax.lax.cond(cond, lambda: _i32(_ld(v, i)),
+                        lambda: _i32(fill))
 
 
-def _row_ld(ref, j):
-    """Dynamic scalar load from a (1, w) row block."""
-    return pl.load(ref, (pl.ds(0, 1), pl.ds(j, 1)))[0, 0]
+def _st_if(v, i, x, cond):
+    @pl.when(cond)
+    def _():
+        _st(v, i, x)
 
 
-def _row_st_if(ref, j, v, cond):
-    a = jnp.where(cond, j, 0)
-    old = pl.load(ref, (pl.ds(0, 1), pl.ds(a, 1)))
-    pl.store(ref, (pl.ds(0, 1), pl.ds(a, 1)),
-             jnp.where(cond, jnp.reshape(v, (1, 1)).astype(old.dtype),
-                       old))
+def _fill(v, start, words, x):
+    """``v[start:start + words] = x``, one word at a time."""
+    def put(j, _):
+        _st(v, start + j, x)
+        return 0
+
+    _loop(0, words, put)
 
 
-def _vec_ld(ref, start, length):
-    """Dynamic row load (``length`` static) from a flat HBM ref."""
-    ref, start = _at(ref, start)
-    return pl.load(ref, (pl.ds(start, length),))
+def _bit(k):
+    return jnp.left_shift(_i32(1), k % 32)
 
 
-def _vec_st(ref, start, vals):
-    """Dynamic row store to a flat HBM ref."""
-    ref, start = _at(ref, start)
-    pl.store(ref, (pl.ds(start, vals.shape[0]),), vals.astype(ref.dtype))
+def _flip_page(cfg, E, off, pw, sign):
+    """Add ``sign`` times the page's occupancy bit to its bitmap word
+    (uint32 arithmetic on int32 words, as the oracle does) and move its
+    chunk's free count the other way."""
+    wpc = cfg.words_per_chunk
+    ch = off // wpc
+    pg = (off % wpc) // pw
+    a = ch * cfg.bitmap_words_per_chunk + pg // 32
+    _st(E["bitmap"], a, _ld(E["bitmap"], a) + sign * _bit(pg))
+    _st(E["free_count"], ch, _ld(E["free_count"], ch) - sign)
 
 
-def _vec_st_if(ref, start, vals, cond):
-    """Predicated row store to a flat HBM ref (safe-address RMW)."""
-    ref, a = _at(ref, jnp.where(cond, start, 0))
-    old = pl.load(ref, (pl.ds(a, vals.shape[0]),))
-    pl.store(ref, (pl.ds(a, vals.shape[0]),),
-             jnp.where(cond, vals.astype(old.dtype), old))
-
-
-def _take(vec, i):
-    """Scalar ``vec[i]`` for a traced index into an in-register vector."""
-    return jax.lax.dynamic_index_in_dim(vec, i, keepdims=False)
-
-
-def _gather_small(vec, idx):
-    """Per-lane gather from a small in-register vector via one-hot
-    (compiled-TPU-friendly: no dynamic gather), OOB lanes read 0."""
-    n, K = idx.shape[0], vec.shape[0]
-    oh = idx[:, None] == jax.lax.broadcasted_iota(jnp.int32, (n, K), 1)
-    return jnp.sum(jnp.where(oh, vec[None, :], 0), axis=1)
-
-
-def _lane_prep(cfg, sizes, valid_i32, offsets=None):
-    """The dispatcher's lane prep: class ids and the validity mask
-    (``page_alloc``/``chunk_alloc`` preamble, bit for bit)."""
-    C = cfg.num_classes
-    cls = size_to_class_device(cfg, sizes)
-    valid = (valid_i32 != 0) & (cls < C)
-    if offsets is not None:
-        valid = valid & (offsets >= 0)
-    return cls, valid
+def _bind(cfg, E, ch, cls):
+    """Claim chunk ``ch`` for class ``cls``: clear its bitmap row, set a
+    full free count, record the binding (alloc's from-pool path)."""
+    bw = cfg.bitmap_words_per_chunk
+    _fill(E["bitmap"], ch * bw, bw, 0)
+    _st(E["free_count"], ch, jnp.right_shift(
+        _i32(cfg.max_pages_per_chunk),
+        jnp.clip(cls, 0, cfg.num_classes - 1)))
+    _st(E["chunk_class"], ch, cls)
 
 
 # --------------------------------------------------------------------------
-# chunk-pool ring: scalar pop/push against the VMEM-resident pool row
+# lanes of one (attempt, shard) visit
 # --------------------------------------------------------------------------
 
-def _pool_pop1(octl, pool_ref, lay, cond):
+class _Lanes:
+    """The lanes a class step sees.  ``msk[i]`` is lane ``i``'s class
+    when this visit serves it, else -1.  ``put`` records a granted
+    shard-local offset (globalized by ``obase``); ``off`` reads a freed
+    offset back as shard-local."""
+    __slots__ = ("n", "msk", "offs", "off_in", "obase")
+
+    def __init__(self, n, msk, offs=None, off_in=None, obase=0):
+        self.n, self.msk, self.offs = n, msk, offs
+        self.off_in, self.obase = off_in, obase
+
+    def member(self, i, c):
+        return self.msk[i] == c
+
+    def put(self, i, v):
+        self.offs[i] = jnp.where(v >= 0, v + self.obase, v)
+
+    def off(self, i):
+        return self.off_in[i] - self.obase
+
+
+def _count(L, c):
+    return _loop(0, L.n, lambda i, k: k + L.member(i, c).astype(jnp.int32))
+
+
+def _for_members(L, c, fn):
+    """``fn(i, rank)`` for every lane of class ``c``, in lane order."""
+    def lane(i, k):
+        m = L.member(i, c)
+
+        @pl.when(m)
+        def _():
+            fn(i, k)
+
+        return k + m.astype(jnp.int32)
+
+    return _loop(0, L.n, lane)
+
+
+# --------------------------------------------------------------------------
+# chunk-pool ring and segment grow (shared with kernels/defrag_txn.py)
+# --------------------------------------------------------------------------
+
+def _pool_pop1(octl, pool, lay, cond):
     """One predicated pool pop (``queues.pool_dequeue`` semantics: the
     slot is read at the wrapped front, masked lanes yield NULL, the
     counter advances only for active lanes)."""
-    nc = pool_ref.shape[0]
+    nc = lay.cfg.num_chunks
     pf = _ld(octl, lay.off_pool_front)
-    v = _ld_if(pool_ref, pf % nc, cond, NULL)
+    v = _ld_if(pool, pf % nc, cond)
     _st(octl, lay.off_pool_front, pf + jnp.where(cond, 1, 0))
     return v
 
 
-def _pool_push1(octl, pool_ref, lay, v, cond):
+def _pool_push1(octl, pool, lay, v, cond):
     """One predicated pool push (``queues.pool_enqueue`` semantics)."""
-    nc = pool_ref.shape[0]
+    nc = lay.cfg.num_chunks
     pb = _ld(octl, lay.off_pool_back)
-    _st_if(pool_ref, pb % nc, v, cond)
+    _st_if(pool, pb % nc, v, cond)
     _st(octl, lay.off_pool_back, pb + jnp.where(cond, 1, 0))
 
 
-# --------------------------------------------------------------------------
-# segment grow: the one canonical protocol per virtualized family
-# --------------------------------------------------------------------------
-
-def _va_grow(octl, pool_ref, dir_ref, lay, spc, back, cnt, m):
+def _va_grow(octl, pool, dirv, lay, c, spc, back, cnt):
     """Append directory segments so slots [back, back+cnt) plus the
-    next insertion point are all backed (``queues._grow_counts``):
-    pool pops in ascending-j order, directory-row writes after the
-    current back segment."""
+    next insertion point are all backed (``queues._grow_counts``): pool
+    pops in order, each written after the current back segment."""
     n_new = (back + cnt) // spc - back // spc
     seg_back = back // spc
-    for j in range(m):
-        active = j < n_new
-        chk = _pool_pop1(octl, pool_ref, lay, active)
-        _row_st_if(dir_ref, (seg_back + 1 + j) % lay.max_segs, chk,
-                   active)
+    row = c * lay.max_segs
+
+    def grow(j, _):
+        chk = _pool_pop1(octl, pool, lay, True)
+        _st(dirv, row + (seg_back + 1 + j) % lay.max_segs, chk)
+        return 0
+
+    _loop(0, n_new, grow)
 
 
-def _vl_grow(octl, pool_ref, heap_ref, lay, spc, wpc, W, tail, back,
-             cnt, m):
-    """Pop, terminate, and chain new tail segments, in the oracle's
-    scatter order (all terminators, then links in j order; the last
-    new chunk keeps its NULL terminator).  Returns ``(new_chunks,
-    tail')`` — the value-write phase selects segments from
-    ``[tail] + new_chunks``."""
+def _vl_grow(octl, pool, heap, segs, lay, spc, tail, back, cnt):
+    """Pop, terminate and chain new tail segments in the oracle's
+    scatter order (all pops, all terminators, then the links).  Leaves
+    ``[tail] + new_chunks`` in ``segs`` and returns the new tail."""
+    wpc = lay.cfg.words_per_chunk
+    W = lay.cfg.total_words
     n_new = (back + cnt) // spc - back // spc
-    new_chunks = [_pool_pop1(octl, pool_ref, lay, j < n_new)
-                  for j in range(m)]
-    for j in range(m):
-        w = new_chunks[j] * wpc
-        _st_if(heap_ref, w, NULL, (j < n_new) & (w >= 0) & (w < W))
-    for j in range(m):
-        prev = tail if j == 0 else new_chunks[j - 1]
-        w = prev * wpc
-        _st_if(heap_ref, w, new_chunks[j],
-               (j < n_new) & (w >= 0) & (w < W))
-    last = jnp.maximum(n_new - 1, 0)
-    cand = _take(jnp.stack(new_chunks), last)
-    return new_chunks, jnp.where(n_new > 0, cand, tail)
+    segs[0] = _i32(tail)
+
+    def pop(j, _):
+        segs[j + 1] = _pool_pop1(octl, pool, lay, True)
+        return 0
+
+    def term(j, _):
+        w = segs[j + 1] * wpc
+        _st_if(heap, w, NULL, (w >= 0) & (w < W))
+        return 0
+
+    def link(j, _):
+        w = segs[j] * wpc
+        _st_if(heap, w, segs[j + 1], (w >= 0) & (w < W))
+        return 0
+
+    _loop(0, n_new, pop)
+    _loop(0, n_new, term)
+    _loop(0, n_new, link)
+    return segs[n_new]
 
 
 # --------------------------------------------------------------------------
-# page-kind bodies: one vectorized transaction slice per size class
+# page-kind bodies: the class-c slice of one page transaction
 # --------------------------------------------------------------------------
 #
-# Each body is the class-c slice of the corresponding oracle bulk
-# transaction.  `E` maps region name -> the ref the body must operate
-# on (the output ref when the region is written, else the input ref);
-# `octl` is the VMEM ctl accumulator initialized from the scalar-
-# prefetched control block at step 0.
+# ``E`` maps region name -> _View; ``octl`` is the ctl view the class
+# steps accumulate into; ``segs`` is SMEM scratch for segment chains.
 
-def _page_ring_alloc(cfg, lay, c, sizes, valid_i32, E, octl, offs_ref):
-    """Class-c slice of page_alloc.alloc over the ring family: masked
-    rank, inventory grant, wrapped ring-window pop (the ring row is the
-    staged VMEM block), one front advance."""
-    cls, valid = _lane_prep(cfg, sizes, valid_i32)
-    n = cls.shape[0]
-    row = E["queue_store"][0, :]
-    cap = row.shape[0]
-    m = min(n, cap)
-
-    member, rank, _ = _member_rank(cls, valid, c)
+def _grants(octl, lay, c, L):
+    """(front, items queued, lanes granted) of class ``c``."""
     front = _ld(octl, lay.off_front + c)
     back = _ld(octl, lay.off_back + c)
-    grant = member & (rank < back - front)
-    cnt = jnp.sum(grant.astype(jnp.int32))
+    return front, back - front, jnp.clip(back - front, 0, _count(L, c))
 
-    start = front % cap
-    padded = jnp.concatenate([row, row[:m]])
-    win = jax.lax.dynamic_slice(padded, (start,), (m,))
-    j = jax.lax.broadcasted_iota(jnp.int32, (n, m), 1)
-    sel = grant[:, None] & (j == (rank % m)[:, None])
-    gathered = jnp.sum(jnp.where(sel, win[None, :], 0), axis=1)
 
-    offs_ref[...] = jnp.where(grant, gathered, offs_ref[...])
+def _grant(L, c, avail, value):
+    """The first ``avail`` members of class ``c`` (in rank order) get
+    ``value(rank)``."""
+    def fn(i, k):
+        @pl.when(k < avail)
+        def _():
+            L.put(i, _i32(value(k)))
+
+    _for_members(L, c, fn)
+
+
+def _page_ring_alloc(cfg, lay, c, L, E, octl, segs):
+    """Class-c slice of page_alloc.alloc over the ring family: the
+    first ``back - front`` members, in rank order, pop the ring."""
+    q = E["queue_store"]
+    cap = lay.queue_capacity
+    front, avail, cnt = _grants(octl, lay, c, L)
+    _grant(L, c, avail, lambda k: _ld(q, c * cap + (front + k) % cap))
     _st(octl, lay.off_front + c, front + cnt)
 
 
-def _page_ring_free(cfg, lay, c, offsets, sizes, valid_i32, E, octl):
-    """Class-c slice of page_alloc.free over the ring family: rank,
-    rank->slot one-hot scatter, wrapped window write-back on the staged
-    ring row, one back advance."""
-    cls, valid = _lane_prep(cfg, sizes, valid_i32, offsets)
-    n = cls.shape[0]
-    qrow = E["queue_store"]
-    cap = qrow.shape[1]
-    m = min(n, cap)
-    row = qrow[0, :]
-
-    member, rank, cnt = _member_rank(cls, valid, c)
+def _page_ring_free(cfg, lay, c, L, E, octl, segs):
+    """Class-c slice of page_alloc.free over the ring family: member
+    ``k`` writes ring slot ``back + k``; one back advance."""
+    q = E["queue_store"]
+    cap = lay.queue_capacity
     back = _ld(octl, lay.off_back + c)
 
-    j2 = jax.lax.broadcasted_iota(jnp.int32, (n, m), 1)
-    sel = member[:, None] & (j2 == (rank % m)[:, None])
-    w = jnp.sum(jnp.where(sel, offsets[:, None], 0), axis=0)
+    def put(i, k):
+        _st(q, c * cap + (back + k) % cap, L.off(i))
 
-    start = back % cap
-    padded = jnp.concatenate([row, row[:m]])
-    cur = jax.lax.dynamic_slice(padded, (start,), (m,))
-    jm = _iota(m)
-    padded = jax.lax.dynamic_update_slice(
-        padded, jnp.where(jm < cnt, w, cur), (start,))
-    over = start + cnt - cap
-    head = jnp.where(jm < over, padded[cap:cap + m], padded[:m])
-    qrow[0, :] = jnp.concatenate([head, padded[m:cap]])
+    cnt = _for_members(L, c, put)
     _st(octl, lay.off_back + c, back + cnt)
 
 
-def _page_va_alloc(cfg, lay, c, sizes, valid_i32, E, octl, offs_ref):
-    """Class-c slice of page_alloc.alloc over the va family: grant,
-    per-lane gather through the directory row into heap segment slots,
-    then segment shrink (fully consumed segments -> pool)."""
-    cls, valid = _lane_prep(cfg, sizes, valid_i32)
-    n = cls.shape[0]
+def _page_va_alloc(cfg, lay, c, L, E, octl, segs):
+    """Class-c slice of page_alloc.alloc over the va family: grants
+    read heap segment slots through the directory row, then fully
+    consumed segments go back to the pool (shrink)."""
     spc = cfg.slots_per_segment("va")
     wpc = cfg.words_per_chunk
     W = cfg.total_words
-    max_segs = lay.max_segs
-    m = n // spc + 1
+    row = c * lay.max_segs
+    dirv, heap = E["directory"], E["heap"]
+    front, avail, cnt = _grants(octl, lay, c, L)
 
-    member, rank, _ = _member_rank(cls, valid, c)
-    front = _ld(octl, lay.off_front + c)
-    back = _ld(octl, lay.off_back + c)
-    grant = member & (rank < back - front)
-    cnt = jnp.sum(grant.astype(jnp.int32))
-    grant_i = grant.astype(jnp.int32)
+    def slot(k):
+        v = front + k
+        word = _ld(dirv, row + (v // spc) % lay.max_segs) * wpc + v % spc
+        return _ld_if(heap, word, (word >= 0) & (word < W))
 
-    dir_ref = E["directory"]
-    heap_ref = E["heap"]
-
-    def lane(i, _):
-        g = _take(grant_i, i) != 0
-        v = front + _take(rank, i)
-        seg = _row_ld(dir_ref, (v // spc) % max_segs)
-        word = seg * wpc + v % spc
-        ok = g & (word >= 0) & (word < W)
-        val = _ld_if(heap_ref, word, ok, NULL)
-        _st(offs_ref, i, jnp.where(g, val, _ld(offs_ref, i)))
-        return 0
-
-    jax.lax.fori_loop(0, n, lane, 0)
-
+    _grant(L, c, avail, slot)
     n_free = (front + cnt) // spc - front // spc
     seg_front = front // spc
-    for j in range(m):
-        freed = _row_ld(dir_ref, (seg_front + j) % max_segs)
-        _pool_push1(octl, E["pool_store"], lay, freed, j < n_free)
+
+    def shrink(j, _):
+        freed = _ld(dirv, row + (seg_front + j) % lay.max_segs)
+        _pool_push1(octl, E["pool_store"], lay, freed, True)
+        return 0
+
+    _loop(0, n_free, shrink)
     _st(octl, lay.off_front + c, front + cnt)
 
 
-def _page_va_free(cfg, lay, c, offsets, sizes, valid_i32, E, octl):
+def _page_va_free(cfg, lay, c, L, E, octl, segs):
     """Class-c slice of page_alloc.free over the va family: segment
-    grow (pool pops -> directory row), then per-lane value writes into
-    heap segment slots through the updated directory."""
-    cls, valid = _lane_prep(cfg, sizes, valid_i32, offsets)
-    n = cls.shape[0]
+    grow (pool pops -> directory row), then the value writes into heap
+    segment slots through the updated directory."""
     spc = cfg.slots_per_segment("va")
     wpc = cfg.words_per_chunk
     W = cfg.total_words
-    max_segs = lay.max_segs
-    m = n // spc + 1
-
-    member, rank, cnt = _member_rank(cls, valid, c)
+    row = c * lay.max_segs
+    dirv, heap = E["directory"], E["heap"]
     back = _ld(octl, lay.off_back + c)
-    member_i = member.astype(jnp.int32)
+    cnt = _count(L, c)
+    _va_grow(octl, E["pool_store"], dirv, lay, c, spc, back, cnt)
 
-    dir_ref = E["directory"]
-    heap_ref = E["heap"]
+    def put(i, k):
+        v = back + k
+        word = _ld(dirv, row + (v // spc) % lay.max_segs) * wpc + v % spc
+        _st_if(heap, word, L.off(i), (word >= 0) & (word < W))
 
-    _va_grow(octl, E["pool_store"], dir_ref, lay, spc, back, cnt, m)
-
-    def lane(i, _):
-        g = _take(member_i, i) != 0
-        v = back + _take(rank, i)
-        seg = _row_ld(dir_ref, (v // spc) % max_segs)
-        word = seg * wpc + v % spc
-        _st_if(heap_ref, word, _take(offsets, i),
-               g & (word >= 0) & (word < W))
-        return 0
-
-    jax.lax.fori_loop(0, n, lane, 0)
+    _for_members(L, c, put)
     _st(octl, lay.off_back + c, back + cnt)
 
 
-def _page_vl_alloc(cfg, lay, c, sizes, valid_i32, E, octl, offs_ref):
-    """Class-c slice of page_alloc.alloc over the vl family: the
-    next-pointer chain walk from the head segment, per-lane gathers,
-    then shrink (consumed leading segments -> pool, head advances)."""
-    cls, valid = _lane_prep(cfg, sizes, valid_i32)
-    n = cls.shape[0]
+def _page_vl_alloc(cfg, lay, c, L, E, octl, segs):
+    """Class-c slice of page_alloc.alloc over the vl family: walk the
+    next-pointer chain from the head segment, grant slots, then hand
+    the consumed leading segments to the pool and advance the head."""
     spc = cfg.slots_per_segment("vl")
     wpc = cfg.words_per_chunk
     W = cfg.total_words
-    m = n // spc + 1
+    heap = E["heap"]
+    front, avail, cnt = _grants(octl, lay, c, L)
+    n_free = (front + cnt) // spc - front // spc
+    segs[0] = _ld(octl, lay.off_head + c)
 
-    member, rank, _ = _member_rank(cls, valid, c)
-    front = _ld(octl, lay.off_front + c)
-    back = _ld(octl, lay.off_back + c)
-    grant = member & (rank < back - front)
-    cnt = jnp.sum(grant.astype(jnp.int32))
-    grant_i = grant.astype(jnp.int32)
-    heap_ref = E["heap"]
-
-    head = _ld(octl, lay.off_head + c)
-    chain = [head]
-    for _hop in range(m):
-        prev = chain[-1]
-        chain.append(_ld_if(heap_ref, prev * wpc, prev >= 0, NULL))
-    chain_vec = jnp.stack(chain)
-
-    def lane(i, _):
-        g = _take(grant_i, i) != 0
-        v = front + _take(rank, i)
-        seg = _take(chain_vec, v // spc - front // spc)
-        word = seg * wpc + 1 + v % spc
-        ok = g & (word >= 0) & (word < W)
-        val = _ld_if(heap_ref, word, ok, NULL)
-        _st(offs_ref, i, jnp.where(g, val, _ld(offs_ref, i)))
+    def hop(j, _):
+        prev = segs[j]
+        segs[j + 1] = _ld_if(heap, prev * wpc, prev >= 0)
         return 0
 
-    jax.lax.fori_loop(0, n, lane, 0)
+    _loop(0, n_free, hop)
 
-    n_free = (front + cnt) // spc - front // spc
-    for j in range(m):
-        _pool_push1(octl, E["pool_store"], lay, chain[j], j < n_free)
-    _st(octl, lay.off_head + c, _take(chain_vec, n_free))
+    def slot(k):
+        v = front + k
+        word = segs[v // spc - front // spc] * wpc + 1 + v % spc
+        return _ld_if(heap, word, (word >= 0) & (word < W))
+
+    _grant(L, c, avail, slot)
+
+    def shrink(j, _):
+        _pool_push1(octl, E["pool_store"], lay, segs[j], True)
+        return 0
+
+    _loop(0, n_free, shrink)
+    _st(octl, lay.off_head + c, segs[n_free])
     _st(octl, lay.off_front + c, front + cnt)
 
 
-def _page_vl_free(cfg, lay, c, offsets, sizes, valid_i32, E, octl):
+def _page_vl_free(cfg, lay, c, L, E, octl, segs):
     """Class-c slice of page_alloc.free over the vl family: grow (pool
-    pops, terminate + link the new segments after the tail), per-lane
-    value writes, tail update."""
-    cls, valid = _lane_prep(cfg, sizes, valid_i32, offsets)
-    n = cls.shape[0]
+    pops, terminate + link the new segments after the tail), value
+    writes, tail update."""
     spc = cfg.slots_per_segment("vl")
     wpc = cfg.words_per_chunk
     W = cfg.total_words
-    m = n // spc + 1
-
-    member, rank, cnt = _member_rank(cls, valid, c)
+    heap = E["heap"]
     back = _ld(octl, lay.off_back + c)
-    member_i = member.astype(jnp.int32)
-    heap_ref = E["heap"]
-    tail = _ld(octl, lay.off_tail + c)
+    cnt = _count(L, c)
+    new_tail = _vl_grow(octl, E["pool_store"], heap, segs, lay, spc,
+                        _ld(octl, lay.off_tail + c), back, cnt)
 
-    new_chunks, new_tail = _vl_grow(octl, E["pool_store"], heap_ref,
-                                    lay, spc, wpc, W, tail, back, cnt,
-                                    m)
-    seg_vec = jnp.stack([tail] + new_chunks)
+    def put(i, k):
+        v = back + k
+        word = segs[v // spc - back // spc] * wpc + 1 + v % spc
+        _st_if(heap, word, L.off(i), (word >= 0) & (word < W))
 
-    def lane(i, _):
-        g = _take(member_i, i) != 0
-        v = back + _take(rank, i)
-        seg = _take(seg_vec, v // spc - back // spc)
-        word = seg * wpc + 1 + v % spc
-        _st_if(heap_ref, word, _take(offsets, i),
-               g & (word >= 0) & (word < W))
-        return 0
-
-    jax.lax.fori_loop(0, n, lane, 0)
-
+    _for_members(L, c, put)
     _st(octl, lay.off_tail + c, new_tail)
     _st(octl, lay.off_back + c, back + cnt)
 
@@ -476,273 +462,236 @@ def _page_vl_free(cfg, lay, c, offsets, sizes, valid_i32, E, octl):
 # chunk-kind bodies: the per-class chunk-drain loop
 # --------------------------------------------------------------------------
 
-def _bitmap_claim(row_u, ppc, t, maxbits, bw):
-    """Rank-select + claim over one staged bitmap row (the in-kernel
-    form of chunk_alloc._select_free_pages + _set_bits(+1), mirroring
-    kernels/alloc_txn._claim_kernel).  Returns (page_idx, new_row_u,
-    total): the first ``t`` free page indices ascending (-1 padded),
-    the row with those bits set, and the claimed count."""
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (bw, 32), 1)
-    occ = ((row_u[:, None] >> shifts) & 1).astype(jnp.int32)
-    occ = occ.reshape(maxbits)
-    p = _iota(maxbits)
-    free = (occ == 0) & (p < ppc)
-    fi = free.astype(jnp.int32)
-    order = jnp.cumsum(fi) - fi
-    chosen = free & (order < t)
-    total = jnp.sum(chosen.astype(jnp.int32))
-    onehot = chosen[None, :] & (order[None, :] == p[:, None])
-    pidx = jnp.sum(jnp.where(onehot, p[None, :], 0), axis=1)
-    page_idx = jnp.where(p < total, pidx, -1)
-    add = jnp.sum(jnp.where(chosen.reshape(bw, 32),
-                            jnp.uint32(1) << shifts, jnp.uint32(0)),
-                  axis=1)
-    return page_idx, row_u + add, total
+def _next_free(bitmap, rowb, p, ppc, want):
+    """First page index ``>= p`` whose occupancy bit is clear (``ppc``
+    when none is); ``p`` itself when not ``want``."""
+    def body(cr):
+        p, _ = cr
+        word = _ld_if(bitmap, rowb + p // 32, p < ppc, 0)
+        busy = (p < ppc) & ((word & _bit(p)) != 0)
+        return jnp.where(busy, p + 1, p), busy
+
+    return jax.lax.while_loop(lambda cr: cr[1], body, (p, want))[0]
 
 
-def _chunk_alloc(cfg, lay, family, c, sizes, valid_i32, E, octl,
-                 offs_ref):
-    """Class-c slice of chunk_alloc.alloc: the dynamic chunk-drain loop
-    — pop a chunk from the class queue (or claim a fresh one from the
-    pool), stage its bitmap row from HBM, rank-select + claim pages,
-    scatter granted offsets to the requesting lanes, re-enqueue the
-    chunk while pages remain.  Queue traffic goes through the staged
-    class row (ring), the directory row (va), or the head/tail chain
-    in ctl + heap (vl), exactly one item at a time, as in the oracle's
+def _chunk_alloc(cfg, lay, family, c, L, E, octl, segs):
+    """Class-c slice of chunk_alloc.alloc: the chunk-drain loop — pop a
+    chunk from the class queue (or claim a fresh one from the pool),
+    claim its first free pages for the next members in rank order,
+    re-enqueue it while pages remain.  Queue traffic goes through the
+    class ring row (ring), the directory row (va) or the head/tail
+    chain in ctl + heap (vl), one item at a time, as in the oracle's
     while loop."""
-    C = cfg.num_classes
     nc = cfg.num_chunks
     wpc = cfg.words_per_chunk
     bw = cfg.bitmap_words_per_chunk
-    maxbits = bw * 32
     W = cfg.total_words
-    pw0 = cfg.page_words(0)
     spc = cfg.slots_per_segment(family)
     max_segs = lay.max_segs
+    cap = lay.queue_capacity
+    row = c * (cap if family == "ring" else max_segs)
 
-    cls, valid = _lane_prep(cfg, sizes, valid_i32)
-    n = cls.shape[0]
-    member, rank, _ = _member_rank(cls, valid, c)
-    counts_c = jnp.sum(member.astype(jnp.int32))
-    pw = pw0 << c                       # page words of class c (traced)
-    ppc = cfg.words_per_chunk // pw     # pages per chunk of class c
+    counts_c = _count(L, c)
+    pw = jnp.left_shift(_i32(cfg.page_words(0)), c)   # page words of c
+    ppc = wpc // pw                                    # pages per chunk
 
-    bitmap_ref = E["bitmap"]
-    fc_ref = E["free_count"]
-    cc_ref = E["chunk_class"]
-    pool_ref = E["pool_store"]
-    heap_ref = E.get("heap")
-    qrow = E.get("queue_store")
-    dir_ref = E.get("directory")
+    bitmap, fc = E["bitmap"], E["free_count"]
+    pool = E["pool_store"]
+    heap, q, dirv = E.get("heap"), E.get("queue_store"), E.get("directory")
+    off_front, off_back = lay.off_front + c, lay.off_back + c
+
+    def in_heap(w):
+        return (w >= 0) & (w < W)
 
     def body(carry):
         served, fail = carry
-        front = _ld(octl, lay.off_front + c)
-        back = _ld(octl, lay.off_back + c)
+        front = _ld(octl, off_front)
+        back = _ld(octl, off_back)
         have = (back - front) > 0
+        step = jnp.where(have, 1, 0)
 
-        # -- pop one chunk from the class queue (family-specific) ------
+        # -- pop one chunk from the class queue ------------------------
         if family == "ring":
-            cap = qrow.shape[1]
-            val_q = _row_ld(qrow, front % cap)
-            _st(octl, lay.off_front + c, front + jnp.where(have, 1, 0))
+            val_q = _ld(q, row + front % cap)
         elif family == "va":
-            seg = _row_ld(dir_ref, (front // spc) % max_segs)
+            seg = _ld(dirv, row + (front // spc) % max_segs)
             word = seg * wpc + front % spc
-            val_q = _ld_if(heap_ref, word, have & (word >= 0) & (word < W))
+            val_q = _ld_if(heap, word, have & in_heap(word))
             crossed = (front + 1) // spc - front // spc > 0
-            _pool_push1(octl, pool_ref, lay, seg, have & crossed)
-            _st(octl, lay.off_front + c, front + jnp.where(have, 1, 0))
+            _pool_push1(octl, pool, lay, seg, have & crossed)
         else:  # vl
             head = _ld(octl, lay.off_head + c)
             word = head * wpc + 1 + front % spc
-            val_q = _ld_if(heap_ref, word, have & (word >= 0) & (word < W))
-            nxt = _ld_if(heap_ref, head * wpc, head >= 0)
-            crossed = (front + 1) // spc - front // spc > 0
-            sh = have & crossed
-            _pool_push1(octl, pool_ref, lay, head, sh)
+            val_q = _ld_if(heap, word, have & in_heap(word))
+            nxt = _ld_if(heap, head * wpc, head >= 0)
+            sh = have & ((front + 1) // spc - front // spc > 0)
+            _pool_push1(octl, pool, lay, head, sh)
             _st(octl, lay.off_head + c, jnp.where(sh, nxt, head))
-            _st(octl, lay.off_front + c, front + jnp.where(have, 1, 0))
+        _st(octl, off_front, front + step)
 
         # -- else claim a fresh chunk from the pool --------------------
         pf = _ld(octl, lay.off_pool_front)
-        pb = _ld(octl, lay.off_pool_back)
-        has = (pb - pf) > 0
+        has = (_ld(octl, lay.off_pool_back) - pf) > 0
         take_pool = (~have) & has
-        ch_p = _ld_if(pool_ref, pf % nc, take_pool)
+        ch_p = _ld_if(pool, pf % nc, take_pool)
         _st(octl, lay.off_pool_front, pf + jnp.where(take_pool, 1, 0))
         fail_now = (~have) & (~has)
         chunk = jnp.where(have, val_q, jnp.where(has, ch_p, NULL))
-        # fresh chunk: zero bitmap row, full free count, bind to class c
-        safe_p = jnp.clip(jnp.where(ch_p < 0, ch_p + nc, ch_p), 0, nc - 1)
-        _vec_st_if(bitmap_ref, safe_p * bw, jnp.zeros(bw, jnp.int32),
-                   take_pool)
-        _st_if(fc_ref, safe_p, ppc, take_pool)
-        _st_if(cc_ref, safe_p, c, take_pool)
 
-        # -- stage the chunk's bitmap row, rank-select + claim ---------
+        @pl.when(take_pool)
+        def _fresh():
+            _bind(cfg, E, jnp.clip(jnp.where(ch_p < 0, ch_p + nc, ch_p),
+                                   0, nc - 1), c)
+
+        # -- claim the chunk's first free pages, lane by lane ----------
         # (index normalization mirrors jnp's negative-wrap + clamp
-        # gather semantics on bitmap[chunk] for the chunk = -1 case,
-        # where t == 0 makes the claim a no-op on whatever row)
+        # gather on bitmap[chunk] for chunk = -1, where t == 0)
         idxc = jnp.clip(jnp.where(chunk < 0, chunk + nc, chunk), 0, nc - 1)
-        f = jnp.where(fail_now, 0, _ld(fc_ref, idxc))
+        f = jnp.where(fail_now, 0, _ld(fc, idxc))
         t = jnp.minimum(counts_c - served, f)
-        row_u = jax.lax.bitcast_convert_type(
-            _vec_ld(bitmap_ref, idxc * bw, bw), jnp.uint32)
-        page_idx, new_row_u, total = _bitmap_claim(row_u, ppc, t,
-                                                   maxbits, bw)
-        _vec_st(bitmap_ref, idxc * bw,
-                jax.lax.bitcast_convert_type(new_row_u, jnp.int32))
-        _st_if(fc_ref, idxc, f - total, total > 0)
+        rowb = idxc * bw
 
-        # -- scatter granted offsets to the lanes of this iteration ----
-        lane_sel = member & (rank >= served) & (rank < served + total)
-        pidx_lane = _gather_small(page_idx, rank - served)
-        offs_ref[...] = jnp.where(lane_sel, chunk * wpc + pidx_lane * pw,
-                                  offs_ref[...])
+        def lane(i, cr):
+            k, p, total = cr
+            m = L.member(i, c)
+            want = m & (k >= served) & (k < served + t)
+            p = _next_free(bitmap, rowb, p, ppc, want)
+            got = want & (p < ppc)
+
+            @pl.when(got)
+            def _claim():
+                a = rowb + p // 32
+                _st(bitmap, a, _ld(bitmap, a) + _bit(p))
+                L.put(i, chunk * wpc + p * pw)
+
+            g = got.astype(jnp.int32)
+            return k + m.astype(jnp.int32), p + g, total + g
+
+        total = _loop(0, L.n, lane, (_i32(0), _i32(0), _i32(0)))[2]
+        _st_if(fc, idxc, f - total, total > 0)
 
         # -- chunk still has pages -> back into the class queue --------
         leftover = (~fail_now) & (f - total > 0)
-        back = _ld(octl, lay.off_back + c)
+        lv = jnp.where(leftover, 1, 0)
+        back = _ld(octl, off_back)
         if family == "ring":
-            cap = qrow.shape[1]
-            _row_st_if(qrow, back % cap, chunk, leftover)
-            _st(octl, lay.off_back + c,
-                back + jnp.where(leftover, 1, 0))
+            _st_if(q, row + back % cap, chunk, leftover)
         elif family == "va":
-            lv = jnp.where(leftover, 1, 0)
-            _va_grow(octl, pool_ref, dir_ref, lay, spc, back, lv, 1)
-            seg = _row_ld(dir_ref, (back // spc) % max_segs)
+            _va_grow(octl, pool, dirv, lay, c, spc, back, lv)
+            seg = _ld(dirv, row + (back // spc) % max_segs)
             word = seg * wpc + back % spc
-            _st_if(heap_ref, word, chunk,
-                   leftover & (word >= 0) & (word < W))
-            _st(octl, lay.off_back + c, back + lv)
+            _st_if(heap, word, chunk, leftover & in_heap(word))
         else:  # vl
             tail = _ld(octl, lay.off_tail + c)
-            lv = jnp.where(leftover, 1, 0)
-            _, new_tail = _vl_grow(octl, pool_ref, heap_ref, lay, spc,
-                                   wpc, W, tail, back, lv, 1)
+            new_tail = _vl_grow(octl, pool, heap, segs, lay, spc, tail,
+                                back, lv)
             word = tail * wpc + 1 + back % spc
-            _st_if(heap_ref, word, chunk,
-                   leftover & (word >= 0) & (word < W))
+            _st_if(heap, word, chunk, leftover & in_heap(word))
             _st(octl, lay.off_tail + c, new_tail)
-            _st(octl, lay.off_back + c, back + lv)
+        _st(octl, off_back, back + lv)
+        return served + t, fail | fail_now.astype(jnp.int32)
 
-        return served + t, fail | fail_now
-
-    jax.lax.while_loop(
-        lambda cr: (cr[0] < counts_c) & ~cr[1],
-        body, (jnp.int32(0), jnp.asarray(False)))
+    jax.lax.while_loop(lambda cr: (cr[0] < counts_c) & (cr[1] == 0),
+                       body, (_i32(0), _i32(0)))
 
 
-def _chunk_free(cfg, lay, family, c, offsets, sizes, valid_i32, E, octl,
-                aux_ref, old_free_ref):
-    """Chunk-kind free.  Step 0 clears the freed page bits (one staged
-    bitmap-row RMW per lane), bumps free counts, and records the
-    full->non-full transitions in ``aux``; every step then re-enqueues
-    its own class's revived chunks (ascending chunk id, the oracle's
-    nonzero order) through the class row / directory / chain."""
+def _chunk_free(cfg, lay, family, c, L, E, octl, segs, aux, nrev):
+    """Chunk-kind free.  Class step 0 marks the chunks that go from
+    full to non-full (``aux``, against the pre-free counts), then
+    clears every freed page bit and bumps free counts, one lane at a
+    time; every class step then re-enqueues its own class's revived
+    chunks (ascending id, the oracle's nonzero order)."""
     C = cfg.num_classes
     nc = cfg.num_chunks
     wpc = cfg.words_per_chunk
-    bw = cfg.bitmap_words_per_chunk
     W = cfg.total_words
-    pw0 = cfg.page_words(0)
     spc = cfg.slots_per_segment(family)
     max_segs = lay.max_segs
+    cap = lay.queue_capacity
+    fc, cc = E["free_count"], E["chunk_class"]
 
-    cls, valid = _lane_prep(cfg, sizes, valid_i32, offsets)
-    n = cls.shape[0]
-    m = n // spc + 1
-
-    bitmap_ref = E["bitmap"]
-    fc_ref = E["free_count"]
-    pool_ref = E.get("pool_store")
-    heap_ref = E.get("heap")
-    qrow = E.get("queue_store")
-    dir_ref = E.get("directory")
-
-    chunk_v = offsets // wpc
-    pw_v = pw0 << (cls % C)
-    page_v = (offsets % wpc) // pw_v
-    ok_v = valid & (chunk_v >= 0) & (chunk_v < nc)
-    ok_i = ok_v.astype(jnp.int32)
-    word_v = chunk_v * bw + page_v // 32
-    bit_v = (page_v % 32).astype(jnp.uint32)
+    def lane_chunk(i):
+        cl = L.msk[i]
+        off = L.off(i)
+        ch = off // wpc
+        return cl, off, ch, (cl >= 0) & (ch >= 0) & (ch < nc)
 
     @pl.when(c == 0)
     def _clear():
-        # full -> non-full transitions, against the PRE-clear counts
-        iota_nc = jax.lax.broadcasted_iota(jnp.int32, (n, nc), 1)
-        touched = jnp.any((chunk_v[:, None] == iota_nc) & ok_v[:, None],
-                          axis=0)
-        revived = touched & (old_free_ref[...] == 0)
-        aux_ref[...] = revived.astype(jnp.int32)
+        nrev[0] = _i32(0)
 
-        def lane(i, _):
-            ok = _take(ok_i, i) != 0
-            a = _take(word_v, i)
-            old_u = jax.lax.bitcast_convert_type(
-                jnp.reshape(_ld_if(bitmap_ref, a, ok, 0), (1,)),
-                jnp.uint32)
-            bitval = jnp.uint32(1) << _take(bit_v, i)
-            new = jax.lax.bitcast_convert_type(old_u - bitval,
-                                               jnp.int32)[0]
-            _st_if(bitmap_ref, a, new, ok)
-            ch = _take(chunk_v, i)
-            cur = _ld_if(fc_ref, ch, ok, 0)
-            _st_if(fc_ref, ch, cur + 1, ok)
+        def mark(i, _):
+            _, _, ch, ok = lane_chunk(i)
+
+            @pl.when(ok)
+            def _():
+                @pl.when(_ld(fc, ch) == 0)
+                def _():
+                    nrev[0] = _i32(1)
+                    _st(aux, ch, 1)
             return 0
 
-        jax.lax.fori_loop(0, n, lane, 0)
+        def clear(i, _):
+            cl, off, _, ok = lane_chunk(i)
 
-    # -- re-enqueue this class's revived chunks ------------------------
-    rev = aux_ref[...] != 0
-    active = rev & (E["chunk_class"][...] == c)
-    ai = active.astype(jnp.int32)
-    rank_v = jnp.cumsum(ai) - ai
-    cnt = jnp.sum(ai)
-    back = _ld(octl, lay.off_back + c)
-
-    if family == "ring":
-        cap = qrow.shape[1]
-
-        def put(k, _):
-            _row_st_if(qrow, (back + _take(rank_v, k)) % cap, k,
-                       _take(ai, k) != 0)
+            @pl.when(ok)
+            def _():
+                _flip_page(cfg, E, off,
+                           jnp.left_shift(_i32(cfg.page_words(0)), cl % C),
+                           -1)
             return 0
 
-        jax.lax.fori_loop(0, nc, put, 0)
-    elif family == "va":
-        _va_grow(octl, pool_ref, dir_ref, lay, spc, back, cnt, m)
+        _loop(0, L.n, mark)
+        _loop(0, L.n, clear)
 
-        def put(k, _):
-            v = back + _take(rank_v, k)
-            seg = _row_ld(dir_ref, (v // spc) % max_segs)
-            word = seg * wpc + v % spc
-            _st_if(heap_ref, word, k,
-                   (_take(ai, k) != 0) & (word >= 0) & (word < W))
-            return 0
+    @pl.when(nrev[0] > 0)
+    def _requeue():
+        def active(k):
+            return (_ld(aux, k) != 0) & (_ld(cc, k) == c)
 
-        jax.lax.fori_loop(0, nc, put, 0)
-    else:  # vl
-        tail = _ld(octl, lay.off_tail + c)
-        new_chunks, new_tail = _vl_grow(octl, pool_ref, heap_ref, lay,
-                                        spc, wpc, W, tail, back, cnt, m)
-        seg_vec = jnp.stack([tail] + new_chunks)
+        cnt = _loop(0, nc, lambda k, a: a + active(k).astype(jnp.int32))
+        back = _ld(octl, lay.off_back + c)
 
-        def put(k, _):
-            v = back + _take(rank_v, k)
-            seg = _take(seg_vec, v // spc - back // spc)
-            word = seg * wpc + 1 + v % spc
-            _st_if(heap_ref, word, k,
-                   (_take(ai, k) != 0) & (word >= 0) & (word < W))
-            return 0
+        def for_active(fn):
+            def step(k, r):
+                act = active(k)
 
-        jax.lax.fori_loop(0, nc, put, 0)
-        _st(octl, lay.off_tail + c, new_tail)
+                @pl.when(act)
+                def _():
+                    fn(k, r)
 
-    _st(octl, lay.off_back + c, back + cnt)
+                return r + act.astype(jnp.int32)
+
+            _loop(0, nc, step)
+
+        if family == "ring":
+            for_active(lambda k, r: _st(E["queue_store"],
+                                        c * cap + (back + r) % cap, k))
+        elif family == "va":
+            dirv, heap = E["directory"], E["heap"]
+            _va_grow(octl, E["pool_store"], dirv, lay, c, spc, back, cnt)
+
+            def put(k, r):
+                v = back + r
+                seg = _ld(dirv, c * max_segs + (v // spc) % max_segs)
+                word = seg * wpc + v % spc
+                _st_if(heap, word, k, (word >= 0) & (word < W))
+
+            for_active(put)
+        else:  # vl
+            heap = E["heap"]
+            new_tail = _vl_grow(octl, E["pool_store"], heap, segs, lay,
+                                spc, _ld(octl, lay.off_tail + c), back,
+                                cnt)
+
+            def put(k, r):
+                v = back + r
+                word = segs[v // spc - back // spc] * wpc + 1 + v % spc
+                _st_if(heap, word, k, (word >= 0) & (word < W))
+
+            for_active(put)
+            _st(octl, lay.off_tail + c, new_tail)
+        _st(octl, lay.off_back + c, back + cnt)
 
 
 # --------------------------------------------------------------------------
@@ -782,38 +731,125 @@ def _tele_counters(lay, octl, c, pre, post):
                (pf1 // nc - pf0 // nc) + (pb1 // nc - pb0 // nc))
 
 
-def _tele_alloc(cfg, lay, octl, c, sizes, valid_i32, cur, new, attempt):
-    """Per-class alloc/failure counts + walk-depth histogram from the
-    step's lane transitions (``cur``/``new`` are the offsets vector
-    before/after the body, shard-local under sharding)."""
-    cls = size_to_class_device(cfg, sizes)
-    member = (valid_i32 != 0) & (cls == c)
-    served = jnp.sum((member & (cur < 0) & (new >= 0))
-                     .astype(jnp.int32))
-    failed = jnp.sum((member & (new < 0)).astype(jnp.int32))
+def _tele_alloc(lay, octl, c, L, attempt):
+    """Per-class alloc/failure counts and walk-depth histogram from the
+    step's served lanes (every lane of the visit entered unserved)."""
+    def tally(i, cr):
+        m = L.member(i, c)
+        ok = L.offs[i] >= 0
+        return (cr[0] + (m & ok).astype(jnp.int32),
+                cr[1] + (m & ~ok).astype(jnp.int32))
+
+    served, failed = _loop(0, L.n, tally, (_i32(0), _i32(0)))
     _tele_bump(octl, lay.off_t_alloc + c, served)
     _tele_bump(octl, lay.off_t_fail + c, failed)
-    nbin = jnp.minimum(jnp.asarray(attempt, jnp.int32),
-                       arena.TELE_WALK_BINS - 1)
+    nbin = jnp.minimum(attempt, arena.TELE_WALK_BINS - 1)
     _tele_bump(octl, lay.off_t_walk + nbin, served)
 
 
-def _tele_free(cfg, lay, octl, c, offsets, sizes, valid_i32):
-    """Per-class free counts — a pure function of the lane inputs."""
-    cls = size_to_class_device(cfg, sizes)
-    freed = (valid_i32 != 0) & (cls == c) & (offsets >= 0)
-    _tele_bump(octl, lay.off_t_free + c,
-               jnp.sum(freed.astype(jnp.int32)))
+def _tele_free(lay, octl, c, L):
+    _tele_bump(octl, lay.off_t_free + c, _count(L, c))
 
 
 # --------------------------------------------------------------------------
-# wrapper: per-region specs from the ArenaLayout, one pallas_call
+# the launcher: regions as padded HBM slabs, one pallas_call
+# --------------------------------------------------------------------------
+
+def _slab(x):
+    """(S, w) region rows -> flat (S · wp,) with wp a multiple of BLOCK
+    (every window DMA stays tile-aligned); returns (flat, wp)."""
+    S, w = x.shape
+    wp = -(-w // BLOCK) * BLOCK
+    return jnp.pad(x, ((0, 0), (0, wp - w))).reshape(S * wp), wp
+
+
+def _unslab(x, S, w):
+    return x.reshape(S, -1)[:, :w]
+
+
+def launch(body, *, smem_in, hbm_in, writes, smem_out, hbm_out=(),
+           scratch=(), interpret=False):
+    """Run ``body`` as ONE ``pallas_call``.
+
+    ``smem_in``: 1-D int32 arrays, whole SMEM operands.  ``hbm_in``:
+    ``{name: flat slab}`` HBM regions; those in ``writes`` are
+    input/output-aliased and updated in place.  ``smem_out``: word
+    counts of SMEM outputs.  ``hbm_out``: ``{name: words}`` HBM outputs
+    with no input.  ``scratch``: word counts of SMEM scratch.
+
+    ``body(ins, wins, outs, scr)`` gets the SMEM input refs, one
+    :class:`_Window` per HBM name, the SMEM output refs and the
+    scratch refs.  Windows start empty and are flushed at the end.
+    Returns ``(smem outputs, {name: flat slab})`` for ``writes`` and
+    ``hbm_out``."""
+    hbm_names = list(hbm_in)
+    out_names = [nm for nm in hbm_names if nm in writes] + list(hbm_out)
+    win_names = hbm_names + list(hbm_out)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    anyspec = pl.BlockSpec(memory_space=pl.ANY)
+    n_si, n_h = len(smem_in), len(hbm_names)
+
+    in_specs = [smem] * n_si + [anyspec] * n_h
+    out_specs = [anyspec] * len(out_names) + [smem] * len(smem_out)
+    shapes = {**{nm: hbm_in[nm].shape[0] for nm in hbm_names},
+              **dict(hbm_out)}
+    out_shape = ([jax.ShapeDtypeStruct((shapes[nm],), jnp.int32)
+                  for nm in out_names]
+                 + [jax.ShapeDtypeStruct((w,), jnp.int32)
+                    for w in smem_out])
+    scratch_shapes = ([pltpu.SMEM((BLOCK,), jnp.int32)] * len(win_names)
+                      + [pltpu.SMEM((2 * len(win_names),), jnp.int32)]
+                      + [pltpu.SMEM((max(w, 1),), jnp.int32)
+                         for w in scratch]
+                      + [pltpu.SemaphoreType.DMA])
+    aliases = {n_si + hbm_names.index(nm): k
+               for k, nm in enumerate(out_names) if nm in hbm_in}
+
+    def kernel(*refs):
+        ins = refs[:n_si]
+        R = dict(zip(hbm_names, refs[n_si:n_si + n_h]))
+        rest = refs[n_si + n_h:]
+        O = dict(zip(out_names, rest[:len(out_names)]))
+        rest = rest[len(out_names):]
+        outs = rest[:len(smem_out)]
+        rest = rest[len(smem_out):]
+        bufs = rest[:len(win_names)]
+        tags = rest[len(win_names)]
+        scr = rest[len(win_names) + 1:-1]
+        sem = rest[-1]
+        wins = {nm: _Window(O.get(nm, R.get(nm)), bufs[k], tags, k, sem)
+                for k, nm in enumerate(win_names)}
+        for w in wins.values():
+            w.reset()
+        body(ins, wins, outs, scr)
+        for w in wins.values():
+            w.flush()
+
+    res = pl.pallas_call(
+        kernel, out_shape=out_shape, in_specs=in_specs,
+        out_specs=out_specs, scratch_shapes=scratch_shapes,
+        input_output_aliases=aliases, interpret=interpret,
+    )(*smem_in, *[hbm_in[nm] for nm in hbm_names])
+    n_o = len(out_names)
+    return list(res[n_o:]), dict(zip(out_names, res[:n_o]))
+
+
+def _copy(src, dst, words):
+    def cp(k, _):
+        dst[k] = src[k]
+        return 0
+
+    _loop(0, words, cp)
+
+
+
+
+# --------------------------------------------------------------------------
+# the transactions: one kernel over (attempt, shard, class)
 # --------------------------------------------------------------------------
 #
-# Region sets per transaction: `reads` enter the kernel, `writes` come
-# back out (everything else bypasses it — arena.split/join are static
-# slices).  A region in both with blocking "hbm" is input/output-
-# aliased, so on device the transaction updates it in place.
+# Region sets per transaction: `reads` enter the kernel, `writes` are
+# aliased back out (everything else bypasses it).
 
 _READS = {
     ("page", "ring", "alloc"): ("queue_store",),
@@ -855,349 +891,170 @@ _WRITES = {
     ("chunk", "vl", "free"): ("heap", "bitmap", "free_count"),
 }
 
-
-def _region_arr(lay, parts, name):
-    r = lay.region(name)
-    return (parts[name].reshape(r.shape) if r.blocking == "row"
-            else parts[name])
-
-
-def _region_spec(lay, name):
-    r = lay.region(name)
-    if r.blocking == "row":
-        return pl.BlockSpec((1,) + r.shape[1:], lambda c, s: (c, 0))
-    if r.blocking == "resident":
-        return pl.BlockSpec((r.words,), lambda c, s: (0,))
-    return pl.BlockSpec(memory_space=pltpu.ANY)
+_PAGE_BODIES = {
+    ("ring", "alloc"): _page_ring_alloc, ("ring", "free"): _page_ring_free,
+    ("va", "alloc"): _page_va_alloc, ("va", "free"): _page_va_free,
+    ("vl", "alloc"): _page_vl_alloc, ("vl", "free"): _page_vl_free,
+}
 
 
-def _region_shape(lay, name):
-    r = lay.region(name)
-    shape = r.shape if r.blocking == "row" else (r.words,)
-    return jax.ShapeDtypeStruct(shape, jnp.int32)
+def segs_words(cfg, family, items):
+    """SMEM words for a segment chain that enqueues ``items`` items."""
+    return items // cfg.slots_per_segment(family) + 2
 
 
-def _txn_call(cfg, kind, family, op, mem, ctl, lanes, n, interpret):
+def _txn(cfg, S, walk, sharded, kind, family, op, parts, ctl, lanes,
+         interpret):
+    """One alloc/free transaction over ``S`` arena slabs.
+
+    ``parts``: ``{region: (S, words)}``; ``ctl``: ``(S, ctl_words)``;
+    ``lanes``: (lane class or -1, offsets | home shard | None).  The
+    kernel visits (attempt, shard, class) in order: attempt ``a``
+    serves the still-unserved lanes homed on ``(home + a) % S``, the
+    serial-replay schedule of DESIGN.md §9 (``S == 1``: one visit)."""
     lay = arena.layout(cfg, kind, family)
-    parts = arena.split(lay, mem)
+    C = cfg.num_classes
+    Ws = cfg.total_words
+    Cw = lay.ctl_words
     reads = _READS[(kind, family, op)]
     writes = _WRITES[(kind, family, op)]
-    C = cfg.num_classes
+    lc, extra = lanes
+    n = lc.shape[0]
+    A = walk + 1 if op == "alloc" else 1
+    chunk_free = kind == "chunk" and op == "free"
 
-    in_arrays = list(lanes) + [_region_arr(lay, parts, nm)
-                               for nm in reads]
-    in_specs = ([pl.BlockSpec((n,), lambda c, s: (0,))] * len(lanes)
-                + [_region_spec(lay, nm) for nm in reads])
+    hbm, stride = {}, {}
+    for nm in reads:
+        hbm[nm], stride[nm] = _slab(parts[nm].reshape(S, -1))
+    if chunk_free:   # full -> non-full marks, zeroed per transaction
+        hbm["aux"], stride["aux"] = _slab(
+            jnp.zeros((S, cfg.num_chunks), jnp.int32))
+        writes = writes + ("aux",)
+    smem_in = [ctl.reshape(-1).astype(jnp.int32), lc]
+    if extra is not None:
+        smem_in.append(extra.astype(jnp.int32))
+    smem_out = [S * Cw] + ([n] if op == "alloc" else [])
+    scratch = [n, segs_words(cfg, family, n), 1]
 
-    out_specs = [_region_spec(lay, nm) for nm in writes]
-    out_shapes = [_region_shape(lay, nm) for nm in writes]
-    out_specs.append(pl.BlockSpec((lay.ctl_words,), lambda c, s: (0,)))
-    out_shapes.append(jax.ShapeDtypeStruct((lay.ctl_words,), jnp.int32))
-    if op == "alloc":
-        out_specs.append(pl.BlockSpec((n,), lambda c, s: (0,)))
-        out_shapes.append(jax.ShapeDtypeStruct((n,), jnp.int32))
-    elif kind == "chunk":
-        # revived-chunk flags, computed at step 0 and read by every
-        # class step (grid-persistent VMEM block)
-        out_specs.append(pl.BlockSpec((cfg.num_chunks,),
-                                      lambda c, s: (0,)))
-        out_shapes.append(jax.ShapeDtypeStruct((cfg.num_chunks,),
-                                               jnp.int32))
+    def body(ins, wins, outs, scr):
+        ctl_in, lc_ref = ins[0], ins[1]
+        octl_ref = outs[0]
+        offs = outs[1] if op == "alloc" else None
+        msk, segs, nrev = scr
+        _copy(ctl_in, octl_ref, S * Cw)
+        if offs is not None:
+            _fill(_View(offs), 0, n, NULL)
 
-    aliases = {1 + len(lanes) + reads.index(nm): writes.index(nm)
-               for nm in writes if lay.region(nm).blocking == "hbm"}
+        def visit(a, s):
+            def sel(i, _):
+                cl = lc_ref[i]
+                if op == "alloc":
+                    ok = offs[i] < 0
+                    if sharded:
+                        ok &= (ins[2][i] + a) % S == s
+                else:
+                    ok = (ins[2][i] // Ws == s) if sharded else True
+                msk[i] = jnp.where((cl >= 0) & ok, cl, -1)
+                return 0
 
-    n_in = len(in_arrays)
-    n_w = len(writes)
+            _loop(0, n, sel)
+            obase = s * Ws if sharded else 0
+            L = _Lanes(n, msk, offs=offs,
+                       off_in=ins[2] if op == "free" else None,
+                       obase=obase)
+            E = {nm: _View(base=s * stride[nm], win=wins[nm])
+                 for nm in hbm}
+            octl = _View(octl_ref, base=s * Cw)
 
-    def kernel(ctl_ref, *refs):
-        in_refs, out_refs = refs[:n_in], refs[n_in:]
-        lane_vals = [r[...] for r in in_refs[:len(lanes)]]
-        R = dict(zip(reads, in_refs[len(lanes):]))
-        O = dict(zip(writes, out_refs[:n_w]))
-        octl = out_refs[n_w]
-        c = pl.program_id(0)
+            def cls_step(c, _):
+                pre = _tele_scalars(octl, lay, c)
+                if kind == "page":
+                    _PAGE_BODIES[(family, op)](cfg, lay, c, L, E, octl,
+                                               segs)
+                elif op == "alloc":
+                    _chunk_alloc(cfg, lay, family, c, L, E, octl, segs)
+                else:
+                    _chunk_free(cfg, lay, family, c, L, E, octl, segs,
+                                E["aux"], nrev)
+                _tele_counters(lay, octl, c, pre,
+                               _tele_scalars(octl, lay, c))
+                if op == "alloc":
+                    _tele_alloc(lay, octl, c, L, a)
+                else:
+                    _tele_free(lay, octl, c, L)
+                return 0
 
-        @pl.when(c == 0)
-        def _init():
-            octl[...] = ctl_ref[...]
-            for nm in writes:
-                blocking = lay.region(nm).blocking
-                if blocking == "resident":
-                    O[nm][...] = R[nm][...]
-                elif blocking == "hbm" and interpret:
-                    # hbm write regions are input/output-aliased: on
-                    # device in == out and this copy would be a no-op
-                    # O(region) DMA, so it exists only for interpret
-                    # mode, whose output buffers start unaliased.
-                    O[nm][...] = R[nm][...]
-            if op == "alloc":
-                out_refs[n_w + 1][...] = jnp.full((n,), NULL, jnp.int32)
+            _loop(0, C, cls_step)
 
-        for nm in writes:          # stage this class's row through VMEM
-            if lay.region(nm).blocking == "row":
-                O[nm][0, :] = R[nm][0, :]
-        E = {nm: O.get(nm, R[nm]) for nm in reads}
+        _loop(0, A, lambda a, _: (_loop(
+            0, S, lambda s, __: (visit(a, s), 0)[1]), 0)[1])
 
-        pre = _tele_scalars(octl, lay, c)
-        if op == "alloc":
-            offs_ref = out_refs[n_w + 1]
-            cur = offs_ref[...]
-            if kind == "page":
-                fn = {"ring": _page_ring_alloc, "va": _page_va_alloc,
-                      "vl": _page_vl_alloc}[family]
-                fn(cfg, lay, c, lane_vals[0], lane_vals[1], E, octl,
-                   offs_ref)
-            else:
-                _chunk_alloc(cfg, lay, family, c, lane_vals[0],
-                             lane_vals[1], E, octl, offs_ref)
-            _tele_counters(lay, octl, c, pre, _tele_scalars(octl, lay, c))
-            _tele_alloc(cfg, lay, octl, c, lane_vals[0], lane_vals[1],
-                        cur, offs_ref[...], 0)
-        else:
-            offsets, sizes, valid = lane_vals
-            if kind == "page":
-                fn = {"ring": _page_ring_free, "va": _page_va_free,
-                      "vl": _page_vl_free}[family]
-                fn(cfg, lay, c, offsets, sizes, valid, E, octl)
-            else:
-                _chunk_free(cfg, lay, family, c, offsets, sizes, valid,
-                            E, octl, out_refs[n_w + 1],
-                            R["free_count"])
-            _tele_counters(lay, octl, c, pre, _tele_scalars(octl, lay, c))
-            _tele_free(cfg, lay, octl, c, offsets, sizes, valid)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(C,),
-        in_specs=in_specs, out_specs=out_specs)
-    outs = pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shapes,
-        input_output_aliases=aliases, interpret=interpret,
-    )(ctl.astype(jnp.int32), *in_arrays)
-
+    souts, houts = launch(body, smem_in=smem_in, hbm_in=hbm,
+                          writes=writes, smem_out=smem_out,
+                          scratch=scratch, interpret=interpret)
     new_parts = dict(parts)
-    for nm, val in zip(writes, outs[:n_w]):
-        new_parts[nm] = val
-    return arena.join(lay, new_parts), outs[n_w], outs[n_w + 1:]
+    for nm in _WRITES[(kind, family, op)]:
+        new_parts[nm] = _unslab(houts[nm], S, parts[nm].reshape(S, -1)
+                                .shape[1])
+    offsets = souts[1] if op == "alloc" else None
+    return new_parts, souts[0].reshape(S, Cw), offsets
+
+
+def _lane_class(cfg, sizes, mask, offsets=None):
+    """Lane prep in XLA: each lane's size class, or -1 when the lane is
+    masked, over-large, or (free) carries a negative offset."""
+    cls = size_to_class_device(cfg, sizes)
+    ok = (mask != 0) & (cls < cfg.num_classes)
+    if offsets is not None:
+        ok &= offsets >= 0
+    return jnp.where(ok, cls, -1).astype(jnp.int32)
+
+
+def _single(cfg, kind, family, op, mem, ctl, lanes, interpret):
+    lay = arena.layout(cfg, kind, family)
+    parts = arena.split(lay, mem)
+    new_parts, octl, offs = _txn(
+        cfg, 1, 0, False, kind, family, op,
+        {nm: p.reshape(1, -1) for nm, p in parts.items()},
+        ctl.reshape(1, -1), lanes, interpret)
+    flat = {nm: p.reshape(-1) for nm, p in new_parts.items()}
+    return arena.join(lay, flat), octl.reshape(-1), offs
 
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "kind", "family", "interpret"))
 def arena_alloc_txn_blocked(cfg, kind, family, mem, ctl, sizes_bytes,
                             mask, *, interpret: bool = False):
-    """Region-blocked whole-transaction alloc: ONE ``pallas_call``,
-    bit-identical to ``transactions.alloc_math`` and to the whole-arena
+    """Blocked whole-transaction alloc: ONE ``pallas_call``,
+    bit-identical to ``transactions.alloc_math`` and to the whole
     lowering.  Returns ``(new_mem, new_ctl, offsets)``."""
-    n = sizes_bytes.shape[0]
-    lanes = (sizes_bytes.astype(jnp.int32), mask.astype(jnp.int32))
-    mem2, octl, extra = _txn_call(cfg, kind, family, "alloc", mem, ctl,
-                                  lanes, n, interpret)
-    return mem2, octl, extra[0]
+    lc = _lane_class(cfg, sizes_bytes.astype(jnp.int32), mask)
+    return _single(cfg, kind, family, "alloc", mem, ctl, (lc, None),
+                   interpret)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "kind", "family", "interpret"))
 def arena_free_txn_blocked(cfg, kind, family, mem, ctl, offsets_words,
                            sizes_bytes, mask, *, interpret: bool = False):
-    """Region-blocked whole-transaction free.  Returns
-    ``(new_mem, new_ctl)``."""
-    n = sizes_bytes.shape[0]
-    lanes = (offsets_words.astype(jnp.int32),
-             sizes_bytes.astype(jnp.int32), mask.astype(jnp.int32))
-    mem2, octl, _ = _txn_call(cfg, kind, family, "free", mem, ctl,
-                              lanes, n, interpret)
+    """Blocked whole-transaction free.  Returns ``(new_mem, new_ctl)``."""
+    offs = offsets_words.astype(jnp.int32)
+    lc = _lane_class(cfg, sizes_bytes.astype(jnp.int32), mask, offs)
+    mem2, octl, _ = _single(cfg, kind, family, "free", mem, ctl,
+                            (lc, offs), interpret)
     return mem2, octl
 
 
-# --------------------------------------------------------------------------
-# sharded wrapper: the (attempt, shard, class) grid (DESIGN.md §9)
-# --------------------------------------------------------------------------
-#
-# The sharded blocked lowering reuses every per-class body above
-# untouched: the grid grows two leading dimensions — attempt a (the
-# overflow walk; 1 for free) and shard s — and every region spec gains
-# a shard coordinate:
-#
-# - row regions stack to (S·C, w) and step (a, s, c) stages row
-#   s·C + c — still exactly one class row in VMEM per step;
-# - resident regions stack flat to (S·words,) with a (words,) block
-#   selected by s, so the bodies keep seeing a single shard's block;
-# - hbm regions stay ONE flat (S·words,) ANY ref; the bodies receive a
-#   _ShardView(ref, s·words), so every word address they compute lands
-#   in shard s's slice;
-# - ctl prefetches flat (S·ctl_words,); the accumulator output is
-#   blocked per shard the same way.
-#
-# Output blocks are staged from the inputs on each (shard, row)'s
-# FIRST visit only (a == 0): later attempts revisit the block and must
-# see the earlier attempts' updates, not the boundary state.  The
-# per-class bodies return shard-LOCAL offsets; the wrapper globalizes
-# newly-served lanes (prev < 0, new >= 0) with s · shard_words before
-# the next grid step, which is also what keeps the "still unserved"
-# test (offs < 0) correct across attempts.
-
-def _txn_call_sharded(cfg, num_shards, walk, kind, family, op, mem, ctl,
-                      lanes, n, interpret):
+def _sharded(cfg, num_shards, walk, kind, family, op, mem, ctl, lanes,
+             interpret):
     from repro.core import shards as _shards  # lazy: kernels <-> core
 
-    S = num_shards
-    scfg = _shards.shard_config(cfg, S)
-    slay = _shards.layout(cfg, S, kind, family)
-    lay = slay.shard
-    Ws = scfg.total_words
-    C = scfg.num_classes
-    Cw = lay.ctl_words
+    scfg = _shards.shard_config(cfg, num_shards)
+    slay = _shards.layout(cfg, num_shards, kind, family)
     parts = _shards.split_regions(slay, mem)   # {name: (S, words)}
-    reads = _READS[(kind, family, op)]
-    writes = _WRITES[(kind, family, op)]
-    A = walk + 1 if op == "alloc" else 1
-    hbm_words = {nm: lay.region(nm).words
-                 for nm in set(reads) | set(writes)
-                 if lay.region(nm).blocking == "hbm"}
-
-    def _arr(name):
-        r = lay.region(name)
-        p = parts[name]
-        if r.blocking == "row":
-            return p.reshape(S * r.shape[0], r.shape[1])
-        return p.reshape(S * r.words)
-
-    def _spec(name):
-        r = lay.region(name)
-        if r.blocking == "row":
-            return pl.BlockSpec((1, r.shape[1]),
-                                lambda a, s, c, t, C=C: (s * C + c, 0))
-        if r.blocking == "resident":
-            return pl.BlockSpec((r.words,), lambda a, s, c, t: (s,))
-        return pl.BlockSpec(memory_space=pltpu.ANY)
-
-    def _oshape(name):
-        r = lay.region(name)
-        if r.blocking == "row":
-            return jax.ShapeDtypeStruct((S * r.shape[0], r.shape[1]),
-                                        jnp.int32)
-        return jax.ShapeDtypeStruct((S * r.words,), jnp.int32)
-
-    lane_spec = pl.BlockSpec((n,), lambda a, s, c, t: (0,))
-    in_arrays = list(lanes) + [_arr(nm) for nm in reads]
-    in_specs = [lane_spec] * len(lanes) + [_spec(nm) for nm in reads]
-
-    out_specs = [_spec(nm) for nm in writes]
-    out_shapes = [_oshape(nm) for nm in writes]
-    out_specs.append(pl.BlockSpec((Cw,), lambda a, s, c, t: (s,)))
-    out_shapes.append(jax.ShapeDtypeStruct((S * Cw,), jnp.int32))
-    if op == "alloc":
-        out_specs.append(pl.BlockSpec((n,), lambda a, s, c, t: (0,)))
-        out_shapes.append(jax.ShapeDtypeStruct((n,), jnp.int32))
-    elif kind == "chunk":
-        # revived-chunk flags, per shard (computed at the shard's
-        # c == 0 step, read by its every class step)
-        out_specs.append(pl.BlockSpec((scfg.num_chunks,),
-                                      lambda a, s, c, t: (s,)))
-        out_shapes.append(jax.ShapeDtypeStruct((S * scfg.num_chunks,),
-                                               jnp.int32))
-
-    aliases = {1 + len(lanes) + reads.index(nm): writes.index(nm)
-               for nm in writes if lay.region(nm).blocking == "hbm"}
-
-    n_in = len(in_arrays)
-    n_w = len(writes)
-
-    def kernel(ctl_ref, *refs):
-        in_refs, out_refs = refs[:n_in], refs[n_in:]
-        lane_vals = [r[...] for r in in_refs[:len(lanes)]]
-        R = dict(zip(reads, in_refs[len(lanes):]))
-        O = dict(zip(writes, out_refs[:n_w]))
-        octl = out_refs[n_w]
-        a = pl.program_id(0)
-        s = pl.program_id(1)
-        c = pl.program_id(2)
-
-        @pl.when((a == 0) & (s == 0) & (c == 0))
-        def _once():
-            if interpret:
-                # hbm write regions are input/output-aliased: on device
-                # this copy is a no-op; interpret-mode output buffers
-                # start unaliased (as in _txn_call).
-                for nm in writes:
-                    if lay.region(nm).blocking == "hbm":
-                        O[nm][...] = R[nm][...]
-            if op == "alloc":
-                out_refs[n_w + 1][...] = jnp.full((n,), NULL, jnp.int32)
-
-        @pl.when((a == 0) & (c == 0))
-        def _per_shard():
-            octl[...] = pl.load(ctl_ref, (pl.ds(s * Cw, Cw),))
-            for nm in writes:
-                if lay.region(nm).blocking == "resident":
-                    O[nm][...] = R[nm][...]
-
-        @pl.when(a == 0)
-        def _stage_rows():   # each (s, c) row's first (only input) copy
-            for nm in writes:
-                if lay.region(nm).blocking == "row":
-                    O[nm][0, :] = R[nm][0, :]
-
-        def _wrap(nm, ref):
-            if lay.region(nm).blocking == "hbm":
-                return _ShardView(ref, s * hbm_words[nm])
-            return ref
-
-        E = {nm: _wrap(nm, O.get(nm, R[nm])) for nm in reads}
-
-        pre = _tele_scalars(octl, lay, c)
-        if op == "alloc":
-            sizes, valid, home = lane_vals
-            offs_ref = out_refs[n_w + 1]
-            cur = offs_ref[...]
-            sel = ((valid != 0) & ((home + a) % S == s) & (cur < 0))
-            sel_i = sel.astype(jnp.int32)
-            if kind == "page":
-                fn = {"ring": _page_ring_alloc, "va": _page_va_alloc,
-                      "vl": _page_vl_alloc}[family]
-                fn(scfg, lay, c, sizes, sel_i, E, octl, offs_ref)
-            else:
-                _chunk_alloc(scfg, lay, family, c, sizes, sel_i, E,
-                             octl, offs_ref)
-            new = offs_ref[...]
-            _tele_counters(lay, octl, c, pre, _tele_scalars(octl, lay, c))
-            # counts from the shard-LOCAL offsets, mask = this visit's
-            # selection — matches the oracle's per-(attempt, shard)
-            # alloc_math telemetry attribution
-            _tele_alloc(scfg, lay, octl, c, sizes, sel_i, cur, new, a)
-            offs_ref[...] = jnp.where((cur < 0) & (new >= 0),
-                                      new + s * Ws, new)
-        else:
-            offsets, sizes, valid = lane_vals
-            sh = jnp.where(offsets >= 0, offsets // Ws, -1)
-            sel = (valid != 0) & (sh == s)
-            local = jnp.where(sel, offsets - s * Ws, -1)
-            sel_i = sel.astype(jnp.int32)
-            if kind == "page":
-                fn = {"ring": _page_ring_free, "va": _page_va_free,
-                      "vl": _page_vl_free}[family]
-                fn(scfg, lay, c, local, sizes, sel_i, E, octl)
-            else:
-                _chunk_free(scfg, lay, family, c, local, sizes, sel_i,
-                            E, octl, out_refs[n_w + 1],
-                            R["free_count"])
-            _tele_counters(lay, octl, c, pre, _tele_scalars(octl, lay, c))
-            _tele_free(scfg, lay, octl, c, local, sizes, sel_i)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(A, S, C),
-        in_specs=in_specs, out_specs=out_specs)
-    outs = pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shapes,
-        input_output_aliases=aliases, interpret=interpret,
-    )(ctl.reshape(-1).astype(jnp.int32), *in_arrays)
-
-    new_parts = dict(parts)
-    for nm, val in zip(writes, outs[:n_w]):
-        new_parts[nm] = val.reshape(S, -1)
-    new_mem = _shards.join_regions(slay, new_parts)
-    new_ctl = outs[n_w].reshape(S, Cw)
-    return new_mem, new_ctl, outs[n_w + 1:]
+    new_parts, octl, offs = _txn(scfg, num_shards, walk, True, kind,
+                                 family, op, parts, ctl, lanes, interpret)
+    return _shards.join_regions(slay, new_parts), octl, offs
 
 
 @functools.partial(jax.jit,
@@ -1206,17 +1063,16 @@ def _txn_call_sharded(cfg, num_shards, walk, kind, family, op, mem, ctl,
 def sharded_arena_alloc_txn_blocked(cfg, num_shards, kind, family, mem,
                                     ctl, sizes_bytes, mask, home, walk,
                                     *, interpret: bool = False):
-    """Sharded region-blocked alloc: ONE ``pallas_call`` over the
-    (attempt, shard, class) grid, bit-identical to
+    """Sharded blocked alloc: ONE ``pallas_call`` over the
+    (attempt, shard, class) schedule, bit-identical to
     ``transactions.sharded_alloc_math`` and to the sharded whole
     lowering.  Returns ``(new_mem, new_ctl, global_offsets)``."""
-    n = sizes_bytes.shape[0]
-    lanes = (sizes_bytes.astype(jnp.int32), mask.astype(jnp.int32),
-             home.astype(jnp.int32))
-    mem2, octl, extra = _txn_call_sharded(cfg, num_shards, walk, kind,
-                                          family, "alloc", mem, ctl,
-                                          lanes, n, interpret)
-    return mem2, octl, extra[0]
+    from repro.core import shards as _shards  # lazy: kernels <-> core
+
+    scfg = _shards.shard_config(cfg, num_shards)
+    lc = _lane_class(scfg, sizes_bytes.astype(jnp.int32), mask)
+    return _sharded(cfg, num_shards, walk, kind, family, "alloc", mem,
+                    ctl, (lc, home), interpret)
 
 
 @functools.partial(jax.jit,
@@ -1225,12 +1081,13 @@ def sharded_arena_alloc_txn_blocked(cfg, num_shards, kind, family, mem,
 def sharded_arena_free_txn_blocked(cfg, num_shards, kind, family, mem,
                                    ctl, offsets_words, sizes_bytes,
                                    mask, *, interpret: bool = False):
-    """Sharded region-blocked free: grid (1, shard, class).  Returns
+    """Sharded blocked free over the (shard, class) schedule.  Returns
     ``(new_mem, new_ctl)``."""
-    n = sizes_bytes.shape[0]
-    lanes = (offsets_words.astype(jnp.int32),
-             sizes_bytes.astype(jnp.int32), mask.astype(jnp.int32))
-    mem2, octl, _ = _txn_call_sharded(cfg, num_shards, 0, kind, family,
-                                      "free", mem, ctl, lanes, n,
-                                      interpret)
+    from repro.core import shards as _shards  # lazy: kernels <-> core
+
+    scfg = _shards.shard_config(cfg, num_shards)
+    offs = offsets_words.astype(jnp.int32)
+    lc = _lane_class(scfg, sizes_bytes.astype(jnp.int32), mask, offs)
+    mem2, octl, _ = _sharded(cfg, num_shards, 0, kind, family, "free",
+                             mem, ctl, (lc, offs), interpret)
     return mem2, octl

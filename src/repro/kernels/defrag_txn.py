@@ -12,29 +12,23 @@ lowerings, exactly like the alloc/free transactions:
     kernels/alloc_txn.arena_*_txn); ``mem``/``ctl`` are input/output-
     aliased so the wave rewrites the arena in place.
 
-``arena_defrag_txn_blocked`` (region-blocked lowering)
-    the §8 discipline applied to a wave: grid over the size classes,
-    control block as scalar prefetch accumulated in a resident VMEM
-    block, pool/free-count/binding regions resident, queue ring or
-    directory rows staged per class step, heap and bitmaps as HBM(ANY)
-    refs touched word-by-word through the alloc_txn_blocked DMA
-    vocabulary.  Step 0 runs the migration (extract every source
-    extent into a carry buffer, insert at the destinations — windowed
-    row loads/stores, bit RMWs) plus the unbind/pool re-prime; every
-    step ``c`` then rebuilds class ``c``'s queue in the oracle's
-    class-major order.  NOTE: defrag writes regions that alloc/free
-    never touch (the chunk-ring heap, for one), so the region
-    treatment here is defrag's own table, not ``Region.blocking``.
+``arena_defrag_txn_blocked`` (blocked lowering, the one the chip runs)
+    the wave as scalar-core code over the region windows of
+    kernels/alloc_txn_blocked.py: every region is an aliased HBM slab
+    reached through a 1024-word SMEM window, the control block lives in
+    SMEM.  The kernel extracts every source extent into an HBM carry
+    buffer, inserts at the destinations (word copies, bit flips), then
+    unbinds emptied chunks, re-primes the pool and rebuilds each class
+    queue in the oracle's class-major order.
 
 ``sharded_arena_defrag_txn`` / ``sharded_arena_defrag_txn_blocked``
-    the (phase, shard) schedule of ``defrag.sharded_migrate_math`` as
-    one grid — phase 0 extracts every source shard's extents into the
-    carry buffer, phase 1 inserts and rebuilds every shard — so a
-    single wave covers in-shard compaction AND cross-shard rebalance
-    moves.  The whole lowering grids (2, S) over shard slabs; the
-    blocked lowering grids (2, S, C) with the §9 region stacking
-    (rows at ``s·C + c``, resident blocks per shard, hbm regions as
-    flat ``(S·words,)`` ANY refs through ``_ShardView``).
+    the (phase, shard) schedule of ``defrag.sharded_migrate_math`` —
+    phase 0 extracts every source shard's extents into the carry
+    buffer, phase 1 inserts and rebuilds every shard — so a single
+    wave covers in-shard compaction AND cross-shard rebalance moves.
+    The whole lowering grids (2, S) over shard slabs; the blocked
+    lowering loops over the shards, addressing shard ``s`` at its
+    slab base in every region.
 
 The plan (``src``/``dst``/``sizes`` forwarding table) is computed once
 in pure jnp and shared by every backend; the execute contract assumes
@@ -50,16 +44,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import arena
 from repro.core.heap import size_to_class_device
-from repro.kernels.alloc_txn import _iota
-from repro.kernels.alloc_txn_blocked import (NULL, _ShardView, _ld_if,
-                                             _pool_pop1, _row_ld,
-                                             _row_st_if, _st, _st_if,
-                                             _take, _va_grow, _vec_ld,
-                                             _vec_st_if, _vl_grow)
+from repro.kernels.alloc_txn_blocked import (BLOCK, NULL, _bind, _copy,
+                                             _fill, _flip_page, _i32, _ld,
+                                             _loop, _pool_pop1, _slab, _st,
+                                             _st_if, _unslab, _va_grow,
+                                             _View, _vl_grow, launch,
+                                             segs_words)
 
 
 # --------------------------------------------------------------------------
@@ -173,348 +166,259 @@ def sharded_arena_defrag_txn(cfg, num_shards, kind, family, mem, ctl,
 
 
 # --------------------------------------------------------------------------
-# blocked lowering: per-region waves under the §8 discipline
+# blocked lowering: the wave as scalar-core code over region windows
 # --------------------------------------------------------------------------
 #
-# Defrag's own region treatment (alloc/free's Region.blocking does not
-# apply — a wave writes the heap for every chunk family):
-#
-#   heap, bitmap                      hbm (ANY; word/window DMAs)
-#   pool_store, free_count,
-#   chunk_class                       resident VMEM blocks
-#   queue_store / directory           one row per class grid step
-#
-# Every region is both read and written; hbm regions are input/output-
-# aliased.  The carry buffer rides as one grid-persistent VMEM block.
+# Every region is both read and written, so every region is an aliased
+# HBM slab reached through its window (alloc_txn_blocked.launch); the
+# carry buffer is one more HBM output.  The kernel runs the extract
+# phase over every shard, then per shard the insert phase, the unbind
+# and pool re-prime, and the class-major queue rebuild.
 
-_HBM = ("heap", "bitmap")
-_RESIDENT = ("pool_store", "free_count", "chunk_class")
-
-
-def _move_lane_prep(cfg, offsets, sizes, sel_i):
+def _moves(cfg, ins, sharded, s, phase):
+    """Move ``i`` of this (phase, shard) visit: (selected, shard-local
+    offset, page words, class).  A move is selected when both ends are
+    planned, its class is valid and (sharded) its end for this phase
+    lives on shard ``s``."""
     C = cfg.num_classes
-    cls = size_to_class_device(cfg, sizes)
-    valid = (sel_i != 0) & (offsets >= 0) & (cls < C)
-    pw = jnp.left_shift(cfg.page_words(0), cls % C).astype(jnp.int32)
-    return valid.astype(jnp.int32), pw
+    Ws = cfg.total_words
+    src, dst, cls = ins[1], ins[2], ins[3]
+
+    def mv(i):
+        end = (src if phase == "extract" else dst)[i]
+        ok = (src[i] >= 0) & (dst[i] >= 0) & (cls[i] < C)
+        if sharded:
+            ok &= end // Ws == s
+        local = end - s * Ws if sharded else end
+        pw = jnp.left_shift(_i32(cfg.page_words(0)), cls[i] % C)
+        return ok, jnp.where(ok, local, 0), pw, cls[i]
+
+    return mv
 
 
-def _rot(win, shift, maxw):
-    """``win`` rotated left by ``shift`` (traced): out[k] = win[(k +
-    shift) % maxw] — the windowed-copy alignment primitive."""
-    padded = jnp.concatenate([win, win])
-    return jax.lax.dynamic_slice(padded, (shift,), (maxw,))
-
-
-def _extract_moves(cfg, lay, E, buf_ref, src, sizes, sel_i):
-    """Blocked extract: per move, stage its heap window, align, write
-    its carry-buffer row, clear its bitmap bit, bump its chunk's free
-    count — ``defrag.extract_math`` at window/word granularity."""
-    W = cfg.total_words
-    wpc = cfg.words_per_chunk
-    bw = cfg.bitmap_words_per_chunk
-    maxw = wpc
-    M = src.shape[0]
-    valid_i, pw_v = _move_lane_prep(cfg, src, sizes, sel_i)
-    heap_ref = E["heap"]
-    bitmap_ref = E["bitmap"]
-    fc_ref = E["free_count"]
-    kk = _iota(maxw)
+def _extract_moves(cfg, E, carry, mv, M):
+    """Per move: copy its page words into its carry row, clear its
+    bitmap bit, give the page back to its chunk's free count —
+    ``defrag.extract_math`` one word at a time."""
+    maxw = cfg.words_per_chunk
+    heap = E["heap"]
 
     def move(i, _):
-        g = _take(valid_i, i) != 0
-        s = jnp.where(g, _take(src, i), 0)
-        pw = _take(pw_v, i)
-        bs = jnp.clip(s, 0, W - maxw)
-        win = _vec_ld(heap_ref, bs, maxw)
-        vals = _rot(win, s - bs, maxw)            # vals[k] = heap[s+k]
-        old = pl.load(buf_ref, (pl.ds(i * maxw, maxw),))
-        new = jnp.where(g & (kk < pw), vals, old)
-        pl.store(buf_ref, (pl.ds(i * maxw, maxw),), new)
-        # clear the source bit, return the page to its chunk
-        ch = s // wpc
-        pg = (s % wpc) // pw
-        a = ch * bw + pg // 32
-        row_u = jax.lax.bitcast_convert_type(
-            jnp.reshape(_ld_if(bitmap_ref, a, g, 0), (1,)), jnp.uint32)
-        bit = jnp.uint32(1) << (pg % 32).astype(jnp.uint32)
-        _st_if(bitmap_ref, a,
-               jax.lax.bitcast_convert_type(row_u - bit, jnp.int32)[0], g)
-        cur = _ld_if(fc_ref, ch, g, 0)
-        _st_if(fc_ref, ch, cur + 1, g)
+        g, s, pw, _c = mv(i)
+
+        @pl.when(g)
+        def _():
+            def cp(k, _):
+                _st(carry, i * maxw + k, _ld(heap, s + k))
+                return 0
+
+            _loop(0, pw, cp)
+            _flip_page(cfg, E, s, pw, -1)
         return 0
 
-    jax.lax.fori_loop(0, M, move, 0)
+    _loop(0, M, move)
 
 
-def _insert_moves(cfg, lay, E, buf_ref, dst, sizes, sel_i):
-    """Blocked insert: per move, place its carry-buffer row into the
-    destination window (RMW), claim the destination chunk if it is
-    still unbound (bitmap reset, full count, bind — alloc's from-pool
-    path, which cross-shard rebalance moves rely on), set the bit,
-    take the page from the chunk's free count —
+def _insert_moves(cfg, E, carry, mv, M):
+    """Per move: place its carry row at the destination, claim the
+    destination chunk if it is still unbound (bitmap reset, full count,
+    bind — alloc's from-pool path, which cross-shard rebalance moves
+    rely on), set the bit, take the page from the free count —
     ``defrag.insert_rebuild_math``'s insert half."""
-    C = cfg.num_classes
-    W = cfg.total_words
     wpc = cfg.words_per_chunk
-    bw = cfg.bitmap_words_per_chunk
     maxw = wpc
-    M = dst.shape[0]
-    valid_i, pw_v = _move_lane_prep(cfg, dst, sizes, sel_i)
-    cls_v = size_to_class_device(cfg, sizes)
-    heap_ref = E["heap"]
-    bitmap_ref = E["bitmap"]
-    fc_ref = E["free_count"]
-    cc_ref = E["chunk_class"]
-    kk = _iota(maxw)
+    heap = E["heap"]
 
     def move(i, _):
-        g = _take(valid_i, i) != 0
-        d = jnp.where(g, _take(dst, i), 0)
-        pw = _take(pw_v, i)
-        cls = _take(cls_v, i)
-        vals = pl.load(buf_ref, (pl.ds(i * maxw, maxw),))
-        bd = jnp.clip(d, 0, W - maxw)
-        sh = d - bd
-        dwin = _vec_ld(heap_ref, bd, maxw)
-        placed = _rot(vals, maxw - sh, maxw)      # placed[sh+k] = vals[k]
-        mask = g & (kk >= sh) & (kk < sh + pw)
-        _vec_st_if(heap_ref, bd, jnp.where(mask, placed, dwin), g)
-        ch = d // wpc
-        # claim a still-unbound destination chunk (sequential per-move:
-        # the first move targeting it claims, later ones see it bound)
-        claim = g & (_ld_if(cc_ref, ch, g, 0) < 0)
-        ppc = jnp.right_shift(cfg.max_pages_per_chunk,
-                              jnp.clip(cls, 0, C - 1))
-        _vec_st_if(bitmap_ref, ch * bw, jnp.zeros(bw, jnp.int32), claim)
-        _st_if(fc_ref, ch, ppc, claim)
-        _st_if(cc_ref, ch, cls, claim)
-        pg = (d % wpc) // pw
-        a = ch * bw + pg // 32
-        row_u = jax.lax.bitcast_convert_type(
-            jnp.reshape(_ld_if(bitmap_ref, a, g, 0), (1,)), jnp.uint32)
-        bit = jnp.uint32(1) << (pg % 32).astype(jnp.uint32)
-        _st_if(bitmap_ref, a,
-               jax.lax.bitcast_convert_type(row_u + bit, jnp.int32)[0], g)
-        cur = _ld_if(fc_ref, ch, g, 0)
-        _st_if(fc_ref, ch, cur - 1, g)
+        g, d, pw, cls = mv(i)
+
+        @pl.when(g)
+        def _():
+            def cp(k, _):
+                _st(heap, d + k, _ld(carry, i * maxw + k))
+                return 0
+
+            _loop(0, pw, cp)
+            ch = d // wpc
+
+            @pl.when(_ld(E["chunk_class"], ch) < 0)
+            def _claim():
+                _bind(cfg, E, ch, cls)
+
+            _flip_page(cfg, E, d, pw, 1)
         return 0
 
-    jax.lax.fori_loop(0, M, move, 0)
+    _loop(0, M, move)
 
 
 def _unbind_and_pool(cfg, lay, E, octl):
     """Unbind fully-free chunks and re-prime the pool ring with every
-    unbound id (ascending) — the vectorized resident-block half of the
-    oracle's rebuild."""
+    unbound id (ascending), NULL behind them."""
     C = cfg.num_classes
     nc = cfg.num_chunks
-    cc_ref = E["chunk_class"]
-    fc_ref = E["free_count"]
-    pool_ref = E["pool_store"]
-    cc = cc_ref[...]
-    fc = fc_ref[...]
-    full_count = jnp.right_shift(cfg.max_pages_per_chunk,
-                                 jnp.clip(cc, 0, C - 1))
-    cc2 = jnp.where((cc >= 0) & (fc == full_count), -1, cc)
-    cc_ref[...] = cc2
-    unbound = cc2 < 0
-    ui = unbound.astype(jnp.int32)
-    rank = jnp.cumsum(ui) - ui
-    k = jnp.sum(ui)
-    ids = _iota(nc)
-    onehot = unbound[None, :] & (rank[None, :] == ids[:, None])
-    row = jnp.sum(jnp.where(onehot, ids[None, :], 0), axis=1)
-    pool_ref[...] = jnp.where(ids < k, row, NULL)
+    cc, fc, pool = E["chunk_class"], E["free_count"], E["pool_store"]
+
+    def scan(k, n_un):
+        ccv = _ld(cc, k)
+        full = jnp.right_shift(_i32(cfg.max_pages_per_chunk),
+                               jnp.clip(ccv, 0, C - 1))
+        cc2 = jnp.where((ccv >= 0) & (_ld(fc, k) == full), -1, ccv)
+        _st(cc, k, cc2)
+        _st_if(pool, n_un, k, cc2 < 0)
+        return n_un + (cc2 < 0).astype(jnp.int32)
+
+    k = _loop(0, nc, scan)
+    _fill(pool, k, nc - k, NULL)
     _st(octl, lay.off_pool_front, 0)
     _st(octl, lay.off_pool_back, k)
 
 
-def _rebuild_class(cfg, lay, family, c, E, octl):
+def _rebuild_class(cfg, lay, family, c, E, octl, segs):
     """Rebuild class ``c``'s queue from the surviving live chunks —
-    the per-class grid step of the oracle's class-major rebuild (fresh
+    the class step of the oracle's class-major rebuild (fresh
     counters, one fresh segment for virtualized families, then the
     ascending-id enqueue of every bound chunk with free pages)."""
-    C = cfg.num_classes
     nc = cfg.num_chunks
     wpc = cfg.words_per_chunk
     W = cfg.total_words
     spc = cfg.slots_per_segment(family)
     max_segs = lay.max_segs
-    m = nc // spc + 1
+    cc, fc = E["chunk_class"], E["free_count"]
+    pool = E["pool_store"]
+    heap = E.get("heap")
 
-    cc = E["chunk_class"][...]
-    fc = E["free_count"][...]
-    live = (cc == c) & (fc > 0)
-    ai = live.astype(jnp.int32)
-    rank_v = jnp.cumsum(ai) - ai
-    cnt = jnp.sum(ai)
-    pool_ref = E["pool_store"]
-    heap_ref = E.get("heap")
-    qrow = E.get("queue_store")
-    dir_ref = E.get("directory")
+    def live(k):
+        return (_ld(cc, k) == c) & (_ld(fc, k) > 0)
 
+    def for_live(fn):
+        def step(k, r):
+            lv = live(k)
+
+            @pl.when(lv)
+            def _():
+                fn(k, r)
+
+            return r + lv.astype(jnp.int32)
+
+        return _loop(0, nc, step)
+
+    cnt = _loop(0, nc, lambda k, a: a + live(k).astype(jnp.int32))
     _st(octl, lay.off_front + c, 0)
     _st(octl, lay.off_back + c, 0)
 
     if family == "ring":
-        cap = qrow.shape[1]
-        qrow[0, :] = jnp.full((cap,), NULL, jnp.int32)
+        q = E["queue_store"]
+        cap = lay.queue_capacity
+        _fill(q, c * cap, cap, NULL)
         _st(octl, lay.off_head + c, 0)
         _st(octl, lay.off_tail + c, 0)
-
-        def put(kk, _):
-            _row_st_if(qrow, _take(rank_v, kk) % cap, kk,
-                       _take(ai, kk) != 0)
-            return 0
-
-        jax.lax.fori_loop(0, nc, put, 0)
+        for_live(lambda k, r: _st(q, c * cap + r % cap, k))
         _st(octl, lay.off_back + c, cnt)
         return
 
     # virtualized families: one fresh segment, popped in class order
-    dir_ref[0, :] = jnp.full((max_segs,), NULL, jnp.int32)
-    s0 = _pool_pop1(octl, pool_ref, lay, jnp.asarray(True))
+    dirv = E["directory"]
+    row = c * max_segs
+    _fill(dirv, row, max_segs, NULL)
+    s0 = _pool_pop1(octl, pool, lay, True)
     if family == "va":
-        _row_st_if(dir_ref, 0, s0, jnp.asarray(True))
+        _st(dirv, row, s0)
     else:  # vl: terminate the fresh head segment
-        w0 = s0 * wpc
-        _st_if(heap_ref, w0, NULL, (w0 >= 0) & (w0 < W))
+        _st_if(heap, s0 * wpc, NULL, (s0 * wpc >= 0) & (s0 * wpc < W))
     _st(octl, lay.off_head + c, s0)
     _st(octl, lay.off_tail + c, s0)
 
     if family == "va":
-        _va_grow(octl, pool_ref, dir_ref, lay, spc, jnp.int32(0), cnt, m)
+        _va_grow(octl, pool, dirv, lay, c, spc, _i32(0), cnt)
 
-        def put(kk, _):
-            v = _take(rank_v, kk)
-            seg = _row_ld(dir_ref, (v // spc) % max_segs)
-            word = seg * wpc + v % spc
-            _st_if(heap_ref, word, kk,
-                   (_take(ai, kk) != 0) & (word >= 0) & (word < W))
-            return 0
-
-        jax.lax.fori_loop(0, nc, put, 0)
+        def put(k, r):
+            seg = _ld(dirv, row + (r // spc) % max_segs)
+            word = seg * wpc + r % spc
+            _st_if(heap, word, k, (word >= 0) & (word < W))
     else:  # vl
-        new_chunks, new_tail = _vl_grow(octl, pool_ref, heap_ref, lay,
-                                        spc, wpc, W, s0, jnp.int32(0),
-                                        cnt, m)
-        seg_vec = jnp.stack([s0] + new_chunks)
+        new_tail = _vl_grow(octl, pool, heap, segs, lay, spc, s0, _i32(0),
+                            cnt)
 
-        def put(kk, _):
-            v = _take(rank_v, kk)
-            seg = _take(seg_vec, v // spc)
-            word = seg * wpc + 1 + v % spc
-            _st_if(heap_ref, word, kk,
-                   (_take(ai, kk) != 0) & (word >= 0) & (word < W))
-            return 0
+        def put(k, r):
+            word = segs[r // spc] * wpc + 1 + r % spc
+            _st_if(heap, word, k, (word >= 0) & (word < W))
 
-        jax.lax.fori_loop(0, nc, put, 0)
+    for_live(put)
+    if family == "vl":
         _st(octl, lay.off_tail + c, new_tail)
     _st(octl, lay.off_back + c, cnt)
 
 
-def _defrag_regions(lay):
-    """(region name, treatment) pairs for this layout, in region order."""
-    out = []
-    for r in lay.regions:
-        if r.name in _HBM:
-            out.append((r.name, "hbm"))
-        elif r.name in _RESIDENT:
-            out.append((r.name, "resident"))
-        else:
-            out.append((r.name, "row"))
-    return out
+def _wave(cfg, S, sharded, kind, family, parts, ctl, src, dst, sizes,
+          interpret):
+    """One migration wave over ``S`` arena slabs (``parts``: ``{region:
+    (S, words)}``, ``ctl``: ``(S, ctl_words)``) as ONE pallas_call."""
+    assert kind == "chunk", "defrag waves exist for chunk kinds only"
+    lay = arena.layout(cfg, kind, family)
+    C = cfg.num_classes
+    Cw = lay.ctl_words
+    M = src.shape[0]
+    names = [r.name for r in lay.regions]
+    hbm, stride = {}, {}
+    for nm in names:
+        hbm[nm], stride[nm] = _slab(parts[nm])
+    carry_words = -(-M * cfg.words_per_chunk // BLOCK) * BLOCK
+    smem_in = [ctl.reshape(-1).astype(jnp.int32), src.astype(jnp.int32),
+               dst.astype(jnp.int32),
+               size_to_class_device(cfg, sizes.astype(jnp.int32))]
+
+    def body(ins, wins, outs, scr):
+        octl_ref = outs[0]
+        segs = scr[0]
+        carry = _View(win=wins["carry"])
+        _copy(ins[0], octl_ref, S * Cw)
+
+        def views(s):
+            return ({nm: _View(base=s * stride[nm], win=wins[nm])
+                     for nm in names}, _View(octl_ref, base=s * Cw))
+
+        def extract(s, _):
+            E, _octl = views(s)
+            _extract_moves(cfg, E, carry,
+                           _moves(cfg, ins, sharded, s, "extract"), M)
+            return 0
+
+        def insert(s, _):
+            E, octl = views(s)
+            _insert_moves(cfg, E, carry,
+                          _moves(cfg, ins, sharded, s, "insert"), M)
+            _unbind_and_pool(cfg, lay, E, octl)
+            _loop(0, C, lambda c, _: (_rebuild_class(
+                cfg, lay, family, c, E, octl, segs), 0)[1])
+            return 0
+
+        _loop(0, S, extract)
+        _loop(0, S, insert)
+
+    souts, houts = launch(
+        body, smem_in=smem_in, hbm_in=hbm, writes=tuple(names),
+        smem_out=[S * Cw], hbm_out={"carry": carry_words},
+        scratch=[segs_words(cfg, family, cfg.num_chunks)],
+        interpret=interpret)
+    new_parts = {nm: _unslab(houts[nm], S, parts[nm].shape[1])
+                 for nm in names}
+    return new_parts, souts[0].reshape(S, Cw)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "kind", "family", "interpret"))
 def arena_defrag_txn_blocked(cfg, kind, family, mem, ctl, src, dst,
                              sizes, *, interpret: bool = False):
-    """Region-blocked migration wave: ONE pallas_call over the class
-    grid, bit-identical to ``defrag.migrate_math`` and to the whole
-    lowering.  Returns ``(new_mem, new_ctl)``."""
-    assert kind == "chunk", "defrag waves exist for chunk kinds only"
+    """Blocked migration wave: ONE pallas_call, bit-identical to
+    ``defrag.migrate_math`` and to the whole lowering.  Returns
+    ``(new_mem, new_ctl)``."""
     lay = arena.layout(cfg, kind, family)
-    parts = arena.split(lay, mem)
-    regions = _defrag_regions(lay)
-    names = [nm for nm, _ in regions]
-    C = cfg.num_classes
-    M = src.shape[0]
-    maxw = cfg.words_per_chunk
-    lanes = (src.astype(jnp.int32), dst.astype(jnp.int32),
-             sizes.astype(jnp.int32))
-
-    def _arr(nm, treat):
-        r = lay.region(nm)
-        return (parts[nm].reshape(r.shape) if treat == "row"
-                else parts[nm])
-
-    def _spec(nm, treat):
-        r = lay.region(nm)
-        if treat == "row":
-            return pl.BlockSpec((1,) + r.shape[1:], lambda c, t: (c, 0))
-        if treat == "resident":
-            return pl.BlockSpec((r.words,), lambda c, t: (0,))
-        return pl.BlockSpec(memory_space=pltpu.ANY)
-
-    def _oshape(nm, treat):
-        r = lay.region(nm)
-        shape = r.shape if treat == "row" else (r.words,)
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-    lane_spec = pl.BlockSpec((M,), lambda c, t: (0,))
-    in_arrays = list(lanes) + [_arr(nm, tr) for nm, tr in regions]
-    in_specs = [lane_spec] * 3 + [_spec(nm, tr) for nm, tr in regions]
-    out_specs = [_spec(nm, tr) for nm, tr in regions]
-    out_shapes = [_oshape(nm, tr) for nm, tr in regions]
-    out_specs.append(pl.BlockSpec((lay.ctl_words,), lambda c, t: (0,)))
-    out_shapes.append(jax.ShapeDtypeStruct((lay.ctl_words,), jnp.int32))
-    out_specs.append(pl.BlockSpec((M * maxw,), lambda c, t: (0,)))
-    out_shapes.append(jax.ShapeDtypeStruct((M * maxw,), jnp.int32))
-
-    n_r = len(regions)
-    aliases = {1 + 3 + i: i for i, (nm, tr) in enumerate(regions)
-               if tr == "hbm"}
-
-    def kernel(ctl_ref, *refs):
-        in_refs, out_refs = refs[:3 + n_r], refs[3 + n_r:]
-        srcv, dstv, sizv = (r[...] for r in in_refs[:3])
-        R = dict(zip(names, in_refs[3:]))
-        O = dict(zip(names, out_refs[:n_r]))
-        octl = out_refs[n_r]
-        buf_ref = out_refs[n_r + 1]
-        c = pl.program_id(0)
-        E = O
-
-        @pl.when(c == 0)
-        def _init():
-            octl[...] = ctl_ref[...]
-            buf_ref[...] = jnp.zeros((M * maxw,), jnp.int32)
-            for nm, tr in regions:
-                if tr == "resident" or (tr == "hbm" and interpret):
-                    # hbm regions are input/output-aliased: the copy is
-                    # interpret-only, as in alloc_txn_blocked._txn_call
-                    O[nm][...] = R[nm][...]
-            sel = ((srcv >= 0) & (dstv >= 0)).astype(jnp.int32)
-            _extract_moves(cfg, lay, E, buf_ref, srcv, sizv, sel)
-            _insert_moves(cfg, lay, E, buf_ref, dstv, sizv, sel)
-            _unbind_and_pool(cfg, lay, E, octl)
-
-        _rebuild_class(cfg, lay, family, c, E, octl)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(C,),
-        in_specs=in_specs, out_specs=out_specs)
-    outs = pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shapes,
-        input_output_aliases=aliases, interpret=interpret,
-    )(ctl.astype(jnp.int32), *in_arrays)
-
-    new_parts = dict(parts)
-    for nm, val in zip(names, outs[:n_r]):
-        new_parts[nm] = val
-    return arena.join(lay, new_parts), outs[n_r]
+    parts = {nm: p.reshape(1, -1)
+             for nm, p in arena.split(lay, mem).items()}
+    new_parts, octl = _wave(cfg, 1, False, kind, family, parts,
+                            ctl.reshape(1, -1), src, dst, sizes, interpret)
+    flat = {nm: p.reshape(-1) for nm, p in new_parts.items()}
+    return arena.join(lay, flat), octl.reshape(-1)
 
 
 @functools.partial(jax.jit,
@@ -523,132 +427,15 @@ def arena_defrag_txn_blocked(cfg, kind, family, mem, ctl, src, dst,
 def sharded_arena_defrag_txn_blocked(cfg, num_shards, kind, family, mem,
                                      ctl, src, dst, sizes, *,
                                      interpret: bool = False):
-    """Sharded region-blocked wave: ONE pallas_call over the
-    (phase, shard, class) grid — §9 region stacking, phase 0 extract /
-    phase 1 insert+rebuild.  Returns ``(new_mem, new_ctl)``."""
-    assert kind == "chunk", "defrag waves exist for chunk kinds only"
+    """Sharded blocked wave: ONE pallas_call — extract on every source
+    shard, then insert + rebuild on every shard, so one wave covers
+    in-shard compaction and cross-shard rebalance moves.  Returns
+    ``(new_mem, new_ctl)``."""
     from repro.core import shards as _shards  # lazy: kernels <-> core
 
-    S = num_shards
-    scfg = _shards.shard_config(cfg, S)
-    slay = _shards.layout(cfg, S, kind, family)
-    lay = slay.shard
-    Ws = scfg.total_words
-    C = scfg.num_classes
-    Cw = lay.ctl_words
-    M = src.shape[0]
-    maxw = scfg.words_per_chunk
+    scfg = _shards.shard_config(cfg, num_shards)
+    slay = _shards.layout(cfg, num_shards, kind, family)
     parts = _shards.split_regions(slay, mem)
-    regions = _defrag_regions(lay)
-    names = [nm for nm, _ in regions]
-    hbm_words = {nm: lay.region(nm).words for nm, tr in regions
-                 if tr == "hbm"}
-    lanes = (src.astype(jnp.int32), dst.astype(jnp.int32),
-             sizes.astype(jnp.int32))
-
-    def _arr(nm, tr):
-        r = lay.region(nm)
-        p = parts[nm]
-        if tr == "row":
-            return p.reshape(S * r.shape[0], r.shape[1])
-        return p.reshape(S * r.words)
-
-    def _spec(nm, tr):
-        r = lay.region(nm)
-        if tr == "row":
-            return pl.BlockSpec((1, r.shape[1]),
-                                lambda p, s, c, t, C=C: (s * C + c, 0))
-        if tr == "resident":
-            return pl.BlockSpec((r.words,), lambda p, s, c, t: (s,))
-        return pl.BlockSpec(memory_space=pltpu.ANY)
-
-    def _oshape(nm, tr):
-        r = lay.region(nm)
-        if tr == "row":
-            return jax.ShapeDtypeStruct((S * r.shape[0], r.shape[1]),
-                                        jnp.int32)
-        return jax.ShapeDtypeStruct((S * r.words,), jnp.int32)
-
-    lane_spec = pl.BlockSpec((M,), lambda p, s, c, t: (0,))
-    in_arrays = list(lanes) + [_arr(nm, tr) for nm, tr in regions]
-    in_specs = [lane_spec] * 3 + [_spec(nm, tr) for nm, tr in regions]
-    out_specs = [_spec(nm, tr) for nm, tr in regions]
-    out_shapes = [_oshape(nm, tr) for nm, tr in regions]
-    out_specs.append(pl.BlockSpec((Cw,), lambda p, s, c, t: (s,)))
-    out_shapes.append(jax.ShapeDtypeStruct((S * Cw,), jnp.int32))
-    out_specs.append(pl.BlockSpec((M * maxw,), lambda p, s, c, t: (0,)))
-    out_shapes.append(jax.ShapeDtypeStruct((M * maxw,), jnp.int32))
-
-    n_r = len(regions)
-    aliases = {1 + 3 + i: i for i, (nm, tr) in enumerate(regions)
-               if tr == "hbm"}
-
-    def kernel(ctl_ref, *refs):
-        in_refs, out_refs = refs[:3 + n_r], refs[3 + n_r:]
-        srcv, dstv, sizv = (r[...] for r in in_refs[:3])
-        R = dict(zip(names, in_refs[3:]))
-        O = dict(zip(names, out_refs[:n_r]))
-        octl = out_refs[n_r]
-        buf_ref = out_refs[n_r + 1]
-        p = pl.program_id(0)
-        s = pl.program_id(1)
-        c = pl.program_id(2)
-
-        @pl.when((p == 0) & (s == 0) & (c == 0))
-        def _once():
-            buf_ref[...] = jnp.zeros((M * maxw,), jnp.int32)
-            if interpret:
-                for nm, tr in regions:
-                    if tr == "hbm":
-                        O[nm][...] = R[nm][...]
-
-        @pl.when((p == 0) & (c == 0))
-        def _per_shard():
-            octl[...] = pl.load(ctl_ref, (pl.ds(s * Cw, Cw),))
-            for nm, tr in regions:
-                if tr == "resident":
-                    O[nm][...] = R[nm][...]
-
-        @pl.when(p == 0)
-        def _stage_rows():
-            for nm, tr in regions:
-                if tr == "row":
-                    O[nm][0, :] = R[nm][0, :]
-
-        def _wrap(nm, tr, ref):
-            if tr == "hbm":
-                return _ShardView(ref, s * hbm_words[nm])
-            return ref
-
-        E = {nm: _wrap(nm, tr, O[nm]) for nm, tr in regions}
-        valid = (srcv >= 0) & (dstv >= 0)
-
-        @pl.when((p == 0) & (c == 0))
-        def _extract():
-            sel = (valid & (srcv // Ws == s)).astype(jnp.int32)
-            local = jnp.where(sel != 0, srcv - s * Ws, -1)
-            _extract_moves(scfg, lay, E, buf_ref, local, sizv, sel)
-
-        @pl.when((p == 1) & (c == 0))
-        def _insert():
-            sel = (valid & (dstv // Ws == s)).astype(jnp.int32)
-            local = jnp.where(sel != 0, dstv - s * Ws, -1)
-            _insert_moves(scfg, lay, E, buf_ref, local, sizv, sel)
-            _unbind_and_pool(scfg, lay, E, octl)
-
-        @pl.when(p == 1)
-        def _rebuild():
-            _rebuild_class(scfg, lay, family, c, E, octl)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(2, S, C),
-        in_specs=in_specs, out_specs=out_specs)
-    outs = pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shapes,
-        input_output_aliases=aliases, interpret=interpret,
-    )(ctl.reshape(-1).astype(jnp.int32), *in_arrays)
-
-    new_parts = dict(parts)
-    for nm, val in zip(names, outs[:n_r]):
-        new_parts[nm] = val.reshape(S, -1)
-    return _shards.join_regions(slay, new_parts), outs[n_r].reshape(S, Cw)
+    new_parts, octl = _wave(scfg, num_shards, True, kind, family, parts,
+                            ctl, src, dst, sizes, interpret)
+    return _shards.join_regions(slay, new_parts), octl

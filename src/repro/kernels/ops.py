@@ -1,13 +1,17 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On a TPU backend the kernels compile natively; on CPU (this container,
-and CI) they run in ``interpret=True`` mode so every call is validated
-against the compiled path's exact semantics.  ``ref.py`` carries the
-pure-jnp oracles used by the tests.
+On a TPU backend the kernels compile natively; on any other backend
+they run in ``interpret=True`` mode.  Interpret mode pins the
+word-level semantics but accepts kernels that Mosaic refuses, so
+tests/test_tpu_compile.py compiles the blocked allocator kernels for a
+described v5e chip.  ``ref.py`` carries the pure-jnp oracles used by
+the tests.
 """
 from __future__ import annotations
 
+import functools
 import os
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -19,8 +23,18 @@ from repro.kernels.ring_window import ring_window as _ring_window
 from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
 
 
+@functools.lru_cache(maxsize=None)
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """True off a TPU: every kernel then runs in the Pallas interpreter,
+    which is said once per process so that a run whose chip was not
+    found cannot pass for a compiled one."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    warnings.warn(f"Pallas kernels run in interpret mode on the "
+                  f"{backend!r} backend, not compiled for a TPU",
+                  RuntimeWarning, stacklevel=2)
+    return True
 
 
 def ring_window(store, front, counts, *, m: int):
@@ -50,15 +64,15 @@ LOWERINGS = ("whole", "blocked", "auto")
 def resolve_lowering(lowering: str = "auto") -> str:
     """Concrete kernel lowering for the fused arena transactions.
 
-    ``whole``    the kernel takes the full ``mem`` image as one ref —
-                 simplest, but only lowers while the arena fits VMEM;
-    ``blocked``  the region-blocked compiled lowering (kernels/
-                 alloc_txn_blocked.py): per-region BlockSpecs, class-row
-                 grid, scalar-prefetched control block (DESIGN.md §8);
+    ``whole``    the kernel takes the full ``mem`` image as one ref and
+                 runs the jnp math on it — interpret mode only: Mosaic
+                 cannot lower it (no ``cumsum``);
+    ``blocked``  the compiled lowering (kernels/alloc_txn_blocked.py):
+                 scalar-core code over SMEM windows of the HBM regions
+                 (DESIGN.md §8);
     ``auto``     honours ``REPRO_ALLOC_LOWERING`` (CI forces the
                  blocked matrix through it), else picks ``blocked`` on
-                 TPU — where whole-arena refs stop lowering at serving
-                 sizes — and ``whole`` in CPU interpret mode.
+                 TPU and ``whole`` in CPU interpret mode.
     """
     if lowering not in LOWERINGS:
         raise ValueError(
@@ -162,7 +176,7 @@ def count_pallas_calls(closed_jaxpr) -> int:
     into sub-jaxprs in eqn params).  The single source of truth for the
     one-kernel-per-transaction assertions in tests/test_alloc_txn_parity
     and the ``launches_per_txn`` proof in benchmarks/run.py."""
-    import jax.core as jc
+    from jax.extend import core as jc
 
     def jaxprs_in(val):
         if isinstance(val, jc.ClosedJaxpr):

@@ -42,7 +42,7 @@ def main(argv=None):
     ap.add_argument("--alloc-lowering",
                     choices=("auto", "whole", "blocked"), default="auto",
                     help="Pallas kernel lowering (whole-arena refs vs "
-                         "region-blocked; DESIGN.md §8) — the active "
+                         "blocked; DESIGN.md §8) — the active "
                          "one is reported in the engine stats")
     ap.add_argument("--num-shards", type=int, default=1,
                     help="shard the KV page allocator into N "
@@ -94,10 +94,12 @@ def main(argv=None):
 
     import jax
     import numpy as np
+    from repro import compile_cache
     from repro.configs import get_arch
     from repro.models.model import build_model
     from repro.serve.engine import ServingEngine
 
+    compile_cache.enable()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

@@ -42,6 +42,7 @@ def main(argv=None):
 
     import jax
     import numpy as np
+    from repro import compile_cache
     from repro.ckpt import checkpoint as CK
     from repro.configs import get_arch
     from repro.configs.base import ShapeConfig
@@ -53,6 +54,7 @@ def main(argv=None):
     from repro.train.optimizer import AdamW
     from repro.train.train_step import init_state, make_train_step
 
+    compile_cache.enable()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

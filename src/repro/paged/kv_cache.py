@@ -121,7 +121,7 @@ def make_kv_allocator(num_pages: int, backend: str = "jnp",
     offsets in it, so with ``backend="pallas"`` every page grant and
     release the engine issues is ONE fused kernel launch, segment walk
     included; ``lowering`` picks the kernel shape (whole-arena refs vs
-    the region-blocked compiled lowering, DESIGN.md §8).  Backends and
+    the blocked compiled lowering, DESIGN.md §8).  Backends and
     lowerings are bit-identical, so serving behaviour is invariant to
     both — which is also why the serving snapshot fingerprint
     (DESIGN.md §12) records this allocator's layout/geometry but NOT

@@ -4,8 +4,9 @@ reference oracle, on randomized alloc/free/write/check traces.
 For every variant the same trace is replayed through
 ``Ouroboros(cfg, variant, backend="jnp")`` and ``backend="pallas"``
 under BOTH kernel lowerings — ``whole`` (full-arena refs) and
-``blocked`` (the region-blocked compiled lowering, DESIGN.md §8) —
-(interpret mode on CPU — the compiled path's exact semantics) and all
+``blocked`` (the compiled lowering, DESIGN.md §8) — in interpret mode
+on CPU, which pins the semantics but accepts kernels the chip's
+compiler refuses (tests/test_tpu_compile.py covers that) — and all
 three executions must be **bit-identical** at every step:
 
   - granted offsets and failure masks (−1 lanes)

@@ -1,15 +1,14 @@
 """Golden snapshot of the arena word layout AND the per-region blocked
 -lowering treatment.
 
-DESIGN.md §7 documents the offset map and §8 the region-blocking
-scheme; both are rendered from the live ``ArenaLayout`` (test_heap.py
-pins §7 prose to ``describe()``).  This test goes one step further and
-pins the full rendering — offsets, shapes, blocking policy, and VMEM
-block shape per region, for all six variants — to a checked-in golden
-file, so ANY layout drift (a reordered region, a changed block shape,
-a region silently promoted to a whole-VMEM load) fails loudly instead
-of silently breaking cross-lowering parity or corrupting live heaps on
-a version upgrade.
+DESIGN.md §7 documents the offset map and §8 the per-region treatment;
+both are rendered from the live ``ArenaLayout`` (test_heap.py pins §7
+prose to ``describe()``).  This test goes one step further and pins
+the full rendering — offsets, shapes, blocking policy, and SMEM window
+per region, for all six variants — to a checked-in golden file, so ANY
+layout drift (a reordered region, a changed window, a region silently
+dropped from the kernel) fails loudly instead of silently breaking
+cross-lowering parity or corrupting live heaps on a version upgrade.
 
 To regenerate after an *intentional* layout change:
 
@@ -57,15 +56,14 @@ def test_layout_and_block_shapes_match_golden():
 @pytest.mark.parametrize("family", arena.QUEUE_FAMILIES)
 def test_block_shapes_consistent_with_policy(kind, family):
     """Structural invariants the blocked lowering relies on, config-
-    independent: row-blocked regions are 2-D with one-row blocks, hbm
-    regions never present a VMEM block, and untouched regions are
-    exactly the ones the transactions never write."""
+    independent: every region the kernel reaches is staged through one
+    tile-aligned SMEM window, and untouched regions are exactly the
+    ones the transactions never write."""
     lay = arena.layout(CFG, kind, family)
     for r in lay.regions:
-        if r.blocking == "row":
-            assert len(r.shape) == 2 and r.block_shape == (1, r.shape[1])
-        elif r.blocking == "resident":
-            assert r.block_shape == r.shape
+        assert r.blocking in ("window", "untouched")
+        if r.blocking == "window":
+            assert r.block_shape == (arena.WINDOW_WORDS,)
         else:
             assert r.block_shape is None
     # the heap is written only by segment traffic; the pool only ever

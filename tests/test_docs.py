@@ -65,7 +65,7 @@ def test_design_s8_blocking_table_matches_live_policies():
     doc = DOC.read_text()
     sec = doc.split("## §8")[1].split("\n## §")[0]
     rows = {}
-    for m in re.finditer(r"\| `(row|resident|hbm|untouched)`[^\n]*", sec):
+    for m in re.finditer(r"\| `(window|untouched)`[^\n]*", sec):
         rows[m.group(1)] = m.group(0)
     live = {}
     for kind in arena.KINDS:

@@ -1,5 +1,5 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, interpret
-mode on CPU (the compiled path's exact semantics)."""
+mode on CPU."""
 import numpy as np
 import pytest
 
