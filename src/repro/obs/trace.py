@@ -1,16 +1,26 @@
-"""Chrome/Perfetto ``trace_event`` spans for serving-engine phases.
+"""Spans for serving-engine phases, with two sinks.
 
-The engine wraps each phase — admission, prefill, tick, bulk grow,
-defrag/rebalance wave, snapshot/restore, eviction, cancel — in a
-:meth:`Tracer.span`; the result is a ``{"traceEvents": [...]}`` JSON
-document loadable in Perfetto (https://ui.perfetto.dev) or
-chrome://tracing.  Ticks that trigger a jit first-call (compile) are
-tagged with category ``"compile"`` instead of ``"steady"`` so the two
-populations separate visually and in queries — the same split
-serve/replay.py uses for its latency summary (DESIGN.md §14).
+The engine wraps each phase — tick, admission, prefill, decode, flag
+sync, retirement, bulk grow/free, defrag/rebalance wave,
+snapshot/restore, eviction, cancel — in a :meth:`Tracer.span`.
 
-``Tracer(enabled=False)`` (and the module-level :data:`NULL`) is a
-no-op with the same surface, so instrumentation sites carry no
+1. Every span, on every tracer, enters a
+   ``jax.profiler.TraceAnnotation`` named ``serve.<phase>`` whose
+   keyword arguments become the event's stats.  While
+   ``jax.profiler`` records, those events share the device planes'
+   clock, so an idle gap on the device can be named by the engine
+   phase the host was in; while it does not, an annotation costs about
+   a microsecond.
+2. An enabled tracer also records a ``{"traceEvents": [...]}`` JSON
+   document on the host clock, loadable in Perfetto
+   (https://ui.perfetto.dev) or chrome://tracing.  Ticks that trigger a
+   jit first-call (compile) are tagged with category ``"compile"``
+   instead of ``"steady"`` so the two populations separate visually and
+   in queries — the same split serve/replay.py uses for its latency
+   summary (DESIGN.md §14).
+
+``Tracer(enabled=False)`` (and the module-level :data:`NULL`) records
+no JSON but keeps the same surface, so instrumentation sites carry no
 conditional logic.
 """
 from __future__ import annotations
@@ -18,17 +28,24 @@ from __future__ import annotations
 import json
 import time
 from contextlib import contextmanager
-from typing import List, Optional
+from typing import List
+
+from jax.profiler import TraceAnnotation
 
 # The span taxonomy (name prefixes the engine emits).  DESIGN.md §14
 # pins this tuple; tests validate emitted traces against it.
 PHASES = ("admission", "prefill", "tick", "bulk_grow", "defrag_wave",
-          "rebalance_wave", "snapshot", "restore", "eviction", "cancel")
+          "rebalance_wave", "snapshot", "restore", "eviction", "cancel",
+          "slot_push", "decode", "flag_sync", "retire", "bulk_free")
+
+# Prefix of the profiler annotation each span enters.
+PROFILER_PREFIX = "serve."
 
 
 class Tracer:
     """Collects complete ("ph": "X") duration events, microsecond
-    timestamps from one monotonic origin."""
+    timestamps from one monotonic origin, and enters the profiler
+    annotation of every span."""
 
     def __init__(self, enabled: bool = True, pid: int = 0):
         self.enabled = enabled
@@ -41,26 +58,33 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, cat: str = "engine", **args):
-        if not self.enabled:
-            yield
-            return
-        ts = self._now_us()
-        try:
-            yield
-        finally:
-            self.events.append({
-                "name": name, "cat": cat, "ph": "X", "ts": ts,
-                "dur": self._now_us() - ts, "pid": self.pid, "tid": 0,
-                "args": args})
+        with TraceAnnotation(PROFILER_PREFIX + name, **args):
+            if not self.enabled:
+                yield
+                return
+            ts = self._now_us()
+            try:
+                yield
+            finally:
+                self.events.append({
+                    "name": name, "cat": cat, "ph": "X", "ts": ts,
+                    "dur": self._now_us() - ts, "pid": self.pid, "tid": 0,
+                    "args": args})
 
-    def begin(self) -> float:
-        """Timestamp for a deferred :meth:`complete` — for spans whose
-        category is only known at close (compile vs steady ticks)."""
-        return self._now_us() if self.enabled else 0.0
+    def begin(self, name: str = "tick", **args):
+        """Open a span that a deferred :meth:`complete` closes — for
+        spans whose category is only known at close (compile vs steady
+        ticks).  Enters the profiler annotation ``serve.<name>`` with
+        ``args``; returns the token :meth:`complete` takes."""
+        ann = TraceAnnotation(PROFILER_PREFIX + name, **args)
+        ann.__enter__()
+        return ann, (self._now_us() if self.enabled else 0.0)
 
-    def complete(self, name: str, ts: float, cat: str = "engine",
+    def complete(self, name: str, token, cat: str = "engine",
                  **args) -> None:
         """Close a span opened with :meth:`begin`."""
+        ann, ts = token
+        ann.__exit__(None, None, None)
         if not self.enabled:
             return
         self.events.append({

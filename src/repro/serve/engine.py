@@ -212,8 +212,9 @@ class ServingEngine:
                                  else float(defrag_threshold))
         self.defrag_check_interval = defrag_check_interval
         # observability (DESIGN.md §14): engine phases emit trace
-        # spans through the tracer (NULL = zero-cost no-op), host-side
-        # readings publish through the metrics registry
+        # spans through the tracer — each span is always a profiler
+        # annotation ``serve.<phase>``; NULL records no JSON events —
+        # host-side readings publish through the metrics registry
         self.tracer = tracer if tracer is not None else obs_trace.NULL
         self.metrics = obs_metrics.MetricsRegistry()
         self.last_tick_compiled = False
@@ -266,12 +267,18 @@ class ServingEngine:
         self._admit_counter = 0
         # both entry points argmax ON DEVICE: only (B,) int32 token ids
         # ever cross the host boundary, never (B, vocab) logits.
-        self._prefill = jax.jit(
-            lambda p, b, c: _tokens_of(model.prefill(
-                p, b, c, remat_policy="none", dtype=compute_dtype)))
-        self._decode = jax.jit(
-            lambda p, t, c: _tokens_of(model.decode_step(
-                p, t, c, dtype=compute_dtype)))
+        # named functions: the programs show up in a profile as
+        # ``jit_prefill`` and ``jit_decode``
+        def prefill(p, b, c):
+            return _tokens_of(model.prefill(
+                p, b, c, remat_policy="none", dtype=compute_dtype))
+
+        def decode(p, t, c):
+            return _tokens_of(model.decode_step(p, t, c,
+                                                dtype=compute_dtype))
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode)
 
         # --- device-resident slot state (mega-step mode) -----------------
         if self.mega_step:
@@ -458,10 +465,11 @@ class ServingEngine:
         lanes = max(self.max_batch * 2, len(pages))
         offs = np.full(lanes, -1, np.int32)
         offs[:len(pages)] = np.asarray(pages, np.int32) * self.wpp
-        sizes = jnp.full(lanes, self.page_bytes, jnp.int32)
-        mask = jnp.asarray(offs >= 0)
-        self.alloc_state = self.ouro.free(
-            self.alloc_state, jnp.asarray(offs), sizes, mask)
+        with self.tracer.span("bulk_free", pages=len(pages)):
+            sizes = jnp.full(lanes, self.page_bytes, jnp.int32)
+            mask = jnp.asarray(offs >= 0)
+            self.alloc_state = self.ouro.free(
+                self.alloc_state, jnp.asarray(offs), sizes, mask)
         if count_stats:
             self.stats["frees"] += len(pages)
         self._note_shard_pages(offs[offs >= 0], -1)
@@ -749,20 +757,23 @@ class ServingEngine:
                            else self.caches._replace(kv=kv0))
             else:
                 caches0 = self.caches
+            # from dispatch through the first-token read, so the span
+            # ends once the device has finished the prefill
             with self.tracer.span("prefill", slot=slot, uid=req.uid,
                                   prompt_len=lp):
                 tok_ids, new_caches = self._prefill(self.params, batch,
                                                     caches0)
-            self.caches = merge_rows(self.cfg, new_caches, self.caches,
-                                     row_mask)
-            first = int(np.asarray(tok_ids)[slot])
+                self.caches = merge_rows(self.cfg, new_caches, self.caches,
+                                         row_mask)
+                first = int(np.asarray(tok_ids)[slot])
             req.out_tokens.append(first)
             self.slot_req[slot] = req
             self.slot_len[slot] = lp + 1
             self._admit_counter += 1
             self._admit_ord[slot] = self._admit_counter
             if self.mega_step:
-                self._mega_admit(slot, req, first)
+                with self.tracer.span("slot_push", uid=req.uid, slot=slot):
+                    self._mega_admit(slot, req, first)
 
     def _merge_row(self, new_caches, row_mask):
         """Back-compat shim over :func:`merge_rows`."""
@@ -930,10 +941,13 @@ class ServingEngine:
             return []
         if self._mega is None:
             self._build_mega()
-        (self.alloc_state, self.caches, self.mega_state, flags,
-         l_offs, l_slot, l_mask) = self._mega(
-            self.params, self.alloc_state, self.caches, self.mega_state)
-        flags = np.asarray(flags)          # the per-tick host sync
+        with self.tracer.span("decode", slots=len(active)):
+            (self.alloc_state, self.caches, self.mega_state, flags,
+             l_offs, l_slot, l_mask) = self._mega(
+                self.params, self.alloc_state, self.caches,
+                self.mega_state)
+        with self.tracer.span("flag_sync"):
+            flags = np.asarray(flags)      # the per-tick host sync
         fin = (flags & 1) > 0
         fail = (flags & 2) > 0
         has_kv = self._kv() is not None
@@ -963,9 +977,12 @@ class ServingEngine:
             self._fail_streak[:] = 0
 
         finished = []
-        for s in np.nonzero(fin)[0]:
-            if self.slot_req[s] is not None:  # not evicted this tick
-                finished.append(self._release_mega(int(s)))
+        for s in np.nonzero(fin)[0].tolist():
+            req = self.slot_req[s]
+            if req is not None:  # not evicted this tick
+                with self.tracer.span("retire", uid=req.uid, slot=s,
+                                      tokens=int(self._nout_host[s])):
+                    finished.append(self._release_mega(s))
         return finished
 
     def _recover_failed(self, fail, fin, l_offs, l_slot, l_mask):
@@ -1184,9 +1201,11 @@ class ServingEngine:
             toks = np.zeros((self.max_batch, 1), np.int32)
             for s in active:
                 toks[s, 0] = self.slot_req[s].out_tokens[-1]
-            tok_ids, self.caches = self._decode(
-                self.params, jnp.asarray(toks), self.caches)
-            nxt = np.asarray(tok_ids)
+            with self.tracer.span("decode", slots=len(active)):
+                tok_ids, self.caches = self._decode(
+                    self.params, jnp.asarray(toks), self.caches)
+            with self.tracer.span("flag_sync"):
+                nxt = np.asarray(tok_ids)
             for s in active:
                 req = self.slot_req[s]
                 req.out_tokens.append(int(nxt[s]))
@@ -1197,7 +1216,9 @@ class ServingEngine:
                             and int(nxt[s]) == req.eos_id)):
                     req.done = True
                     finished.append(req)
-                    self._release(s)
+                    with self.tracer.span("retire", uid=req.uid, slot=s,
+                                          tokens=ln):
+                        self._release(s)
         return finished
 
     def step(self) -> List[Request]:
@@ -1210,7 +1231,7 @@ class ServingEngine:
         ``"steady"`` otherwise — is resolved at close from the jit
         cache sizes; ``last_tick_compiled`` exposes the same signal to
         the replay harness (DESIGN.md §14)."""
-        ts = self.tracer.begin()
+        ts = self.tracer.begin("tick", step=self.stats["steps"] + 1)
         pre = self._compile_count()
         with self.tracer.span("admission"):
             self._admit()

@@ -358,3 +358,129 @@ def test_step_monitor_publishes_through_registry():
     assert steps == 3
     assert reg.get("repro_step_time_ms").samples[()].count == 3
     assert reg.get("repro_step_time_ewma_ms") is not None
+
+
+# ---- spans on the profiler's clock -----------------------------------------
+
+def _profiled(fn):
+    """Run ``fn`` under ``jax.profiler`` (Python tracer off); return the
+    host plane's ``serve.*`` spans as ``(name, start_ns, end_ns, stats)``
+    in start order, and the names of the jitted programs dispatched
+    (``PjitFunction(<name>)`` events)."""
+    import glob
+    import os
+    import tempfile
+
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d, profiler_options=opts)
+        try:
+            fn()
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(os.path.join(d, "**", "*.xplane.pb"),
+                         recursive=True)[0]
+        pd = ProfileData.from_file(path)
+        spans, programs = [], set()
+        for plane in pd.planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("serve."):
+                        spans.append((ev.name, ev.start_ns,
+                                      ev.start_ns + ev.duration_ns,
+                                      dict(ev.stats)))
+                    elif ev.name.startswith("PjitFunction("):
+                        programs.add(ev.name[len("PjitFunction("):-1])
+    return sorted(spans, key=lambda s: (s[1], -s[2])), programs
+
+
+def _inside(child, parents):
+    return [p for p in parents if p[1] <= child[1] and child[2] <= p[2]]
+
+
+@pytest.fixture(scope="module")
+def smoke_model():
+    from repro.configs import get_arch
+    from repro.models.model import build_model
+    cfg = get_arch("qwen2-0.5b").smoke()
+    m = build_model(cfg)
+    return cfg, m, m.init(jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("mega", [True, False])
+def test_engine_spans_on_the_profiler_clock(smoke_model, mega):
+    """Every engine span is a ``serve.<phase>`` profiler annotation,
+    nested tick ⊃ admission ⊃ prefill and retire ⊃ bulk_free, one
+    prefill per admission and one retire per retirement, each with its
+    request's uid; the prefill program is named, no anonymous program
+    runs; and the JSON sink records the same spans and validates."""
+    from repro.serve.engine import ServingEngine
+    cfg, m, params = smoke_model
+    tracer = Tracer()
+    eng = ServingEngine(m, params, max_batch=2, max_seq=64,
+                        kv_dtype=jnp.float32, compute_dtype=jnp.float32,
+                        mega_step=mega, tracer=tracer)
+    rng = np.random.default_rng(0)
+    uids = [eng.submit(rng.integers(2, cfg.vocab_size, 8),
+                       max_new_tokens=3) for _ in range(3)]
+    done = []
+    spans, programs = _profiled(
+        lambda: done.extend(eng.run_until_done(50)))
+    assert sorted(r.uid for r in done) == uids
+
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s)
+    ticks = by["serve.tick"]
+    assert [t[3]["step"] for t in ticks] == list(
+        range(1, eng.stats["steps"] + 1))
+    for p in by["serve.prefill"]:
+        adm = _inside(p, by["serve.admission"])
+        assert len(adm) == 1 and len(_inside(adm[0], ticks)) == 1
+        assert p[3]["prompt_len"] == 8
+    for r in by["serve.retire"]:
+        assert len(_inside(r, ticks)) == 1
+        assert _inside(r, by["serve.admission"]) == []
+        assert any(_inside(f, [r]) for f in by["serve.bulk_free"])
+        assert r[3]["tokens"] == 3
+    assert sorted(p[3]["uid"] for p in by["serve.prefill"]) == uids
+    assert sorted(r[3]["uid"] for r in by["serve.retire"]) == uids
+    for d in by["serve.decode"]:
+        assert len(_inside(d, ticks)) == 1 and 1 <= d[3]["slots"] <= 2
+    assert len(by["serve.flag_sync"]) == len(by["serve.decode"])
+    if mega:
+        assert sorted(p[3]["uid"] for p in by["serve.slot_push"]) == uids
+        assert {"prefill", "mega"} <= programs
+    else:
+        assert "serve.slot_push" not in by
+        assert {"prefill", "decode"} <= programs
+    assert "<lambda>" not in programs
+
+    doc = tracer.to_json()
+    assert validate_trace(doc) == len(spans)
+    json_names = sorted(ev["name"] for ev in doc["traceEvents"])
+    assert json_names == sorted(s[0][len("serve."):] for s in spans)
+
+
+def test_begin_complete_is_one_profiler_span_and_null_records_no_json():
+    before = len(NULL.events)
+    tr = Tracer()
+
+    def run():
+        for t in (tr, NULL):
+            ts = t.begin("tick", step=7)
+            with t.span("admission"):
+                pass
+            t.complete("tick", ts, cat="steady", step=7)
+
+    spans, _ = _profiled(run)
+    assert [(s[0], s[3]) for s in spans] == [
+        ("serve.tick", {"step": 7}), ("serve.admission", {}),
+        ("serve.tick", {"step": 7}), ("serve.admission", {})]
+    assert len(NULL.events) == before
+    assert validate_trace(tr.to_json()) == 2
