@@ -104,11 +104,10 @@ def merge_rows(cfg, new_caches, old_caches, row_mask):
 
     Structure-aware (never shape-guessing — num_layers can equal
     max_batch): page heaps are taken wholesale (rows outside the mask
-    either had their page tables hidden or their writes dropped on a
-    table hole — heap rows stay disjoint); batch-first leaves merge on
-    axis 0; layer-stacked state leaves (Lr, B, ...) merge on axis 1.
-    Shared by the admission prefill (mask = the admitted row) and the
-    mega-step (mask = slots that advanced this tick)."""
+    had their writes dropped on a table hole — heap rows stay
+    disjoint); batch-first leaves merge on axis 0; layer-stacked state
+    leaves (Lr, B, ...) merge on axis 1.  The mega-step's cache
+    advance: mask = slots that advanced this tick."""
     mask = jnp.asarray(row_mask)
 
     def axis0(new, old):
@@ -144,6 +143,42 @@ def merge_rows(cfg, new_caches, old_caches, row_mask):
         kv=merge_kv(new_caches.kv, old.kv),
         ssm_h=axis1(new_caches.ssm_h, old.ssm_h),
         ssm_conv=axis1(new_caches.ssm_conv, old.ssm_conv))
+
+
+def put_row(cfg, row_caches, caches, slot):
+    """Write a one-row cache update back at batch row ``slot``.
+
+    The admission prefill computes only the admitted row, through a
+    view of the caches that holds the shared page heap and that slot's
+    page-table row.  The sibling of :func:`merge_rows` over the same
+    tree: the page heap is taken wholesale (the row wrote only into
+    its own pages), the page table is kept (the prefill never changes
+    it), batch-first leaves are set at ``[slot]`` and layer-stacked
+    state leaves (Lr, B, ...) at ``[:, slot]``.  ``slot`` may be traced.
+    Dtypes promote as in :func:`merge_rows`."""
+    def put(row, old, axis):
+        if row is None:
+            return old
+        dt = jnp.promote_types(row.dtype, old.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(
+            old.astype(dt), row.astype(dt), slot, axis)
+
+    def put_kv(row_kv, kv):
+        if row_kv is None:
+            return None
+        return kv._replace(layers=row_kv.layers,
+                           seq_lens=put(row_kv.seq_lens, kv.seq_lens, 0))
+
+    if cfg.is_encdec:
+        return caches._replace(
+            self_kv=put_kv(row_caches.self_kv, caches.self_kv),
+            cross_k=put(row_caches.cross_k, caches.cross_k, 1),
+            cross_v=put(row_caches.cross_v, caches.cross_v, 1),
+            enc_valid=put(row_caches.enc_valid, caches.enc_valid, 0))
+    return caches._replace(
+        kv=put_kv(row_caches.kv, caches.kv),
+        ssm_h=put(row_caches.ssm_h, caches.ssm_h, 1),
+        ssm_conv=put(row_caches.ssm_conv, caches.ssm_conv, 1))
 
 
 class ServingEngine:
@@ -252,6 +287,15 @@ class ServingEngine:
         self.caches = model.make_decode_caches(
             max_batch, max_seq=max_seq, kv_dtype=kv_dtype,
             num_pages=physical_pages)
+        if cfg.is_encdec:
+            # cross-attention K/V of every slot at the FIXED encoder
+            # length (max_seq frames): each admission writes its row
+            xkv = (cfg.num_layers, max_batch, max_seq, cfg.num_kv_heads,
+                   cfg.head_dim_)
+            self.caches = self.caches._replace(
+                cross_k=jnp.zeros(xkv, compute_dtype),
+                cross_v=jnp.zeros(xkv, compute_dtype),
+                enc_valid=jnp.zeros(max_batch, jnp.int32))
         self.slot_req: List[Optional[Request]] = [None] * max_batch
         self.slot_pages: List[List[int]] = [[] for _ in range(max_batch)]
         # per-modality aux pages (SSM state / MoE expert buffers) held
@@ -268,16 +312,29 @@ class ServingEngine:
         # both entry points argmax ON DEVICE: only (B,) int32 token ids
         # ever cross the host boundary, never (B, vocab) logits.
         # named functions: the programs show up in a profile as
-        # ``jit_prefill`` and ``jit_decode``
-        def prefill(p, b, c):
-            return _tokens_of(model.prefill(
-                p, b, c, remat_policy="none", dtype=compute_dtype))
+        # ``jit_prefill`` and ``jit_decode``.  The prefill computes the
+        # admitted row alone, through a view holding the shared heap
+        # and the slot's page-table row, and writes it back at the
+        # traced ``slot`` (one program per prompt length, any slot)
+        def prefill(p, b, c, slot):
+            kv = c.self_kv if cfg.is_encdec else c.kv
+            view = c
+            if kv is not None:
+                kv = kv._replace(
+                    page_table=jax.lax.dynamic_slice_in_dim(
+                        kv.page_table, slot, 1),
+                    seq_lens=jnp.zeros(1, jnp.int32))
+                view = (c._replace(self_kv=kv) if cfg.is_encdec
+                        else c._replace(kv=kv))
+            tok, row = _tokens_of(model.prefill(
+                p, b, view, remat_policy="none", dtype=compute_dtype))
+            return tok, put_row(cfg, row, c, slot)
 
         def decode(p, t, c):
             return _tokens_of(model.decode_step(p, t, c,
                                                 dtype=compute_dtype))
 
-        self._prefill = jax.jit(prefill)
+        self._prefill = jax.jit(prefill, donate_argnums=(2,))
         self._decode = jax.jit(decode)
 
         # --- device-resident slot state (mega-step mode) -----------------
@@ -343,7 +400,11 @@ class ServingEngine:
                       # many of this process's ticks paid a compile
                       # (the replay harness splits its latency summary
                       # on exactly this signal — DESIGN.md §14)
-                      "jit_first_calls": 0}
+                      "jit_first_calls": 0,
+                      # admission prefill work: rows and token-rows
+                      # the prefill programs computed, padding included
+                      "prefill_rows": 0,
+                      "prefill_tokens": 0}
         self.refresh_frag_stats()
 
     def _compile_count(self) -> int:
@@ -659,7 +720,7 @@ class ServingEngine:
                     "alloc_txns", "alloc_overflows", "evictions",
                     "cancels", "defrag_waves", "rebalance_waves",
                     "auto_defrag_waves", "pages_migrated",
-                    "jit_first_calls")
+                    "jit_first_calls", "prefill_rows", "prefill_tokens")
         for k in counters:
             reg.counter(f"repro_engine_{k}_total",
                         f"engine stats[{k!r}]").set(float(self.stats[k]))
@@ -725,47 +786,25 @@ class ServingEngine:
                 self._free_aux(slot)
                 self.waiting.insert(0, req)  # heap full; retry later
                 break
-            # single-row prefill (padded batch keeps jit cache small)
-            toks = np.zeros((self.max_batch, lp), np.int32)
-            toks[slot] = req.prompt
-            batch = {"tokens": jnp.asarray(toks)}
+            batch = {"tokens": jnp.asarray(req.prompt[None])}
             if self.cfg.modality == "audio":
-                # FIXED encoder length: resident rows keep their cross-
-                # KV through merge_rows, so every admission must produce
-                # identically-shaped cross_k/cross_v — staggered prompts
-                # of different lengths would otherwise be unmergeable.
-                # The stub frontend is zeros; ``src_valid`` masks the
-                # padding out of cross attention (kv_valid_len).
-                sv = np.zeros(self.max_batch, np.int32)
-                sv[slot] = lp
+                # FIXED encoder length, so every slot's cross-KV row has
+                # one shape.  The stub frontend is zeros; ``src_valid``
+                # masks the padding out of cross attention
+                # (kv_valid_len).
                 batch["src_embeds"] = jnp.zeros(
-                    (self.max_batch, self.max_seq, self.cfg.d_model),
-                    jnp.float32)
-                batch["src_valid"] = jnp.asarray(sv)
-            kv = self._kv()
-            row_mask = np.zeros(self.max_batch, bool)
-            row_mask[slot] = True
-            if kv is not None:
-                # hide other rows' page tables so their KV writes DROP
-                # (heap rows stay disjoint), and zero this row's seq_len.
-                sel = jnp.asarray(row_mask)
-                kv0 = kv._replace(
-                    page_table=jnp.where(sel[:, None], kv.page_table, -1),
-                    seq_lens=jnp.where(sel, 0, kv.seq_lens))
-                caches0 = (self.caches._replace(self_kv=kv0)
-                           if self.cfg.is_encdec
-                           else self.caches._replace(kv=kv0))
-            else:
-                caches0 = self.caches
+                    (1, self.max_seq, self.cfg.d_model), jnp.float32)
+                batch["src_valid"] = jnp.full(1, lp, jnp.int32)
+            rows = int(batch["tokens"].shape[0])
             # from dispatch through the first-token read, so the span
             # ends once the device has finished the prefill
             with self.tracer.span("prefill", slot=slot, uid=req.uid,
-                                  prompt_len=lp):
-                tok_ids, new_caches = self._prefill(self.params, batch,
-                                                    caches0)
-                self.caches = merge_rows(self.cfg, new_caches, self.caches,
-                                         row_mask)
-                first = int(np.asarray(tok_ids)[slot])
+                                  prompt_len=lp, rows=rows):
+                tok_ids, self.caches = self._prefill(
+                    self.params, batch, self.caches, np.int32(slot))
+                first = int(np.asarray(tok_ids)[0])
+            self.stats["prefill_rows"] += rows
+            self.stats["prefill_tokens"] += rows * lp
             req.out_tokens.append(first)
             self.slot_req[slot] = req
             self.slot_len[slot] = lp + 1
@@ -774,10 +813,6 @@ class ServingEngine:
             if self.mega_step:
                 with self.tracer.span("slot_push", uid=req.uid, slot=slot):
                     self._mega_admit(slot, req, first)
-
-    def _merge_row(self, new_caches, row_mask):
-        """Back-compat shim over :func:`merge_rows`."""
-        return merge_rows(self.cfg, new_caches, self.caches, row_mask)
 
     # ---- fused decode mega-step (DESIGN.md §11) ----------------------------
 

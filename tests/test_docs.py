@@ -228,7 +228,9 @@ def test_design_s14_span_taxonomy_and_metric_names_documented():
                 "repro_segment_grow_total", "repro_segment_shrink_total",
                 "repro_pool_wrap_total",
                 "repro_overflow_walk_served_total",
-                "repro_arena_frag_ratio", "repro_step_time_ms"):
+                "repro_arena_frag_ratio", "repro_step_time_ms",
+                "repro_engine_prefill_rows_total",
+                "repro_engine_prefill_tokens_total"):
         assert fam in sec, f"DESIGN.md §14 lost metric family {fam!r}"
     for needle in ("validate_exposition", "require_phases=True",
                    "--metrics-file", "--trace-file", "obs_dump",

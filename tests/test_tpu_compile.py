@@ -14,12 +14,14 @@ chip:
   (qwen2-0.5b, ``max_batch=16``, ``max_seq=4096``), single-arena and
   with four shards;
 - the serving engine's fused decode tick for qwen2-0.5b at its
-  published widths with the Pallas allocator, built from shapes only.
+  published widths with the Pallas allocator, built from shapes only;
+- the engine's admission prefill of one 2048-token prompt at the same
+  widths (XLA alone: it holds no kernel).
 
-Each compile must hold a Mosaic kernel (``tpu_custom_call``).  The
-topology is described only inside the module fixture: the TPU library
-may be loaded by one process at a time, so nothing here may touch it
-while the module is imported.
+Each allocator or tick compile must hold a Mosaic kernel
+(``tpu_custom_call``).  The topology is described only inside the
+module fixture: the TPU library may be loaded by one process at a
+time, so nothing here may touch it while the module is imported.
 """
 import os
 
@@ -138,12 +140,11 @@ def test_blocked_defrag_compiles_at_serving_arena(compile_for, num_shards):
     assert "tpu_custom_call" in text
 
 
-def test_mega_tick_compiles_at_full_width(compile_for, monkeypatch):
-    """The fused decode tick (grow kernel + qwen2-0.5b forward) at
-    published widths.  The engine is built from shapes: parameters from
-    ``eval_shape`` and abstract KV caches, so nothing full-size is
-    allocated on this host; the kernels are compiled, not interpreted,
-    as they are on a chip."""
+@pytest.fixture()
+def full_width_engine(monkeypatch):
+    """qwen2-0.5b's mega-step engine at published widths, built from
+    shapes: parameters from ``eval_shape`` and abstract KV caches, so
+    nothing full-size is allocated on this host."""
     from repro.configs import get_arch
     from repro.models import model as M
     from repro.serve.engine import ServingEngine
@@ -152,14 +153,36 @@ def test_mega_tick_compiles_at_full_width(compile_for, monkeypatch):
     monkeypatch.setattr(M.Model, "make_decode_caches",
                         lambda self, *a, **k: caches(self, *a, **k,
                                                      abstract=True))
-    monkeypatch.setattr(ops, "_interpret", lambda: False)
     model = M.build_model(get_arch("qwen2-0.5b"))
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    eng = ServingEngine(model, params, max_batch=MAX_BATCH,
-                        max_seq=MAX_SEQ, alloc_backend="pallas",
-                        alloc_lowering="blocked", mega_step=True,
-                        max_new_cap=64)
+    return ServingEngine(model, params, max_batch=MAX_BATCH,
+                         max_seq=MAX_SEQ, alloc_backend="pallas",
+                         alloc_lowering="blocked", mega_step=True,
+                         max_new_cap=64)
+
+
+def test_mega_tick_compiles_at_full_width(compile_for, full_width_engine,
+                                          monkeypatch):
+    """The fused decode tick (grow kernel + qwen2-0.5b forward) at
+    published widths; the kernels are compiled, not interpreted, as
+    they are on a chip."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    eng = full_width_engine
     eng._build_mega()
     carry = jax.eval_shape(lambda *a: a, eng.params, eng.alloc_state,
                            eng.caches, eng.mega_state)
     assert "tpu_custom_call" in compile_for(eng._mega_fn, *carry)
+
+
+def test_admission_prefill_compiles_at_full_width(compile_for,
+                                                  full_width_engine):
+    """The admission prefill of one 2048-token prompt at published
+    widths computes one row: the program's activations are
+    ``(1, 2048, d_model)``, never ``(max_batch, 2048, d_model)``."""
+    eng = full_width_engine
+    lp, d = 2048, eng.cfg.d_model
+    batch = {"tokens": jax.ShapeDtypeStruct((1, lp), jnp.int32)}
+    slot = jax.ShapeDtypeStruct((), jnp.int32)
+    text = compile_for(eng._prefill, eng.params, batch, eng.caches, slot)
+    assert f"[1,{lp},{d}]" in text
+    assert f"[{MAX_BATCH},{lp},{d}]" not in text
