@@ -3,11 +3,12 @@
     python3 bench/calibrate.py --workload <name> --seeds 1,2,3 --seconds <s>
 
 Runs the cell once per seed in this one process, as ``run.py`` does,
-and also its control: for a served model the plain reference computed
-from fp8 operands put in the program's place (each position's gap of
-the token the lower precision puts first); for the allocator the
-program with overlapping grants planted.  Prints one JSON line per
-seed and mode with every number compared, as the harness judges it
+and also its control: for a served model the reference module the
+configuration names, computed at its ``CONTROL`` precision (fp8
+operands for ``qwen2_ref``) and put in the program's place (each
+position's gap of the token the lower precision puts first); for the
+allocator the program with overlapping grants planted.  Prints one JSON
+line per seed and mode with every number compared, as the harness judges it
 (the control's reading in a control run), the verdict, and for a
 served model the program's own gap on the same tokens.  The
 benchmark's own runs never run the control.
@@ -49,6 +50,7 @@ def main(argv=None) -> int:
                                    devs, counter, control=control)
             line = {"seed": seed, "control": control,
                     "checks": rec["checks"],
+                    "reference": c["config"].get("reference"),
                     "program_gap": rec.get("program_gap"),
                     "compared_tokens": rec.get("compared_tokens"),
                     "correct": harness.correct(rec["checks"])}

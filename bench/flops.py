@@ -1,6 +1,7 @@
 """Operations a dense decoder needs, counted from its sizes: the
-arithmetic behind ``mfu.*``.  Multiply-adds count as two operations.
-Only the work a token needs counts: no padding, no recomputation."""
+count a Qwen2 configuration names (``"flops": "flops"``), which
+``mfu.*`` reads.  Multiply-adds count as two operations.  Only the work
+a token needs counts: no padding, no recomputation."""
 from __future__ import annotations
 
 

@@ -1,17 +1,21 @@
 """Whole step: model operations the window's work needed (each admitted
-prompt once, unpadded; each output token at its context) over the
-window's seconds times the chip's bf16 peak (``peaks.json``).  A
-request admitted at step ``a`` gets token 0 from that step's prefill
-and token ``j >= 1`` from the tick of step ``a + j - 1``, attending to
+prompt once, unpadded; each output token at its context, counted by the
+module the configuration names under ``flops``) over the window's
+seconds times the chip's bf16 peak (``peaks.json``).  A request
+admitted at step ``a`` gets token 0 from that step's prefill and token
+``j >= 1`` from the tick of step ``a + j - 1``, attending to
 ``prompt + j`` positions.  In a traced run, the steps before the
 trace."""
-from bench import device, flops
+import importlib
+
+from bench import device
 
 
 def read(run):
     if run["system"] != "serving":
         return None
     sz = run["sizes"]
+    flops = importlib.import_module("bench." + sz["flops"])
     (t0, t1), (k0, k1) = run.get("harness",
                                  (run["window"], run["window_steps"]))
     total = 0

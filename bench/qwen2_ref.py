@@ -30,6 +30,11 @@ import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
+# the configuration file's keys this reference reads, and the ``quant``
+# its control computes at
+SIZES = ("rms_norm_eps", "rope_theta", "num_attention_heads",
+         "num_key_value_heads", "head_dim")
+CONTROL = "fp8"
 
 
 def _q8(x):

@@ -8,14 +8,33 @@ as soon as one of its requests retires); an open loop submits each
 request when its arrival falls due, whatever the engine is doing.
 
 Weights are made here, on the device in one jitted call from the seed,
-in the layout the program's model declares and the dtype it serves.
+in the layout the program's model declares and the dtype it serves,
+each leaf by a rule (``RULES``, and the configuration's ``init``).
 After the window the run checks what the timed path produced: the page
 grants of the allocator, and a sample of finished requests against the
-plain float32 reference (``qwen2_ref``).
+plain reference module the configuration names (``reference``).
+
+Everything of one architecture is in its configuration file, so a new
+one is data and modules of its own, with no edit here:
+
+- ``arch``: the program's registered architecture, built and served;
+- ``program_sizes``: each key of the model's published configuration
+  mapped to the program attribute it must equal; ``not_program_sizes``
+  lists the keys the program has no attribute for (``check_arch``);
+- ``reference``: a module of ``bench`` with ``SIZES`` (the keys of the
+  file it reads), ``CONTROL`` (the ``quant`` of its control) and
+  ``served_gaps(params, sizes, prompt, served, pad_to, quant=None)``;
+- ``flops``: a module of ``bench`` with ``prefill_flops(sizes, n)`` and
+  ``decode_token_flops(sizes, context)``, which ``mfu.*`` reads;
+- ``init``: weight rules by leaf name, added to ``RULES`` or in place
+  of one of them (``KINDS``);
+- ``engine``: arguments of ``ServingEngine`` for every cell, beside the
+  traffic file's own ``engine`` geometry.
 """
 from __future__ import annotations
 
 import gc
+import importlib
 import math
 import time
 from typing import Dict, List
@@ -34,52 +53,132 @@ def _key(seed: int):
     return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32))
 
 
-def make_params(shapes, sizes: dict, seed: int):
-    """Random weights for a tree of ``ShapeDtypeStruct`` (the program's
-    layout), one jitted call on the device.  Matrices: normal with
-    variance 1/fan-in (fan-in is the second-to-last axis); the embedding:
-    variance 1/hidden, rows past the vocabulary zero; norm scales
-    1 + N(0, 0.1²); Q/K/V biases N(0, 0.5²)."""
+# the rules every configuration starts from, by leaf name; its ``init``
+# adds to them or replaces one.  A leaf no rule names is a matrix, drawn
+# normal with variance 1/fan-in, its fan-in the second-to-last axis of
+# one layer's slice.
+RULES = {"embed": {"embedding": True},
+         "scale": {"normal": [1.0, 0.1]},
+         "bq": {"normal": [0.0, 0.5]},
+         "bk": {"normal": [0.0, 0.5]},
+         "bv": {"normal": [0.0, 0.5]}}
+# a rule has one of these, and may add ``"then": "log"`` or
+# ``"then": "inv_softplus"`` (x + log(1 - exp(-x))):
+#   embedding: N(0, 1/hidden_size), rows past vocab_size zero
+#   const: c;  normal: [mean, std];  uniform: [low, high]
+#   log_uniform: [low, high], uniform in log
+KINDS = ("embedding", "const", "normal", "uniform", "log_uniform")
+THEN = ("log", "inv_softplus")
+
+
+def init_rules(sizes: dict) -> dict:
+    """The configuration's weight rules over ``RULES``, each checked."""
+    rules = dict(RULES, **sizes.get("init", {}))
+    for name, r in rules.items():
+        kind = [k for k in r if k != "then"]
+        if (len(kind) != 1 or kind[0] not in KINDS
+                or r.get("then", THEN[0]) not in THEN):
+            raise ValueError(f"init rule {name!r} is not one of {KINDS} "
+                             f"with an optional then in {THEN}: {r}")
+    return rules
+
+
+def _draw(key, sd, fan, rule, sizes):
+    """One leaf of shape ``sd`` by ``rule`` (None: over its fan-in);
+    ``fan`` is its shape less the layer axis."""
     import jax
     import jax.numpy as jnp
 
+    if rule is None:
+        if len(fan) < 2:
+            raise ValueError("a vector leaf needs a rule under init")
+        return jax.random.normal(key, sd.shape, jnp.float32) * fan[-2] ** -0.5
+    (kind, arg), = ((k, v) for k, v in rule.items() if k != "then")
+    if kind == "embedding":
+        z = jax.random.normal(key, sd.shape, jnp.float32)
+        rows = jnp.arange(sd.shape[0])[:, None] < sizes["vocab_size"]
+        x = jnp.where(rows, z * sizes["hidden_size"] ** -0.5, 0.0)
+    elif kind == "const":
+        x = jnp.full(sd.shape, float(arg), jnp.float32)
+    elif kind == "normal":
+        mean, std = arg
+        x = std * jax.random.normal(key, sd.shape, jnp.float32)
+        if mean:
+            x = mean + x
+    else:
+        lo, hi = ((math.log(a) for a in arg) if kind == "log_uniform"
+                  else arg)
+        x = jax.random.uniform(key, sd.shape, jnp.float32, lo, hi)
+        if kind == "log_uniform":
+            x = jnp.exp(x)
+    if rule.get("then") == "log":
+        x = jnp.log(x)
+    elif rule.get("then") == "inv_softplus":
+        x = x + jnp.log(-jnp.expm1(-x))
+    return x
+
+
+def make_params(shapes, axes, sizes: dict, seed: int):
+    """Random weights for a tree of ``ShapeDtypeStruct`` (the program's
+    layout) whose logical axes are ``axes`` (a stacked leaf's layer axis
+    is named ``layers``), one jitted call on the device, each leaf by
+    its rule (``init_rules``)."""
+    import jax
+
     leaves, tree = jax.tree_util.tree_flatten_with_path(shapes)
-    d, vocab = sizes["hidden_size"], sizes["vocab_size"]
+    leaf_axes = jax.tree.leaves(axes,
+                                is_leaf=lambda x: isinstance(x, tuple))
+    rules = init_rules(sizes)
 
     def init(key):
         keys = jax.random.split(key, len(leaves))
         out = []
-        for k, (path, sd) in zip(keys, leaves):
+        for k, (path, sd), ax in zip(keys, leaves, leaf_axes):
             name = str(getattr(path[-1], "key", path[-1]))
-            z = jax.random.normal(k, sd.shape, jnp.float32)
-            if name == "embed":
-                rows = jnp.arange(sd.shape[0])[:, None] < vocab
-                x = jnp.where(rows, z * d ** -0.5, 0.0)
-            elif name == "scale":
-                x = 1.0 + 0.1 * z
-            elif name in ("bq", "bk", "bv"):
-                x = 0.5 * z
-            else:
-                x = z * sd.shape[-2] ** -0.5
+            fan = [n for n, a in zip(sd.shape, ax) if a != "layers"]
+            try:
+                x = _draw(k, sd, fan, rules.get(name), sizes)
+            except ValueError as e:
+                raise ValueError(f"{jax.tree_util.keystr(path)}: {e}")
             out.append(x.astype(sd.dtype))
         return jax.tree_util.tree_unflatten(tree, out)
 
     return jax.jit(init)(_key(seed))
 
 
+# keys of a serving configuration file that are the harness's own; every
+# other key is the model's, compared with the program or listed as not
+# the program's
+HARNESS_KEYS = frozenset((
+    "system", "arch", "source", "reference", "flops", "init",
+    "program_sizes", "not_program_sizes", "param_dtype", "kv_dtype",
+    "compute_dtype", "engine", "deployment", "assumed"))
+
+
 def check_arch(cfg, sizes: dict):
-    """The program's architecture must be the configuration file's."""
-    got = {"num_hidden_layers": cfg.num_layers, "hidden_size": cfg.d_model,
-           "num_attention_heads": cfg.num_heads,
-           "num_key_value_heads": cfg.num_kv_heads,
-           "head_dim": cfg.head_dim_, "intermediate_size": cfg.d_ff,
-           "vocab_size": cfg.vocab_size, "rope_theta": cfg.rope_theta,
-           "tie_word_embeddings": cfg.tie_embeddings,
-           "qkv_bias": cfg.qkv_bias}
-    bad = {k: (v, sizes[k]) for k, v in got.items() if v != sizes[k]}
+    """The program's architecture must be the configuration file's: each
+    key in ``program_sizes`` equals the program attribute it names, and
+    every key of the model is either there or in ``not_program_sizes``."""
+    mapped = sizes["program_sizes"]
+    loose = (set(sizes) - HARNESS_KEYS - set(mapped)
+             - set(sizes["not_program_sizes"]))
+    missing = set(mapped) - set(sizes)
+    if loose or missing:
+        raise ValueError(f"configuration keys neither mapped to the program "
+                         f"nor listed as not its sizes: {sorted(loose)}; "
+                         f"mapped but not in the file: {sorted(missing)}")
+    got = {k: getattr(cfg, a) for k, a in mapped.items()}
+    bad = {k: (v, sizes[k]) for k, v in got.items()
+           if (list(v) if isinstance(v, tuple) else v) != sizes[k]}
     if bad:
         raise ValueError(f"program arch {cfg.name!r} differs from the "
                          f"configuration file: {bad}")
+
+
+def reference(sizes: dict):
+    """The configuration's reference module and the sizes it reads."""
+    ref = importlib.import_module("bench." + sizes["reference"])
+    return ref, {k: sizes[k] for k in ref.SIZES}
 
 
 class Driver:
@@ -160,14 +259,11 @@ def _engine(cell: dict, seed: int):
            for x in jax.tree.leaves(shapes)):
         raise ValueError("program parameters are not in the configured "
                          f"{sizes['param_dtype']}")
-    params = make_params(shapes, sizes, seed)
-    geo = mix["engine"]
+    params = make_params(shapes, model.logical_axes(), sizes, seed)
     eng = ServingEngine(
-        model, params, max_batch=geo["max_batch"], max_seq=geo["max_seq"],
-        max_new_cap=geo["max_new_cap"],
-        kv_dtype=jnp.dtype(sizes["kv_dtype"]),
+        model, params, kv_dtype=jnp.dtype(sizes["kv_dtype"]),
         compute_dtype=jnp.dtype(sizes["compute_dtype"]),
-        **sizes["engine"])
+        **sizes["engine"], **mix["engine"])
     return params, eng
 
 
@@ -364,8 +460,12 @@ def _checks(drv, params, cell, seed, out, control) -> dict:
     """What the timed path produced, each number beside its limit."""
     eng, mix, sizes = drv.eng, cell["mix"], cell["config"]
     lim = mix["limits"]
-    pt = np.asarray(eng.caches.kv.page_table)
-    live = pt[pt >= 0]
+    # every page a slot holds: its page-table pages (none where the
+    # program keeps no KV) and its state pages
+    kv = eng._kv()
+    pt = np.asarray(kv.page_table) if kv is not None else np.zeros(0, int)
+    live = np.concatenate([pt[pt >= 0].astype(np.int64), np.asarray(
+        [p for aux in eng.slot_aux for p in aux], np.int64)])
     checks = {
         "page_dups": (int(live.size - np.unique(live).size), 0),
         "page_balance": (abs(int(eng.stats["allocs"]) - int(
@@ -386,10 +486,7 @@ def _checks(drv, params, cell, seed, out, control) -> dict:
     drv.eng = None
     del eng
     gc.collect()
-    from bench import qwen2_ref as ref
-    ref_sizes = {k: sizes[k] for k in (
-        "rms_norm_eps", "rope_theta", "num_attention_heads",
-        "num_key_value_heads", "head_dim")}
+    ref, ref_sizes = reference(sizes)
     gap, ctl = 0.0, None
     t0 = time.perf_counter()
     for r in pick:
@@ -399,7 +496,7 @@ def _checks(drv, params, cell, seed, out, control) -> dict:
         gap = max(gap, float(g.max()))
         if control:
             c = ref.served_gaps(params, ref_sizes, prompts[id(r)], r["out"],
-                                pad, quant="fp8")
+                                pad, quant=ref.CONTROL)
             ctl = max(ctl or 0.0, float(c.max()))
     out["reference_s"] = time.perf_counter() - t0
     if not pick:

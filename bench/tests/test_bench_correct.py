@@ -39,7 +39,9 @@ def tiny_serving(monkeypatch):
                  intermediate_size=sm.d_ff, vocab_size=sm.vocab_size,
                  engine={"alloc_backend": "jnp", "mega_step": True})
     mix = _load("traffic", "reasoning.json")
-    mix.update(clients=4, block=4,
+    # up to sixteen finished requests compared, some 240 tokens: with
+    # four, the control's widest gap hangs on which four finished
+    mix.update(clients=4, block=4, check_requests=16,
                prompt_tokens={"dist": "choice", "values": [16, 32]},
                output_tokens={"dist": "loguniform", "low": 8, "high": 24},
                engine={"max_batch": 4, "max_seq": 96, "max_new_cap": 32})
@@ -100,7 +102,8 @@ def test_knee_sweep_runs_the_cells_open_loop(tiny_serving, monkeypatch,
         (5, 2.0), (5, 4.0), (6, 2.0), (6, 4.0)]
     for x in lines:
         assert x["compiles_in_window"] == 0 and x["unfinished"] == 0
-        assert x["due_in_window"] == 0 or x["ttft_p90_ms"] > 0
+        assert x["due_in_window"] == 0 or (
+            0 < x["ttft_p85_ms"] <= x["ttft_p90_ms"])
 
 
 def test_serving_program_passes_control_and_fault_fail(tiny_serving,
@@ -108,14 +111,14 @@ def test_serving_program_passes_control_and_fault_fail(tiny_serving,
     rec, ok = _run(tiny_serving, 2 ** 40 + 5)
     assert ok, rec["checks"]
     assert rec["compared_tokens"] > 0
-    gap = rec["checks"]["logit_gap"][0]
 
-    # the fp8 control in the program's place, on the same tokens, reads
-    # far above the program and is judged not correct
+    # the fp8 control in the program's place, on the tokens the program
+    # served in the same run, reads far above the program's own gap and
+    # is judged not correct (two runs of one seed need not compare the
+    # same requests: which ones finish follows the host clock)
     rec, ok = _run(tiny_serving, 2 ** 40 + 5, control=True)
-    assert rec["program_gap"] == gap
     ctl, limit = rec["checks"]["logit_gap"]
-    assert ctl > max(3 * gap, 1e-3)
+    assert ctl > max(3 * rec["program_gap"], 1e-3)
     assert not ok and ctl > limit
 
     # a token altered where it is produced: the prefill's argmax

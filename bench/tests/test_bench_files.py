@@ -84,3 +84,29 @@ def test_config_files_are_the_program_arch(bm):
             cfg = json.load(f)
         if cfg["system"] == "serving":
             serving.check_arch(get_arch(cfg["arch"]), cfg)
+
+
+def test_serving_configs_name_their_modules(bm):
+    """Each serving configuration names a reference module that declares
+    the keys it reads and its control, an operation count, and weight
+    rules of known kinds; its engine keys and its cells' are arguments
+    of the program's engine."""
+    import importlib
+    import inspect
+    sys.path.insert(0, os.path.join(ROOT, "src"))
+    from bench import serving
+    from repro.serve.engine import ServingEngine
+    args = set(inspect.signature(ServingEngine).parameters)
+    for w in bm["workloads"]:
+        c = harness.cell(bm, w["name"], ROOT)
+        cfg = c["config"]
+        if cfg["system"] != "serving":
+            continue
+        ref, sizes = serving.reference(cfg)
+        assert set(sizes) == set(ref.SIZES) <= set(cfg)
+        assert callable(ref.served_gaps) and ref.CONTROL
+        flops = importlib.import_module("bench." + cfg["flops"])
+        assert flops.prefill_flops(cfg, 16) > flops.decode_token_flops(
+            cfg, 16) > 0
+        assert set(serving.init_rules(cfg)) >= set(serving.RULES)
+        assert set(cfg["engine"]) | set(c["mix"]["engine"]) <= args

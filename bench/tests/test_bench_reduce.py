@@ -16,7 +16,8 @@ TRACE = os.path.join(ROOT, "bench", "testdata", "paper_iter.xplane.pb")
 
 QWEN2 = {"num_hidden_layers": 24, "hidden_size": 896,
          "num_attention_heads": 14, "num_key_value_heads": 2,
-         "head_dim": 64, "intermediate_size": 4864, "vocab_size": 151936}
+         "head_dim": 64, "intermediate_size": 4864, "vocab_size": 151936,
+         "flops": "flops"}
 
 
 def test_percentile_nearest_rank():
@@ -72,8 +73,10 @@ def _serving_run(**kw):
 
 def test_serving_readers_on_a_synthetic_timeline():
     run = _serving_run()
-    # first tokens at 0.3 - 0.0 and 0.9 - 0.5: the p90 of two is the max
-    assert harness.reader("ttft_p90_ms")(run) == pytest.approx(400.0)
+    # first tokens at 0.3 - 0.0 and 0.9 - 0.5: the p85 and the p90 of
+    # two are the max
+    assert harness.reader("ttft_p85_ms")(run) == pytest.approx(400.0)
+    assert harness.reader("ttft_ms_p90.ttft")(run) == pytest.approx(400.0)
     assert harness.reader("tpot_p90_ms")(run) == pytest.approx(100.0)
     assert harness.reader("queue_wait_ms_p90.ttft")(run) == \
         pytest.approx(100.0)
@@ -95,6 +98,9 @@ def test_traced_runs_read_the_harness_clock_before_the_trace():
     run["harness"] = ((0.0, 0.85), (0, 4))
     assert harness.reader("queue_wait_ms_p90.ttft")(run) == \
         pytest.approx(300.0)
+    assert harness.reader("ttft_ms_p90.ttft")(run) == pytest.approx(400.0)
+    run["harness"] = ((0.0, 0.75), (0, 4))
+    assert harness.reader("ttft_ms_p90.ttft")(run) == pytest.approx(300.0)
     run["harness"] = ((0.0, 0.55), (0, 4))
     assert harness.reader("batch_occupancy.tput")(run) == \
         pytest.approx(100.0 * (4 + 2) / 8)
@@ -109,7 +115,8 @@ def test_unadmitted_request_counts_its_wait():
     run = _serving_run()
     run["rec"][1]["admit1"] = None
     # not admitted by the drain limit (1.0 + 2.0): it waited 3.0 - 0.5
-    assert harness.reader("ttft_p90_ms")(run) == pytest.approx(2500.0)
+    assert harness.reader("ttft_p85_ms")(run) == pytest.approx(2500.0)
+    assert harness.reader("ttft_ms_p90.ttft")(run) == pytest.approx(2500.0)
 
 
 def test_mfu_counts_each_token_at_its_context():
