@@ -52,10 +52,14 @@ class ModelConfig:
     tie_embeddings: bool = False
     logit_softcap: Optional[float] = None
 
-    # moe
+    # moe: the router scores all ``num_experts``; this chip holds and
+    # computes experts [expert_offset, expert_offset + held_experts)
     num_experts: int = 0
     num_experts_per_tok: int = 0
     moe_capacity_factor: float = 1.25
+    num_held_experts: Optional[int] = None  # None: every expert
+    expert_offset: int = 0
+    shared_expert_d_ff: int = 0             # 0: no shared expert
 
     # ssm (mamba2 SSD)
     ssm_state: int = 0
@@ -70,6 +74,20 @@ class ModelConfig:
     attn_period: int = 3
     local_window: int = 2048
 
+    # a stack served from its layer list (granite-4.0-h): one kind per
+    # layer, "mamba" (Mamba-2 mixer) or "attention", each followed by
+    # the MoE; the recurrent state lives in arena pages
+    layer_types: Optional[Tuple[str, ...]] = None
+
+    # published constants; the defaults leave every other config as is
+    norm_eps: float = 1e-6
+    position_embedding: str = "rope"        # rope | nope
+    attn_scale: Optional[float] = None      # default head_dim ** -0.5
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0             # logits are divided by it
+    param_dtype: str = "float32"
+
     # encoder-decoder
     enc_layers: int = 0
 
@@ -83,6 +101,16 @@ class ModelConfig:
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+    @property
+    def held_experts(self) -> int:
+        return (self.num_experts if self.num_held_experts is None
+                else self.num_held_experts)
+
+    @property
+    def attn_scale_(self) -> float:
+        return (self.head_dim_ ** -0.5 if self.attn_scale is None
+                else self.attn_scale)
 
     @property
     def is_encdec(self) -> bool:
@@ -120,7 +148,16 @@ class ModelConfig:
                     + self.num_heads * hd * d)
         per_mlp = 3 * d * f
         n = 0
-        if self.family == "ssm":
+        if self.layer_types is not None:
+            di, ns, g = self.d_inner, self.ssm_state, self.ssm_ngroups
+            per_mamba = (d * (2 * di + 2 * g * ns + self.ssm_nheads)
+                         + di * d)
+            per_moe = (self.held_experts * per_mlp + d * self.num_experts
+                       + 3 * d * self.shared_expert_d_ff)
+            n_attn = self.layer_types.count("attention")
+            n += (n_attn * per_attn + (self.num_layers - n_attn) * per_mamba
+                  + self.num_layers * per_moe)
+        elif self.family == "ssm":
             di, ns = self.d_inner, self.ssm_state
             per = (d * (2 * di + 2 * self.ssm_ngroups * ns + self.ssm_nheads)
                    + di * d)
@@ -149,13 +186,29 @@ class ModelConfig:
         if not self.num_experts:
             return self.param_count()
         full = self.param_count()
-        moe = self.num_layers * self.num_experts * 3 * self.d_model * self.d_ff
+        moe = (self.num_layers * self.held_experts * 3 * self.d_model
+               * self.d_ff)
         active = self.num_layers * self.num_experts_per_tok * 3 \
             * self.d_model * self.d_ff
         return full - moe + active
 
     def smoke(self) -> "ModelConfig":
-        """Reduced same-family config for CPU smoke tests."""
+        """Reduced same-family config for CPU smoke tests.  A layer list
+        keeps both kinds (its first attention layer and the Mamba layer
+        before it), a held share smaller than the router's width, and
+        the shared expert."""
+        if self.layer_types is not None:
+            a = self.layer_types.index("attention")
+            return dataclasses.replace(
+                self, name=self.name + "-smoke", num_layers=3,
+                layer_types=("mamba", "attention", "mamba") if a else
+                ("attention", "mamba", "mamba"),
+                d_model=128, num_heads=4, num_kv_heads=2, head_dim=32,
+                d_ff=64, vocab_size=512, num_experts=8,
+                num_experts_per_tok=3, num_held_experts=4,
+                expert_offset=0, shared_expert_d_ff=96,
+                ssm_state=32, ssm_headdim=16, ssm_chunk=16,
+                attn_scale=1 / 32, param_dtype="float32")
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",

@@ -45,7 +45,8 @@ def norm_spec(cfg: ModelConfig, d: Optional[int] = None):
     return {"scale": Spec((d,), ("embed",), "ones")}
 
 
-def apply_norm(cfg: ModelConfig, p, x, eps: float = 1e-6):
+def apply_norm(cfg: ModelConfig, p, x, eps: Optional[float] = None):
+    eps = cfg.norm_eps if eps is None else eps
     xf = x.astype(jnp.float32)
     if cfg.norm == "layernorm":
         mu = xf.mean(-1, keepdims=True)
@@ -92,12 +93,13 @@ def apply_rope(cfg: ModelConfig, x, positions):
 
 def flash_attention(q, k, v, *, causal: bool, window: Optional[int] = None,
                     q_offset=0, kv_valid_len=None, block: int = 512,
-                    block_q: int = 4096):
+                    block_q: int = 4096, scale: Optional[float] = None):
     """q: (B, S, Hq, D); k, v: (B, T, Hkv, D).  GQA via head grouping.
 
     ``q_offset``: global position of q[0] relative to k[0] (decode /
     chunked prefill).  ``window``: sliding-window width (None = full).
     ``kv_valid_len``: (B,) valid kv length (padding mask).
+    ``scale``: score scale (None = D ** -0.5).
     Long sequences are additionally blocked over q (``block_q``) so the
     live score/accumulator tensors stay O(block_q·block), not O(S·block)
     — prefill_32k peaked at 61 GiB/chip without it.
@@ -114,7 +116,7 @@ def flash_attention(q, k, v, *, causal: bool, window: Optional[int] = None,
             qi, oi = args
             return flash_attention(qi, k, v, causal=causal, window=window,
                                    q_offset=oi, kv_valid_len=kv_valid_len,
-                                   block=block, block_q=S)
+                                   block=block, block_q=S, scale=scale)
 
         out = jax.lax.map(one, (qb, offs))
         out = out.transpose(1, 0, 2, 3, 4).reshape(B, nq * block_q, Hq, D)
@@ -131,7 +133,8 @@ def flash_attention(q, k, v, *, causal: bool, window: Optional[int] = None,
     vb = v.reshape(B, nblocks, block, Hkv, D).transpose(1, 0, 2, 3, 4)
 
     stage_dt = jnp.float32 if q.dtype == jnp.float32 else jnp.bfloat16
-    qg = (q.reshape(B, S, Hkv, G, D) * (D ** -0.5)).astype(stage_dt)
+    qg = (q.reshape(B, S, Hkv, G, D)
+          * (D ** -0.5 if scale is None else scale)).astype(stage_dt)
     qpos = q_offset + jnp.arange(S, dtype=jnp.int32)
 
     def body(carry, inp):
@@ -192,7 +195,7 @@ def attn_specs(cfg: ModelConfig):
     return s
 
 
-def qkv_project(cfg: ModelConfig, p, x, positions, *, rope: bool = True):
+def qkv_project(cfg: ModelConfig, p, x, positions):
     B, S, _ = x.shape
     hd = cfg.head_dim_
     q = x @ p["wq"].astype(x.dtype)
@@ -205,7 +208,7 @@ def qkv_project(cfg: ModelConfig, p, x, positions, *, rope: bool = True):
     q = q.reshape(B, S, cfg.num_heads, hd)
     k = k.reshape(B, S, cfg.num_kv_heads, hd)
     v = v.reshape(B, S, cfg.num_kv_heads, hd)
-    if rope:
+    if cfg.position_embedding == "rope":
         q = apply_rope(cfg, q, positions)
         k = apply_rope(cfg, k, positions)
     return q, k, v

@@ -58,6 +58,8 @@ def _chunked_ce(cfg, params, h, targets, chunk: int = 512):
         hc, tc = inp
         logits = constrain((hc @ w).astype(jnp.float32),
                            "batch", None, "vocab")
+        if cfg.logits_scaling != 1:
+            logits = logits / cfg.logits_scaling
         if cfg.logit_softcap:
             logits = cfg.logit_softcap * jnp.tanh(
                 logits / cfg.logit_softcap)
@@ -146,6 +148,13 @@ class Model:
                           else None))
             if window:
                 pps = min(pps, window // page + 2)
+        if cfg.layer_types is not None:
+            # the page heap holds the recurrent state too: float32
+            if kv_dtype not in (None, jnp.float32):
+                raise ValueError(
+                    f"{cfg.name} keeps its recurrent state in the page "
+                    f"heap, which must be float32, not {kv_dtype}")
+            kv_dtype = jnp.float32
         kv_dtype = kv_dtype or kv_dtype_for(cfg, max_seq, batch)
         mk = KV.abstract_paged_kv if abstract else KV.init_paged_kv
 
@@ -161,6 +170,8 @@ class Model:
 
         n_attn = TF.num_attn_layers(cfg)
         n_rec = TF.num_rec_layers(cfg)
+        if cfg.layer_types is not None:
+            return self._pattern_caches(batch, paged, kv_dtype, abstract)
         kv = paged(n_attn) if n_attn else None
         ssm_h = ssm_conv = None
         if n_rec:
@@ -180,6 +191,23 @@ class Model:
                 ssm_h = jnp.zeros(h_shape, jnp.float32)
                 ssm_conv = jnp.zeros(c_shape, jnp.bfloat16)
         return TF.Caches(kv=kv, ssm_h=ssm_h, ssm_conv=ssm_conv)
+
+    def _pattern_caches(self, batch, paged, kv_dtype, abstract):
+        """A layer-list model's caches: one page heap for the attention
+        layers' K/V and the Mamba layers' state pages, each slot's
+        state-page table, and the per-expert token counts."""
+        cfg = self.cfg
+        n_mamba = cfg.layer_types.count("mamba")
+        shapes = {"state_table": ((batch, n_mamba,
+                                   KV.state_pages_per_layer(cfg)), -1),
+                  "expert_tokens": ((cfg.num_layers, cfg.held_experts), 0)}
+        if abstract:
+            arrs = {k: jax.ShapeDtypeStruct(sh, jnp.int32)
+                    for k, (sh, _) in shapes.items()}
+        else:
+            arrs = {k: jnp.full(sh, v, jnp.int32)
+                    for k, (sh, v) in shapes.items()}
+        return TF.Caches(kv=paged(TF.num_attn_layers(cfg)), **arrs)
 
     def prefill(self, params, batch, caches, remat_policy: str = "full",
                 dtype=jnp.bfloat16):

@@ -151,23 +151,34 @@ def apply_ssm_layer(cfg: ModelConfig, p, x, cache=None):
     if cache is None:
         y, hf = ssd_jnp(xh, dt, a, b, c, cfg.ssm_chunk)
     else:
-        h0 = cache[0]
-        # O(1) decode recurrence (S == 1)
+        # O(1) decode recurrence (S == 1).  The state may come as a
+        # tuple of head slices in head order (a layer-list model reads
+        # it from page rows so); each slice is updated on its own
         decay = jnp.exp(dt[:, 0] * a[None, :])       # (B, H)
         rep = H // g
         bh = jnp.repeat(b[:, 0], rep, axis=1)        # (B, H, N)
         ch = jnp.repeat(c[:, 0], rep, axis=1)
-        hf = (h0 * decay[:, :, None, None]
-              + (dt[:, 0, :, None] * xh[:, 0].astype(jnp.float32))[..., None]
-              * bh[:, :, None, :].astype(jnp.float32))
-        y = jnp.einsum("bhpn,bhn->bhp", hf,
-                       ch.astype(jnp.float32))[:, None]
+        dtx = dt[:, 0, :, None] * xh[:, 0].astype(jnp.float32)
+        parts = cache[0] if isinstance(cache[0], tuple) else (cache[0],)
+        hf, ys, lo = [], [], 0
+        for h0 in parts:
+            hi = lo + h0.shape[1]
+            hs = (h0 * decay[:, lo:hi, None, None]
+                  + dtx[:, lo:hi, :, None]
+                  * bh[:, lo:hi, None, :].astype(jnp.float32))
+            ys.append(jnp.einsum("bhpn,bhn->bhp", hs,
+                                 ch[:, lo:hi].astype(jnp.float32)))
+            hf.append(hs)
+            lo = hi
+        hf = tuple(hf) if isinstance(cache[0], tuple) else hf[0]
+        y = jnp.concatenate(ys, 1)[:, None] if len(ys) > 1 else ys[0][:, None]
 
     y = y + p["d_skip"][None, None, :, None].astype(jnp.float32) \
         * xh.astype(jnp.float32)
     y = y.reshape(B, S, di).astype(x.dtype) * jax.nn.silu(z)
     yf = y.astype(jnp.float32)
-    y = (yf * jax.lax.rsqrt((yf ** 2).mean(-1, keepdims=True) + 1e-6)
+    var = (yf ** 2).mean(-1, keepdims=True)
+    y = (yf * jax.lax.rsqrt(var + cfg.norm_eps)
          * p["norm_scale"][None, None, :]).astype(x.dtype)
     out = y @ p["out_proj"].astype(x.dtype)
     return out, (hf, new_conv)

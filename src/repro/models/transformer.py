@@ -37,6 +37,11 @@ class Caches(NamedTuple):
     kv: Optional[KV.PagedKV] = None      # attention layers
     ssm_h: Optional[Any] = None          # (Lr, B, H, P, N) f32
     ssm_conv: Optional[Any] = None       # (Lr, B, W-1, conv_dim)
+    # a layer-list model: each slot's state pages in the page heap,
+    # (B, Mamba layers, pages a layer) int32, -1 = none; and the tokens
+    # routed to each held expert, (layers, held) int32, summed on device
+    state_table: Optional[Any] = None
+    expert_tokens: Optional[Any] = None
 
 
 # ---------------------------------------------------------------------------
@@ -67,11 +72,28 @@ def hybrid_triple_specs(cfg: ModelConfig):
     return {"rec1": rec, "rec2": rec, "attn": att}
 
 
+def pattern_block_specs(cfg: ModelConfig, kind: str):
+    """One layer of a layer-list stack: its mixer, then the MoE."""
+    mixer = ({"mixer": Ssm.ssm_specs(cfg)} if kind == "mamba"
+             else {"attn": Lyr.attn_specs(cfg)})
+    return {"norm1": Lyr.norm_spec(cfg), **mixer,
+            "norm2": Lyr.norm_spec(cfg), "ffn": Moe.moe_specs(cfg)}
+
+
+# a layer list's kinds and the params key each kind's stack is under
+PATTERN_KINDS = {"mamba": "mamba", "attention": "attn"}
+
+
 def lm_specs(cfg: ModelConfig):
     v, d = cfg.padded_vocab, cfg.d_model
     s = {"embed": Spec((v, d), ("vocab", "embed")),
          "final_norm": Lyr.norm_spec(cfg)}
-    if cfg.family == "hybrid":
+    if cfg.layer_types is not None:
+        for kind, key in PATTERN_KINDS.items():
+            n = cfg.layer_types.count(kind)
+            if n:
+                s[key] = Prm.stack(pattern_block_specs(cfg, kind), n)
+    elif cfg.family == "hybrid":
         ntri, tail = divmod(cfg.num_layers, cfg.attn_period)
         s["triples"] = Prm.stack(hybrid_triple_specs(cfg), ntri)
         if tail:
@@ -81,10 +103,16 @@ def lm_specs(cfg: ModelConfig):
         s["blocks"] = Prm.stack(block_specs(cfg), cfg.num_layers)
     if not cfg.tie_embeddings:
         s["lm_head"] = Spec((d, v), ("embed", "vocab"))
+    if cfg.param_dtype != "float32":
+        dt = jnp.dtype(cfg.param_dtype)
+        s = jax.tree.map(lambda x: x._replace(dtype=dt), s,
+                         is_leaf=lambda x: isinstance(x, Spec))
     return s
 
 
 def num_attn_layers(cfg: ModelConfig) -> int:
+    if cfg.layer_types is not None:
+        return cfg.layer_types.count("attention")
     if cfg.family == "ssm":
         return 0
     if cfg.family == "hybrid":
@@ -93,6 +121,10 @@ def num_attn_layers(cfg: ModelConfig) -> int:
 
 
 def num_rec_layers(cfg: ModelConfig) -> int:
+    """Layers whose state lives in dense per-slot arrays (a layer-list
+    model keeps its recurrent state in pages: none)."""
+    if cfg.layer_types is not None:
+        return 0
     if cfg.family == "ssm":
         return cfg.num_layers
     if cfg.family == "hybrid":
@@ -124,7 +156,8 @@ def _attn_mix(cfg, p, x, positions, mode, kvl, page_table, seq_lens,
 
 def _ffn(cfg, p, x, mode="train"):
     if cfg.num_experts:
-        return Moe.apply_moe(cfg, p, x, no_drop=(mode == "decode"))
+        y, aux, _ = Moe.apply_moe(cfg, p, x, no_drop=(mode != "train"))
+        return y, aux
     return Lyr.apply_mlp(cfg, p, x), jnp.float32(0.0)
 
 
@@ -280,12 +313,104 @@ def hybrid_stack(cfg, params, x, positions, mode, caches: Caches,
     return x, jnp.float32(0.0), new
 
 
+def _runs(layer_types):
+    """(kind, first index within its kind, length) of each run of
+    consecutive layers of one kind, in layer order."""
+    runs, seen = [], {}
+    for kind in layer_types:
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, seen.get(kind, 0), 1])
+        seen[kind] = seen.get(kind, 0) + 1
+    return [tuple(r) for r in runs]
+
+
+def pattern_stack(cfg, params, x, positions, mode, caches: Caches,
+                  remat_policy="full"):
+    """A stack driven by ``cfg.layer_types`` (granite-4.0-h).  Each layer:
+    pre-norm → mixer (Mamba-2, or attention without rotary embedding at
+    ``attn_scale``) → residual + ``residual_multiplier`` × branch →
+    pre-norm → held-expert MoE plus the shared expert → residual +
+    ``residual_multiplier`` × branch.
+
+    Parameters are stacked per kind and each layer's are indexed inside
+    a scan over its run of consecutive layers of one kind, so a kind's
+    layer body is traced once per run and never unrolled.  In serving
+    the page heap (``caches.kv.layers``: the attention layers' K/V and
+    the Mamba layers' state pages) is a scan carry, read and written at
+    the pages the slot tables name and never copied whole.  The prefill
+    writes each Mamba layer's final SSD state and conv tail into the
+    row's state pages; decode reads and writes them."""
+    kv = caches.kv
+    serving = mode != "train"
+    rm = cfg.residual_multiplier
+    heap = kv.layers if serving else None
+    page_table = kv.page_table if serving else None
+    seq_lens = kv.seq_lens if serving else None
+    table = caches.state_table
+    valid = None if table is None else table[:, 0, 0] >= 0
+
+    def moe(p, x):
+        h = Lyr.apply_norm(cfg, p["norm2"], x)
+        f, aux, load = Moe.apply_moe(cfg, p["ffn"], h, no_drop=serving,
+                                     valid=valid)
+        return x + rm * f, aux, load
+
+    def layer(kind, carry, i):
+        x, heap, aux = carry
+        x = constrain(x, "batch", "seq", "act_embed")
+        p = jax.tree.map(lambda a: a[i], params[PATTERN_KINDS[kind]])
+        h = Lyr.apply_norm(cfg, p["norm1"], x)
+        if kind == "mamba":
+            ids = None if table is None else table[:, i]
+            state = (KV.read_state(cfg, heap, ids) if mode == "decode"
+                     else None)
+            y, (hf, conv) = Ssm.apply_ssm_layer(cfg, p["mixer"], h, state)
+            if serving:   # an admission's row holds all its pages
+                heap = KV.write_state(cfg, heap, ids, hf, conv,
+                                      holes=mode == "decode")
+        else:
+            q, k, v = Lyr.qkv_project(cfg, p["attn"], h, positions)
+            if mode == "decode":
+                heap = KV.append1_at(heap, i, page_table, seq_lens, k, v)
+                o = KV.paged_attend1(KV.layer_of(heap, i), page_table,
+                                     seq_lens + 1, q, scale=cfg.attn_scale_,
+                                     precision=jax.lax.Precision.HIGHEST)
+            else:
+                o = Lyr.flash_attention(q, k, v, causal=True,
+                                        scale=cfg.attn_scale_)
+                if mode == "prefill":
+                    heap = KV.prefill_write_at(heap, i, page_table, k, v)
+            y = Lyr.attn_out(p["attn"], o, x.dtype)
+        x, a, load = moe(p, x + rm * y)
+        return (x, heap, aux + a), load
+
+    carry, loads = (x, heap, jnp.float32(0.0)), []
+    for kind, i0, n in _runs(cfg.layer_types):
+        body = _remat(functools.partial(layer, kind), remat_policy)
+        carry, load = jax.lax.scan(body, carry,
+                                   jnp.arange(i0, i0 + n, dtype=jnp.int32),
+                                   unroll=Lyr.scan_unroll())
+        loads.append(load)
+    x, heap, aux = carry
+    new = caches
+    if serving:
+        new = new._replace(kv=kv._replace(layers=heap))
+    if caches.expert_tokens is not None:
+        new = new._replace(expert_tokens=caches.expert_tokens
+                           + jnp.concatenate(loads, 0))
+    return x, aux, new
+
+
 # ---------------------------------------------------------------------------
 # top level
 # ---------------------------------------------------------------------------
 
 def embed(cfg, params, tokens, extra_embeds=None, dtype=jnp.bfloat16):
     x = params["embed"].astype(dtype)[tokens]
+    if cfg.embedding_multiplier != 1:
+        x = x * cfg.embedding_multiplier
     if extra_embeds is not None:
         x = x + extra_embeds.astype(dtype)
     return constrain(x, "batch", "seq", "act_embed")
@@ -297,6 +422,8 @@ def unembed(cfg, params, x):
          else params["lm_head"]).astype(h.dtype)
     logits = constrain((h @ w).astype(jnp.float32),
                        "batch", "seq", "vocab")
+    if cfg.logits_scaling != 1:
+        logits = logits / cfg.logits_scaling
     if cfg.logit_softcap:
         logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
     return logits
@@ -322,7 +449,8 @@ def forward(cfg: ModelConfig, params, tokens, positions=None,
         # so microbatch splitting is uniform; rope wants (3, B, S).
         positions = positions.transpose(1, 0, 2)
     x = embed(cfg, params, tokens, extra_embeds, dtype)
-    stack = hybrid_stack if cfg.family == "hybrid" else uniform_stack
+    stack = (pattern_stack if cfg.layer_types is not None
+             else hybrid_stack if cfg.family == "hybrid" else uniform_stack)
     x, aux, new_caches = stack(cfg, params, x, positions, mode, caches,
                                remat_policy)
     if mode == "prefill":

@@ -26,6 +26,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import HeapConfig, Ouroboros
 
@@ -166,53 +167,170 @@ def make_kv_allocator(num_pages: int, backend: str = "jnp",
                       num_shards=num_shards), 64, physical_pages)
 
 
-def modality_page_quota(cfg, page_bytes: int = 256) -> int:
-    """Arena pages of per-sequence state residency BEYOND the KV pages
-    — the per-modality allocation policy (DESIGN.md §13).
+def state_geometry(cfg):
+    """How a layer-list model's Mamba layer keeps its recurrent state in
+    page rows: ``(heads_per_page, head_pages, conv_pages)``.
 
-    The paper's claim is ONE dynamic allocator for heterogeneous
-    workloads, so every model family's per-sequence state rides the
-    same Ouroboros arena the KV pages come from.  Attention KV grows
-    page-by-page with the sequence (``make_kv_allocator``); what this
-    helper sizes is the O(1)-per-sequence state the other families
-    carry instead of (or on top of) KV:
+    A page id's row in the page heap is its K and V slots across the
+    attention layers: ``2 · attention layers · PAGE_SIZE · kv_heads ·
+    head_dim`` float32 words (128 KiB for granite-4.0-h's one attention
+    layer per stage).  A state page holds ``heads_per_page`` SSD heads
+    of ``(headdim, state)`` float32; a layer's conv tail, ``(conv - 1,
+    conv_dim)``, takes ``conv_pages`` rows (DESIGN.md §13)."""
+    from repro.models.ssm import conv_dim
+    n_attn = cfg.layer_types.count("attention")
+    row = 2 * n_attn * PAGE_SIZE * cfg.num_kv_heads * cfg.head_dim_
+    head = cfg.ssm_headdim * cfg.ssm_state
+    hpp = row // head if head else 0
+    if not n_attn or row % head or cfg.ssm_nheads % hpp:
+        raise ValueError(
+            f"{cfg.name}: a page row of {row} words does not hold whole "
+            f"SSD heads of {head} words for {cfg.ssm_nheads} heads")
+    tail = (cfg.ssm_conv - 1) * conv_dim(cfg)
+    return hpp, cfg.ssm_nheads // hpp, -(-tail // row)
 
-    - ``ssm`` (mamba2): the SSD recurrent state — ``(nheads, headdim,
-      state)`` f32 plus the ``(conv-1, conv_dim)`` bf16 convolution
-      tail, per layer;
-    - ``hybrid`` (recurrentgemma): the RG-LRU recurrence — ``(lru_width,)``
-      f32 hidden plus the ``(3, lru_width)`` bf16 conv tail, per
-      recurrent (non-attention) layer;
-    - ``moe`` (mixtral, phi3.5): the routed expert activation buffers —
-      ``top_k × d_ff`` bf16 per MoE layer;
-    - dense / enc-dec / vlm: 0 (their per-sequence state is entirely
-      KV pages).
 
-    The serving engine grants this many pages per slot at admission
-    (``slot_aux``) and frees them at retirement/eviction/cancel, so
-    SSM and MoE traffic exercises the allocator even though their
-    state tensors live in dense device arrays.
+def state_pages_per_layer(cfg) -> int:
+    _, head_pages, conv_pages = state_geometry(cfg)
+    return head_pages + conv_pages
+
+
+def modality_page_quota(cfg) -> int:
+    """Arena pages a slot holds beyond its KV pages: the pages that hold
+    its recurrent state (DESIGN.md §13).
+
+    The paper's claim is ONE dynamic allocator for heterogeneous state,
+    so a model served from its layer list (granite-4.0-h) keeps each
+    Mamba layer's SSD state and conv tail in pages of the same arena
+    as its KV pages, in the same page heap: ``state_pages_per_layer``
+    pages for each Mamba layer, granted at admission in the same
+    transaction as the prompt's KV pages, read and written by every
+    tick through the slot's state-page table, and freed with the KV
+    pages at retirement, eviction or cancel.  Every other family holds
+    no state pages: an MoE layer keeps no per-sequence state, and the
+    ssm / hybrid families keep theirs in dense per-slot arrays.
 
     >>> from repro.configs import get_arch
     >>> from repro.paged.kv_cache import modality_page_quota
     >>> modality_page_quota(get_arch("qwen2-0.5b").smoke())
     0
-    >>> modality_page_quota(get_arch("mamba2-780m").smoke()) > 0
-    True
+    >>> modality_page_quota(get_arch("granite-4.0-h-small.stage0"))
+    297
     """
-    if cfg.family == "ssm":
-        conv_dim = cfg.d_inner + 2 * cfg.ssm_ngroups * cfg.ssm_state
-        per_layer = (cfg.ssm_nheads * cfg.ssm_headdim * cfg.ssm_state * 4
-                     + (cfg.ssm_conv - 1) * conv_dim * 2)
-        return -(-cfg.num_layers * per_layer // page_bytes)
-    if cfg.family == "hybrid":
-        r = cfg.lru_width or cfg.d_model
-        n_rec = cfg.num_layers - cfg.num_layers // cfg.attn_period
-        return -(-n_rec * (r * 4 + 3 * r * 2) // page_bytes)
-    if cfg.num_experts:
-        buf = cfg.num_layers * cfg.num_experts_per_tok * cfg.d_ff * 2
-        return -(-buf // page_bytes)
-    return 0
+    if cfg.layer_types is None:
+        return 0
+    return cfg.layer_types.count("mamba") * state_pages_per_layer(cfg)
+
+
+def _state_slices(layers: KVLayer):
+    """The heap arrays a state page spans, in head order: attention
+    layer by layer, its K slot then its V slot — ``(field, layer)``."""
+    return [(f, l) for l in range(layers.k.shape[0]) for f in ("k", "v")]
+
+
+def read_state(cfg, layers: KVLayer, ids):
+    """One Mamba layer's state for every row from its state pages
+    ``ids`` (B, head_pages + conv_pages): ``(h, conv)``.  ``h`` is a
+    tuple of head slices, one per heap array a page spans (each
+    (B, H / slices, P, N) float32, in head order), gathered straight
+    from the heap rows and never interleaved into one array; ``conv``
+    is (B, conv - 1, conv_dim) float32.  A hole (-1) reads page 0."""
+    from repro.models.ssm import conv_dim
+    _, hp, _ = state_geometry(cfg)
+    safe = jnp.maximum(ids, 0)
+    B = ids.shape[0]
+    h, tails = [], []
+    for f, l in _state_slices(layers):
+        arr = getattr(layers, f)
+        h.append(arr[l, safe[:, :hp]].reshape(
+            B, -1, cfg.ssm_headdim, cfg.ssm_state))
+        tails.append(arr[l, safe[:, hp:]].reshape(B, -1))
+    tail = (cfg.ssm_conv - 1) * conv_dim(cfg)
+    conv = jnp.concatenate(tails, 1)[:, :tail].reshape(
+        B, cfg.ssm_conv - 1, conv_dim(cfg))
+    return tuple(h), conv
+
+
+def write_state(cfg, layers: KVLayer, ids, h, conv,
+                holes: bool = True) -> KVLayer:
+    """Write one Mamba layer's state into its state pages ``ids``: ``h``
+    as :func:`read_state` gives it, or whole (B, H, P, N); ``conv``
+    (B, conv - 1, conv_dim).  Holes (-1) are dropped; ``holes=False``
+    promises there are none (an admission's row), which lets a one-row
+    write update the heap in place."""
+    _, hp, cp = state_geometry(cfg)
+    slices = _state_slices(layers)
+    B, np_ = ids.shape[0], layers.k.shape[1]
+    if not isinstance(h, tuple):
+        h = tuple(jnp.split(h, len(slices), axis=1))
+    row = layers.k.shape[2:]
+    tail = conv.reshape(B, -1).astype(jnp.float32)
+    n = len(slices) * cp * int(np.prod(row))
+    tails = jnp.split(jnp.pad(tail, ((0, 0), (0, n - tail.shape[1]))),
+                      len(slices), axis=1)
+    dst = jnp.where(ids >= 0, ids, np_) if holes else ids
+    mode = "drop" if holes else "promise_in_bounds"
+    new = {}
+    for (f, l), hs, ts in zip(slices, h, tails):
+        a = new.get(f, getattr(layers, f))
+        hs = hs.reshape((B, hp) + row).astype(a.dtype)
+        ts = ts.reshape((B, cp) + row).astype(a.dtype)
+        if B * cp == 1:
+            # a one-row scatter becomes an update that rereads, so
+            # copies, the heap: write the conv row with the heads
+            a = a.at[l, dst].set(jnp.concatenate([hs, ts], 1), mode=mode)
+        else:
+            a = a.at[l, dst[:, :hp]].set(hs, mode=mode)
+            a = a.at[l, dst[:, hp:]].set(ts, mode=mode)
+        new[f] = a
+    return layers._replace(**new)
+
+
+class _LayerOf:
+    """Attention layer ``a`` of a stacked heap array as
+    :func:`paged_attend1` reads it: indexing gathers from the stacked
+    array itself, so no layer slice is copied."""
+
+    def __init__(self, heap, a):
+        self.heap, self.a = heap, a
+        self.shape, self.dtype = heap.shape[1:], heap.dtype
+
+    def __getitem__(self, idx):
+        return self.heap[self.a, idx]
+
+
+def layer_of(layers: KVLayer, a) -> KVLayer:
+    """Attention layer ``a`` (may be traced) of a stacked float32 heap,
+    for :func:`paged_attend1`."""
+    return KVLayer(_LayerOf(layers.k, a), _LayerOf(layers.v, a), None,
+                   None)
+
+
+def append1_at(layers: KVLayer, a, page_table, seq_lens, k_t,
+               v_t) -> KVLayer:
+    """:func:`append1` into attention layer ``a`` of the stacked heap,
+    written in place in the stacked arrays."""
+    page, np_ = layers.k.shape[2], layers.k.shape[1]
+    pidx, slot = seq_lens // page, seq_lens % page
+    ids = jnp.take_along_axis(page_table, pidx[:, None], axis=1)[:, 0]
+    idx = (a, jnp.where(ids >= 0, ids, np_), slot)
+    return _store(layers, idx, k_t[:, 0], v_t[:, 0])
+
+
+def prefill_write_at(layers: KVLayer, a, page_table, k, v) -> KVLayer:
+    """:func:`prefill_write1` of a prompt's K/V (B, S, Hkv, hd) into
+    attention layer ``a`` of the stacked heap, in place.  Every
+    position's page must be mapped (an admission maps the prompt's)."""
+    B, S = k.shape[:2]
+    page = layers.k.shape[2]
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    ids = jnp.take_along_axis(page_table, pos // page, axis=1)
+    idx = (a, ids, pos % page)
+    return layers._replace(
+        k=layers.k.at[idx].set(k.astype(layers.k.dtype),
+                               mode="promise_in_bounds"),
+        v=layers.v.at[idx].set(v.astype(layers.v.dtype),
+                               mode="promise_in_bounds"))
 
 
 def scatter_grant_words(page_table, page_counts, lane_slot, lane_rank,
@@ -385,10 +503,14 @@ def prefill_write1(layer: KVLayer, page_table, k, v, pos0=0,
 
 def paged_attend1(layer: KVLayer, page_table, kv_len, q, *,
                   window: Optional[int] = None, page_block: int = 16,
-                  ring: bool = False):
+                  ring: bool = False, scale: Optional[float] = None,
+                  precision=None):
     """Decode attention for one layer over the paged heap.
 
-    q: (B, 1, Hq, hd); kv_len: (B,) valid tokens (incl. current).
+    q: (B, 1, Hq, hd); kv_len: (B,) valid tokens (incl. current);
+    ``scale``: score scale (None = hd ** -0.5); ``precision``: of both
+    products (a float32 heap read at ``HIGHEST``: at the default, XLA
+    on a TPU lowers the heap to bfloat16 whole, ahead of the gathers).
     Blockwise online softmax over page-table gathers — O(page_block)
     live memory, GSPMD-shardable (heads on 'model', batch on 'data')."""
     B, _, Hq, D = q.shape
@@ -406,7 +528,8 @@ def paged_attend1(layer: KVLayer, page_table, kv_len, q, *,
     # f32 accumulation via preferred_element_type below.
     stage_dt = (jnp.float32 if layer.k.dtype == jnp.float32
                 else jnp.bfloat16)
-    qg = (q[:, 0].reshape(B, Hkv, G, D) * (D ** -0.5)).astype(stage_dt)
+    qg = (q[:, 0].reshape(B, Hkv, G, D)
+          * (D ** -0.5 if scale is None else scale)).astype(stage_dt)
 
     def body(carry, inp):
         m, l, acc = carry
@@ -434,7 +557,7 @@ def paged_attend1(layer: KVLayer, page_table, kv_len, q, *,
                 & jnp.repeat(ids >= 0, page, axis=1)
         if window is not None:
             valid &= tok > (kv_len[:, None] - 1 - window)
-        s = jnp.einsum("bhgd,bthd->bhgt", qg, k,
+        s = jnp.einsum("bhgd,bthd->bhgt", qg, k, precision=precision,
                        preferred_element_type=jnp.float32)
         s = jnp.where(valid[:, None, None, :], s, _NEG)
         m_new = jnp.maximum(m, s.max(-1))
@@ -443,7 +566,7 @@ def paged_attend1(layer: KVLayer, page_table, kv_len, q, *,
         alpha = jnp.exp(m - m_new)
         l_new = alpha * l + p.sum(-1)
         acc_new = alpha[..., None] * acc + jnp.einsum(
-            "bhgt,bthd->bhgd", p.astype(stage_dt), v,
+            "bhgt,bthd->bhgd", p.astype(stage_dt), v, precision=precision,
             preferred_element_type=jnp.float32)
         return (m_new, l_new, acc_new), None
 

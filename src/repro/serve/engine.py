@@ -37,6 +37,7 @@ multi-pod path); everything device-side is jitted.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 from typing import Dict, List, NamedTuple, Optional
@@ -139,10 +140,14 @@ def merge_rows(cfg, new_caches, old_caches, row_mask):
             enc_valid=(axis0(new_caches.enc_valid, old.enc_valid)
                        if new_caches.enc_valid is not None
                        else old.enc_valid))
+    # a layer-list model's state pages are in the heap (rows that did
+    # not advance were given no state pages, so wrote none); its slot
+    # tables merge on axis 0 and its expert counts are taken wholesale
     return new_caches._replace(
         kv=merge_kv(new_caches.kv, old.kv),
         ssm_h=axis1(new_caches.ssm_h, old.ssm_h),
-        ssm_conv=axis1(new_caches.ssm_conv, old.ssm_conv))
+        ssm_conv=axis1(new_caches.ssm_conv, old.ssm_conv),
+        state_table=axis0(new_caches.state_table, old.state_table))
 
 
 def put_row(cfg, row_caches, caches, slot):
@@ -178,7 +183,8 @@ def put_row(cfg, row_caches, caches, slot):
     return caches._replace(
         kv=put_kv(row_caches.kv, caches.kv),
         ssm_h=put(row_caches.ssm_h, caches.ssm_h, 1),
-        ssm_conv=put(row_caches.ssm_conv, caches.ssm_conv, 1))
+        ssm_conv=put(row_caches.ssm_conv, caches.ssm_conv, 1),
+        expert_tokens=row_caches.expert_tokens)
 
 
 class ServingEngine:
@@ -266,12 +272,12 @@ class ServingEngine:
         self.num_shards = num_shards
         self.rebalance_threshold = rebalance_threshold
         self.page_bytes = 256  # logical bytes per page in the heap
-        # per-modality page policy (DESIGN.md §13): SSM/recurrent state
-        # and MoE expert buffers ride the SAME arena as KV pages —
-        # aux_pages per slot are granted at admission and freed at
-        # retirement/eviction/cancel.  0 for dense/enc-dec/vlm, so
-        # those engines are sized and behave exactly as before.
-        self.aux_pages = KV.modality_page_quota(cfg, self.page_bytes)
+        # per-modality page policy (DESIGN.md §13): a layer-list
+        # model's recurrent state rides the SAME arena and page heap as
+        # its KV pages — aux_pages per slot, granted at admission with
+        # the prompt's KV pages and freed with them.  0 for every other
+        # family, so those engines are sized as before.
+        self.aux_pages = KV.modality_page_quota(cfg)
         self.ouro, self.wpp, physical_pages = KV.make_kv_allocator(
             self.num_pages + max_batch * self.aux_pages,
             backend=alloc_backend,
@@ -298,10 +304,14 @@ class ServingEngine:
                 enc_valid=jnp.zeros(max_batch, jnp.int32))
         self.slot_req: List[Optional[Request]] = [None] * max_batch
         self.slot_pages: List[List[int]] = [[] for _ in range(max_batch)]
-        # per-modality aux pages (SSM state / MoE expert buffers) held
-        # by each admitted slot — host-side in BOTH decode modes (the
-        # quota is static per arch, so nothing device-resident needed)
+        # the state pages each admitted slot holds — host-side in BOTH
+        # decode modes; the device's state-page table is their mirror
         self.slot_aux: List[List[int]] = [[] for _ in range(max_batch)]
+        # a slot's state and KV pages are granted and freed in one
+        # transaction of a fixed lane count, so every admission and
+        # every retirement runs one program
+        self._slot_lanes = (self.aux_pages + self.pps if self.aux_pages
+                            else None)
         self.slot_len = np.zeros(max_batch, np.int64)  # host truth
         self.waiting: List[Request] = []
         self._uid = 0
@@ -326,6 +336,10 @@ class ServingEngine:
                     seq_lens=jnp.zeros(1, jnp.int32))
                 view = (c._replace(self_kv=kv) if cfg.is_encdec
                         else c._replace(kv=kv))
+            if getattr(c, "state_table", None) is not None:
+                view = view._replace(
+                    state_table=jax.lax.dynamic_slice_in_dim(
+                        c.state_table, slot, 1))
             tok, row = _tokens_of(model.prefill(
                 p, b, view, remat_policy="none", dtype=compute_dtype))
             return tok, put_row(cfg, row, c, slot)
@@ -387,8 +401,11 @@ class ServingEngine:
                       # cancelled mid-stream or in the waiting queue
                       "cancels": 0,
                       # per-modality page policy: arena pages each
-                      # admitted slot holds beyond KV (0 = dense)
+                      # admitted slot holds beyond KV (0 = dense), and
+                      # the state pages granted and freed so far
                       "aux_pages_per_slot": self.aux_pages,
+                      "state_pages_granted": 0,
+                      "state_pages_freed": 0,
                       "defrag_waves": 0,
                       "rebalance_waves": 0,
                       "auto_defrag_waves": 0,
@@ -445,15 +462,17 @@ class ServingEngine:
         else:
             self.caches = self.caches._replace(kv=kv)
 
-    def _bulk_alloc(self, homes: List[int]) -> List[int]:
+    def _bulk_alloc(self, homes: List[int],
+                    lanes: Optional[int] = None) -> List[int]:
         """ONE allocator transaction granting one page per entry of
         ``homes`` (the requesting slot's home shard — grants overflow
         to neighbor shards when that shard is full).  Lanes from
         different slots coalesce into this single kernel launch: a
         decode step issues at most one transaction for the whole
-        batch."""
+        batch.  ``lanes`` fixes the transaction's width (default
+        ``max(2 · max_batch, pages)``)."""
         n_pages = len(homes)
-        lanes = max(self.max_batch * 2, n_pages)
+        lanes = max(self.max_batch * 2, n_pages, lanes or 0)
         sizes = jnp.full(lanes, self.page_bytes, jnp.int32)
         mask = jnp.arange(lanes) < n_pages
         home = np.zeros(lanes, np.int32)
@@ -476,18 +495,19 @@ class ServingEngine:
                                              .sum())
         return [int(o) // self.wpp if o >= 0 else -1 for o in offs]
 
-    def _alloc_pages(self, homes: List[int]) -> List[int]:
+    def _alloc_pages(self, homes: List[int],
+                     lanes: Optional[int] = None) -> List[int]:
         """Bulk page grant with defragmentation recovery: if any lane
         fails, return this transaction's partial grants, run ONE
         defrag wave (migrating stragglers together and retiring the
         emptied chunks to the pool), and retry once — the paper-regime
         alternative to dying on a fragmented heap."""
-        got = self._bulk_alloc(homes)
+        got = self._bulk_alloc(homes, lanes)
         if all(g >= 0 for g in got):
             return got
-        self._bulk_free([g for g in got if g >= 0])
+        self._bulk_free([g for g in got if g >= 0], lanes=lanes)
         self.defrag()
-        return self._bulk_alloc(homes)
+        return self._bulk_alloc(homes, lanes)
 
     def _note_shard_pages(self, offs, delta: int):
         """Update per-shard live-page occupancy for granted/freed word
@@ -520,10 +540,11 @@ class ServingEngine:
         self.stats["shard_pages_live"] = [int(x) for x in
                                           self._shard_pages]
 
-    def _bulk_free(self, pages: List[int], count_stats: bool = True):
+    def _bulk_free(self, pages: List[int], count_stats: bool = True,
+                   lanes: Optional[int] = None):
         if not pages:
             return
-        lanes = max(self.max_batch * 2, len(pages))
+        lanes = max(self.max_batch * 2, len(pages), lanes or 0)
         offs = np.full(lanes, -1, np.int32)
         offs[:len(pages)] = np.asarray(pages, np.int32) * self.wpp
         with self.tracer.span("bulk_free", pages=len(pages)):
@@ -535,41 +556,57 @@ class ServingEngine:
             self.stats["frees"] += len(pages)
         self._note_shard_pages(offs[offs >= 0], -1)
 
-    def _map_pages(self, slot: int, upto_tokens: int):
-        """Grow slot's page table to cover ``upto_tokens`` positions
-        (admission path; decode growth coalesces in ``step``)."""
-        if self._kv() is None:  # attention-free family: O(1) state
+    def _grant_slot(self, slot: int, upto_tokens: int) -> bool:
+        """Admission's ONE allocator transaction: the slot's state
+        pages (``aux_pages``: a layer-list model's recurrent state,
+        DESIGN.md §13) and the page-table pages covering
+        ``upto_tokens`` positions.  On failure the partial grants go
+        back, so allocs and frees stay balanced."""
+        need = 0
+        if self._kv() is not None:  # attention-free: O(1) state only
+            need = max(-(-upto_tokens // self.page)
+                       - len(self.slot_pages[slot]), 0)
+        n = self.aux_pages + need
+        if n == 0:
             return True
-        need = -(-upto_tokens // self.page)
-        missing = need - len(self.slot_pages[slot])
-        if missing <= 0:
-            return True
-        got = self._alloc_pages([slot % self.num_shards] * missing)
+        span = (self.tracer.span("state_grant", slot=slot,
+                                 pages=self.aux_pages)
+                if self.aux_pages else contextlib.nullcontext())
+        with span:
+            got = self._alloc_pages([slot % self.num_shards] * n,
+                                    self._slot_lanes)
         if any(g < 0 for g in got):
-            self._bulk_free([g for g in got if g >= 0])
+            self._bulk_free([g for g in got if g >= 0],
+                            lanes=self._slot_lanes)
             return False
-        self._map_granted([slot] * missing, got)
+        if self.aux_pages:
+            self._set_slot_aux(slot, got[:self.aux_pages])
+            self.stats["state_pages_granted"] += self.aux_pages
+        if need:
+            self._map_granted([slot] * need, got[self.aux_pages:])
         return True
 
-    def _alloc_aux(self, slot: int) -> bool:
-        """Grant the slot its per-modality aux pages (SSM state / MoE
-        expert buffers — DESIGN.md §13) out of the SAME arena the KV
-        pages come from: ONE bulk transaction for the whole quota.
-        Partial grants are returned on failure so allocs/frees stay
-        balanced."""
-        if self.aux_pages == 0:
-            return True
-        got = self._alloc_pages([slot % self.num_shards]
-                                * self.aux_pages)
-        if any(g < 0 for g in got):
-            self._bulk_free([g for g in got if g >= 0])
-            return False
-        self.slot_aux[slot] = got
-        return True
+    def _set_slot_aux(self, slot: int, pages: List[int]):
+        """The slot's state pages, host list and device table row."""
+        self.slot_aux[slot] = list(pages)
+        tab = self.caches.state_table
+        row = np.full(tab.shape[1:], -1, np.int32)
+        if pages:
+            row[:] = np.asarray(pages, np.int32).reshape(row.shape)
+        self.caches = self.caches._replace(
+            state_table=tab.at[slot].set(jnp.asarray(row)))
 
-    def _free_aux(self, slot: int):
-        self._bulk_free(self.slot_aux[slot])
-        self.slot_aux[slot] = []
+    def _free_slot_pages(self, slot: int, kv_pages: List[int]):
+        """Return a slot's KV pages and state pages to the arena in ONE
+        transaction (retirement, eviction, cancel)."""
+        aux = self.slot_aux[slot]
+        if not aux:
+            self._bulk_free(kv_pages)
+            return
+        with self.tracer.span("state_free", slot=slot, pages=len(aux)):
+            self._bulk_free(list(kv_pages) + aux, lanes=self._slot_lanes)
+        self.stats["state_pages_freed"] += len(aux)
+        self._set_slot_aux(slot, [])
 
     def _map_granted(self, slots: List[int], pages: List[int]):
         """Extend the slots' page tables with freshly granted page ids
@@ -671,6 +708,11 @@ class ServingEngine:
         if not self.mega_step:
             self.stats["shard_pages_live"] = [int(x) for x in
                                               self._shard_pages]
+        if self.aux_pages:
+            # the state pages moved with their heap rows above; their
+            # table follows the host lists
+            for slot, pages in enumerate(self.slot_aux):
+                self._set_slot_aux(slot, pages)
         return total
 
     def refresh_frag_stats(self):
@@ -720,10 +762,20 @@ class ServingEngine:
                     "alloc_txns", "alloc_overflows", "evictions",
                     "cancels", "defrag_waves", "rebalance_waves",
                     "auto_defrag_waves", "pages_migrated",
-                    "jit_first_calls", "prefill_rows", "prefill_tokens")
+                    "jit_first_calls", "prefill_rows", "prefill_tokens",
+                    "state_pages_granted", "state_pages_freed")
         for k in counters:
             reg.counter(f"repro_engine_{k}_total",
                         f"engine stats[{k!r}]").set(float(self.stats[k]))
+        load = self.expert_load()
+        if load is not None:
+            m = reg.counter("repro_engine_expert_tokens_total",
+                            "tokens routed to each held expert",
+                            labelnames=("layer", "expert"))
+            for layer, row in enumerate(load):
+                for e, n in enumerate(row):
+                    m.labels(layer=layer, expert=self.cfg.expert_offset
+                             + e).set(float(n))
         reg.gauge("repro_engine_waiting",
                   "requests queued for admission").set(
                       float(len(self.waiting)))
@@ -773,17 +825,24 @@ class ServingEngine:
                             float(arr[s, a]))
         return reg
 
+    def expert_load(self) -> Optional[np.ndarray]:
+        """Tokens routed to each held expert, (layers, held) — summed on
+        the device by every prefill and tick and read only here (None
+        for a model that counts none)."""
+        c = self.caches
+        if getattr(c, "expert_tokens", None) is None:
+            return None
+        load = np.asarray(c.expert_tokens)
+        self.stats["expert_tokens"] = load.tolist()
+        return load
+
     def _admit(self):
         for slot in range(self.max_batch):
             if self.slot_req[slot] is not None or not self.waiting:
                 continue
             req = self.waiting.pop(0)
             lp = len(req.prompt)
-            if not self._alloc_aux(slot):
-                self.waiting.insert(0, req)  # heap full; retry later
-                break
-            if not self._map_pages(slot, lp + 1):
-                self._free_aux(slot)
+            if not self._grant_slot(slot, lp + 1):
                 self.waiting.insert(0, req)  # heap full; retry later
                 break
             batch = {"tokens": jnp.asarray(req.prompt[None])}
@@ -893,8 +952,13 @@ class ServingEngine:
             # (d) model forward with paged attention; failed/inactive
             # rows write to table holes (dropped) and their cache
             # advance is masked back out below
+            view = caches
+            if getattr(caches, "state_table", None) is not None:
+                # a row that does not advance reads and writes no state
+                view = caches._replace(state_table=jnp.where(
+                    advance[:, None, None], caches.state_table, -1))
             logits, new_caches = model.decode_step(
-                params, ms.last_tok[:, None], caches, dtype=dtype)
+                params, ms.last_tok[:, None], view, dtype=dtype)
             caches = merge_rows(cfg, new_caches, caches, advance)
             # (e) greedy sampling + seq/token advance, all on device
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
@@ -1068,11 +1132,12 @@ class ServingEngine:
         kv = self._kv()
         if kv is not None:
             row = np.asarray(kv.page_table[slot])
-            self._bulk_free([int(p) for p in row[row >= 0]])
+            self._free_slot_pages(slot, [int(p) for p in row[row >= 0]])
             pt = kv.page_table.at[slot].set(-1)
             sl = kv.seq_lens.at[slot].set(0)
             self._set_kv(kv._replace(page_table=pt, seq_lens=sl))
-        self._free_aux(slot)
+        else:
+            self._free_slot_pages(slot, [])
         ms = self.mega_state
         self.mega_state = MegaState(
             last_tok=ms.last_tok.at[slot].set(0),
@@ -1115,9 +1180,9 @@ class ServingEngine:
         if self.mega_step:
             # mid-flight the device page-table row is the only page-id
             # holder (slot_pages was cleared at _mega_admit)
-            if kv is not None:
-                row = np.asarray(kv.page_table[slot])
-                self._bulk_free([int(p) for p in row[row >= 0]])
+            row = (np.asarray(kv.page_table[slot]) if kv is not None
+                   else np.zeros(0, np.int32))
+            self._free_slot_pages(slot, [int(p) for p in row[row >= 0]])
             ms = self.mega_state
             self.mega_state = MegaState(
                 last_tok=ms.last_tok.at[slot].set(0),
@@ -1132,9 +1197,8 @@ class ServingEngine:
             self._nout_host[slot] = 0
             self._fail_streak[slot] = 0
         else:
-            self._bulk_free(self.slot_pages[slot])
+            self._free_slot_pages(slot, self.slot_pages[slot])
             self.slot_pages[slot] = []
-        self._free_aux(slot)
         kv = self._kv()
         if kv is not None:
             self._set_kv(kv._replace(
@@ -1284,9 +1348,8 @@ class ServingEngine:
         return finished
 
     def _release(self, slot: int):
-        self._bulk_free(self.slot_pages[slot])
+        self._free_slot_pages(slot, self.slot_pages[slot])
         self.slot_pages[slot] = []
-        self._free_aux(slot)
         kv = self._kv()
         if kv is not None:
             pt = kv.page_table.at[slot].set(-1)

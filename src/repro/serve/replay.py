@@ -22,11 +22,11 @@ engine-step time (cancel at step ``t``), which both decode modes reach
 through the identical host-side admission machinery, keeping cancels
 parity-safe.
 
-Per-modality page policy rides underneath (DESIGN.md §13): SSM state
-pages (mamba2 / recurrentgemma) and MoE expert buffers (mixtral /
-phi3.5) are granted out of the SAME Ouroboros arena as KV pages
-(``kv_cache.modality_page_quota``), so every family's traffic churns
-the allocator — not just the attention archs.
+Per-modality page policy rides underneath (DESIGN.md §13): a
+layer-list model's recurrent-state pages (granite-4.0-h) are granted
+out of the SAME Ouroboros arena and page heap as its KV pages
+(``kv_cache.modality_page_quota``), so its state churns the allocator
+beside its KV.
 
     from repro.serve.replay import SCENARIOS, engine_factory, \
         generate_trace, replay, replay_pair
@@ -294,7 +294,7 @@ def replay(engine, trace: List[TraceItem], *, scenario: str = "",
 
 def assert_conserved(engine):
     """End-state allocator conservation after a drained replay: every
-    page ever granted — KV, SSM-state, MoE-buffer alike — went back
+    page ever granted — KV and state pages alike — went back
     through the allocator (``allocs == frees``), no slot holds page
     ids, and the device page table is all holes.  Abandonment and
     eviction paths free through the same counters, so a leak anywhere
@@ -309,6 +309,9 @@ def assert_conserved(engine):
     if kv is not None:
         pt = np.asarray(kv.page_table)
         assert (pt < 0).all(), f"page table still maps {int((pt >= 0).sum())} pages"
+    table = getattr(engine.caches, "state_table", None)
+    if table is not None:
+        assert (np.asarray(table) < 0).all(), "state table still maps pages"
     if engine.mega_step:
         engine._sync_shard_pages_from_table()
     assert sum(engine.stats["shard_pages_live"]) == 0, (

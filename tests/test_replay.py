@@ -13,7 +13,7 @@ correctness net, so this file is where the zoo-wide guarantees live:
   load and mid-stream abandonment, with end-state conservation;
 - **no family untested**: a replay smoke runs over every arch in the
   zoo (tiny geometries), exercising the per-modality page policy —
-  SSM state and MoE expert-buffer pages through the same arena as KV.
+  recurrent-state pages through the same arena as KV.
 
 Marker ``replay`` (conftest.py): the forced-blocked CI job runs
 ``pytest -m replay``; the nightly job adds the two-scenario benchmark
@@ -121,9 +121,9 @@ def test_abandonment_frees_all_pages(mega):
     replay drains, every page ever granted — KV and modality aux alike
     — went back through the allocator (allocs == frees), no slot holds
     pages, and the device page table is all holes."""
-    cfg, make = engine_factory("mamba2-780m")   # aux pages > 0
+    cfg, make = engine_factory("granite-4.0-h-small")   # aux pages > 0
     eng = make(mega=mega)
-    assert eng.aux_pages > 0, "SSM config should carry state pages"
+    assert eng.aux_pages > 0, "a paged-state config carries state pages"
     trace = generate_trace(SCENARIOS["abandon"], seed=5,
                            vocab_size=cfg.vocab_size)
     r = replay(eng, trace, scenario="abandon")
@@ -148,8 +148,8 @@ def test_bursty_parity_mega_vs_host():
 
 def test_abandon_parity_with_aux_pages():
     """Parity holds through mid-stream cancels on a config whose slots
-    hold modality aux pages (hybrid RG-LRU state)."""
-    cfg, make = engine_factory("recurrentgemma-9b")
+    hold state pages (the Mamba-2 state of a layer-list model)."""
+    cfg, make = engine_factory("granite-4.0-h-small")
     eng_h, eng_m = make(mega=False), make(mega=True)
     assert eng_h.aux_pages > 0
     trace = generate_trace(SCENARIOS["abandon"], seed=7,
@@ -180,9 +180,9 @@ _SMOKE = dataclasses.replace(SCENARIOS["abandon"], n_requests=4,
 @pytest.mark.parametrize("arch", ALL_ARCHS)
 def test_replay_smoke_every_config(arch):
     """Every arch in the zoo replays a short abandonment trace on the
-    host loop with conservation asserted — the per-modality page
-    policy (SSM state, MoE expert buffers, plain KV) all route through
-    the same Ouroboros arena, and no family is ever untested again."""
+    host loop with conservation asserted — state pages and KV pages
+    all route through the same Ouroboros arena, and no family is ever
+    untested again."""
     cfg, make = engine_factory(arch, max_batch=2)
     eng = make(mega=False)
     trace = generate_trace(_SMOKE, seed=3, vocab_size=cfg.vocab_size)
@@ -193,9 +193,11 @@ def test_replay_smoke_every_config(arch):
 
 
 def test_modality_page_quota_families():
-    """The quota helper behind the aux policy: zero for pure-attention
-    families, positive for state-holding ones, and exact page-count
-    arithmetic (ceil of state bytes over the page size)."""
+    """The quota helper behind the aux policy counts the pages that hold
+    state: a layer-list model's Mamba state pages, exactly; zero for
+    every family whose per-sequence state is KV pages alone, dense
+    per-slot arrays (mamba2, recurrentgemma), or none (an MoE layer
+    keeps no per-sequence state)."""
     from repro.configs import get_arch
     from repro.paged.kv_cache import modality_page_quota
 
@@ -203,13 +205,21 @@ def test_modality_page_quota_families():
              for a in ALL_ARCHS}
     assert quota["qwen2-0.5b"] == 0 and quota["qwen2-vl-2b"] == 0
     assert quota["seamless-m4t-large-v2"] == 0
-    assert quota["mamba2-780m"] > 0 and quota["recurrentgemma-9b"] > 0
-    assert quota["mixtral-8x7b"] > 0 and quota["phi3.5-moe-42b-a6.6b"] > 0
-    # exactness on one family: mixtral's expert buffer is
-    # layers · top_k · d_ff bf16 elements
-    cfg = get_arch("mixtral-8x7b").smoke()
-    bytes_ = cfg.num_layers * cfg.num_experts_per_tok * cfg.d_ff * 2
-    assert quota["mixtral-8x7b"] == -(-bytes_ // 256)
+    assert quota["mamba2-780m"] == 0 and quota["recurrentgemma-9b"] == 0
+    assert quota["mixtral-8x7b"] == 0 and quota["phi3.5-moe-42b-a6.6b"] == 0
+    assert quota["granite-4.0-h-small"] > 0
+    # exactness: a page row is a page id's K and V slots over the
+    # attention layers (float32); each Mamba layer takes its SSD heads
+    # whole rows at a time, plus its conv tail
+    cfg = get_arch("granite-4.0-h-small").smoke()
+    row = 2 * 1 * 16 * cfg.num_kv_heads * cfg.head_dim_
+    heads = cfg.ssm_nheads * cfg.ssm_headdim * cfg.ssm_state
+    tail = (cfg.ssm_conv - 1) * (cfg.d_inner + 2 * cfg.ssm_state)
+    assert quota["granite-4.0-h-small"] == 2 * (heads // row
+                                                + -(-tail // row))
+    # at published widths: 9 Mamba layers × (32 pages of 4 heads + 1)
+    assert modality_page_quota(
+        get_arch("granite-4.0-h-small.stage0")) == 9 * (32 + 1)
 
 
 # ---- telemetry + BENCH_serve.json schema ----------------------------------

@@ -77,7 +77,7 @@ def test_cells_for_skips():
     total = sum(len(cells_for(get_arch(a))) for a in
                 [a for a in __import__("repro.configs",
                                        fromlist=["ALL_ARCHS"]).ALL_ARCHS])
-    assert total == 33  # 10×3 + 3 long_500k
+    assert total == 39  # 12×3 + 3 long_500k
 
 
 # ---- data pipeline -----------------------------------------------------------

@@ -16,7 +16,10 @@ chip:
 - the serving engine's fused decode tick for qwen2-0.5b at its
   published widths with the Pallas allocator, built from shapes only;
 - the engine's admission prefill of one 2048-token prompt at the same
-  widths (XLA alone: it holds no kernel).
+  widths (XLA alone: it holds no kernel);
+- the fused tick and a 1024-token admission prefill of granite-4.0-h's
+  stage 0 (64 slots × 5120 tokens), whose page heap holds the KV and
+  the recurrent state: neither program copies or converts the heap.
 
 Each allocator or tick compile must hold a Mosaic kernel
 (``tpu_custom_call``).  The topology is described only inside the
@@ -61,8 +64,8 @@ def one_chip(topo):
 @pytest.fixture()
 def compile_for(one_chip):
     """Compile ``fn`` for arguments of the given shapes on the described
-    chip with
-    the persistent cache off (a TPU compile written to it could not be
+    chip (donating the arguments ``donate`` names, as the engine does)
+    with the persistent cache off (a TPU compile written to it could not be
     read back without a chip); returns the compiled program's text."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -76,9 +79,10 @@ def compile_for(one_chip):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=one_chip), a)
 
-    def run(fn, *args):
+    def run(fn, *args, donate=()):
         placed = [place(a) for a in args]
-        return jax.jit(fn).lower(*placed).compile().as_text()
+        return jax.jit(fn, donate_argnums=donate).lower(
+            *placed).compile().as_text()
 
     yield run
     jax.config.update("jax_enable_compilation_cache", prev)
@@ -186,3 +190,62 @@ def test_admission_prefill_compiles_at_full_width(compile_for,
     text = compile_for(eng._prefill, eng.params, batch, eng.caches, slot)
     assert f"[1,{lp},{d}]" in text
     assert f"[{MAX_BATCH},{lp},{d}]" not in text
+
+
+@pytest.fixture()
+def granite_engine(monkeypatch):
+    """granite-4.0-h-small's stage-0 engine at the benchmark cell's
+    geometry, built from shapes."""
+    from repro.configs import get_arch
+    from repro.models import model as M
+    from repro.serve.engine import ServingEngine
+
+    caches = M.Model.make_decode_caches
+    monkeypatch.setattr(M.Model, "make_decode_caches",
+                        lambda self, *a, **k: caches(self, *a, **k,
+                                                     abstract=True))
+    model = M.build_model(get_arch("granite-4.0-h-small.stage0"))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    return ServingEngine(model, params, max_batch=64, max_seq=5120,
+                         kv_dtype=jnp.float32, alloc_backend="pallas",
+                         alloc_lowering="blocked", mega_step=True,
+                         max_new_cap=4096)
+
+
+def _heap_copies(text, heap):
+    """Lines of a compiled program that copy the whole page heap, or
+    make it again in another dtype (XLA on a TPU lowers a float32 heap
+    that a default-precision product reads to bfloat16 whole)."""
+    import re
+    dims = ",".join(map(str, heap.shape))
+    made = re.compile(r"= (\w+)\[%s\]\{[^}]*\} ([\w-]+)\(" % dims)
+    return [ln for ln in text.splitlines()
+            for m in [made.search(ln)]
+            if m and (m.group(2) in ("copy", "convert")
+                      or m.group(1) != "f32")]
+
+
+def test_granite_tick_compiles_and_never_copies_the_heap(
+        compile_for, granite_engine, monkeypatch):
+    """The tick reads and writes every slot's state pages and KV in the
+    page heap it was given (5.2 GB here) and copies none of it."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    eng = granite_engine
+    eng._build_mega()
+    carry = jax.eval_shape(lambda *a: a, eng.params, eng.alloc_state,
+                           eng.caches, eng.mega_state)
+    text = compile_for(eng._mega_fn, *carry, donate=(1, 2, 3))
+    assert "tpu_custom_call" in text
+    assert not _heap_copies(text, eng.caches.kv.layers.k)
+
+
+def test_granite_prefill_compiles_and_never_copies_the_heap(
+        compile_for, granite_engine):
+    """A 1024-token admission writes the row's state pages and KV pages
+    in place."""
+    eng = granite_engine
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 1024), jnp.int32)}
+    slot = jax.ShapeDtypeStruct((), jnp.int32)
+    text = compile_for(eng._prefill, eng.params, batch, eng.caches, slot,
+                       donate=(2,))
+    assert not _heap_copies(text, eng.caches.kv.layers.k)
