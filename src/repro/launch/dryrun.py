@@ -143,12 +143,9 @@ def cache_shardings(rules: ShardingRules, caches):
     def one(x):
         if x is None:
             return None
-        if x.ndim == 5:     # (L, NP, page, Hkv, hd) page heap
+        if x.ndim >= 4:     # page heap (L, NP, page, *kv_row), its scales
             return NamedSharding(mesh, rules.spec_for(
-                (None, "pages", None, None, None), x.shape))
-        if x.ndim == 4:     # kv scales (L, NP, page, Hkv)
-            return NamedSharding(mesh, rules.spec_for(
-                (None, "pages", None, None), x.shape))
+                (None, "pages") + (None,) * (x.ndim - 2), x.shape))
         if x.ndim == 3:     # ssm conv (Lr, B, ...) / rglru states
             return NamedSharding(mesh, rules.spec_for(
                 (None, "batch", "mlp"), x.shape))
@@ -256,7 +253,6 @@ def _measure(cfg, shape, mesh, *, analysis: bool, kv_dtype=None,
     from repro.models import layers as Lyr
     Lyr.set_analysis_unroll(analysis)
     KVC.set_page_block_override(10 ** 9 if analysis else None)
-    KVC.set_dense_prefill(True)  # canonical page layout in the dry-run
     try:
         fn, args, shardings, _rules = build_cell(cfg, shape, mesh,
                                                  kv_dtype=kv_dtype,
@@ -284,7 +280,6 @@ def _measure(cfg, shape, mesh, *, analysis: bool, kv_dtype=None,
     finally:
         Lyr.set_analysis_unroll(False)
         KVC.set_page_block_override(None)
-        KVC.set_dense_prefill(False)
 
 
 def _probe_plan(cfg: ModelConfig):

@@ -103,18 +103,20 @@ def decode_stack(cfg, params, tokens, enc_out, mode,
     else:
         pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
 
-    def body(x, inp):
+    def body(carry, inp):
+        x, heap = carry
         x = constrain(x, "batch", "seq", "act_embed")
-        p_l, kv_l, cross = inp
+        p_l, i, cross = inp
         h = Lyr.apply_norm(cfg, p_l["norm1"], x)
         q, k, v = Lyr.qkv_project(cfg, p_l["attn"], h, pos)
         if mode == "decode":
-            kv_l = KV.append1(kv_l, page_table, seq_lens, k, v)
-            o = KV.paged_attend1(kv_l, page_table, seq_lens + 1, q)
+            heap = KV.append1_at(heap, i, page_table, seq_lens, k, v)
+            o = KV.paged_attend1(KV.layer_of(heap, i), page_table,
+                                 seq_lens + 1, q)
         else:
             o = Lyr.flash_attention(q, k, v, causal=True)
             if mode == "prefill":
-                kv_l = KV.prefill_write1(kv_l, page_table, k, v)
+                heap = KV.prefill_write_at(heap, i, page_table, k, v)
         x = x + Lyr.attn_out(p_l["attn"], o, x.dtype)
 
         # cross attention
@@ -133,16 +135,17 @@ def decode_stack(cfg, params, tokens, enc_out, mode,
 
         h = Lyr.apply_norm(cfg, p_l["norm2"], x)
         x = x + Lyr.apply_mlp(cfg, p_l["ffn"], h)
-        return x, (kv_l, kx, vx)
+        return (x, heap), (kx, vx)
 
     from repro.models.transformer import _remat
-    kv_xs = None if kv is None else kv.layers
+    heap = None if kv is None else kv.layers
     cross_xs = ((caches.cross_k, caches.cross_v) if mode == "decode"
                 else (None, None))
-    x, (kv_layers, ck, cv) = jax.lax.scan(
-        _remat(body, remat_policy), x, (params["dec_blocks"], kv_xs,
-                                        cross_xs), unroll=Lyr.scan_unroll())
-    new_kv = None if kv is None else kv._replace(layers=kv_layers)
+    (x, heap), (ck, cv) = jax.lax.scan(
+        _remat(body, remat_policy), (x, heap),
+        (params["dec_blocks"], jnp.arange(cfg.num_layers, dtype=jnp.int32),
+         cross_xs), unroll=Lyr.scan_unroll())
+    new_kv = None if kv is None else kv._replace(layers=heap)
     new = EncDecCaches(self_kv=new_kv, cross_k=ck, cross_v=cv,
                        enc_valid=caches.enc_valid)
     if mode == "prefill":

@@ -10,8 +10,11 @@ flat with depth, and the FSDP all-gathers pipeline across the scan.
     decode   — one token against the caches (paged attention / O(1)
                recurrences)
 
-Caches ride through the scan as per-layer xs/ys (KVLayer arrays are
-stacked on the same leading axis as params).
+The page heap (``KVLayer`` arrays stacked over attention layers) is a
+scan carry with the layer index, read and written in place at the pages
+the table names — never per-layer xs/ys, which would slice every layer
+out and write a new stacked heap each step.  Dense recurrent states
+(ssm / hybrid) ride as per-layer xs/ys.
 """
 from __future__ import annotations
 
@@ -136,22 +139,30 @@ def num_rec_layers(cfg: ModelConfig) -> int:
 # sub-blocks (single layer)
 # ---------------------------------------------------------------------------
 
-def _attn_mix(cfg, p, x, positions, mode, kvl, page_table, seq_lens,
+def _attn_mix(cfg, p, x, positions, mode, heap, a, page_table, seq_lens,
               window):
+    """Attention layer ``a`` of the stacked page ``heap`` (a scan
+    carry): returns its output and the heap with the layer's new K/V
+    written in place."""
     q, k, v = Lyr.qkv_project(cfg, p, x, positions)
     # windowed layers use ring page tables when the table is smaller
     # than the sequence needs (window-bounded KV — pages recycle).
     ring = (window is not None and page_table is not None
             and mode in ("decode", "prefill"))
+    scale = cfg.attn_scale_
     if mode == "decode":
-        kvl = KV.append1(kvl, page_table, seq_lens, k, v, ring=ring)
-        o = KV.paged_attend1(kvl, page_table, seq_lens + 1, q,
-                             window=window, ring=ring)
+        heap = KV.append1_at(heap, a, page_table, seq_lens, k, v,
+                             ring=ring)
+        o = KV.paged_attend1(KV.layer_of(heap, a), page_table,
+                             seq_lens + 1, q, window=window, ring=ring,
+                             scale=scale)
     else:
-        o = Lyr.flash_attention(q, k, v, causal=True, window=window)
+        o = Lyr.flash_attention(q, k, v, causal=True, window=window,
+                                scale=scale)
         if mode == "prefill":
-            kvl = KV.prefill_write1(kvl, page_table, k, v, ring=ring)
-    return Lyr.attn_out(p, o, x.dtype), kvl
+            heap = KV.prefill_write_at(heap, a, page_table, k, v,
+                                       ring=ring)
+    return Lyr.attn_out(p, o, x.dtype), heap
 
 
 def _ffn(cfg, p, x, mode="train"):
@@ -161,21 +172,19 @@ def _ffn(cfg, p, x, mode="train"):
     return Lyr.apply_mlp(cfg, p, x), jnp.float32(0.0)
 
 
-def dense_block(cfg, p, x, positions, mode, kvl, page_table, seq_lens):
+def dense_block(cfg, p, x, positions, mode, heap, i, page_table,
+                seq_lens):
     window = cfg.sliding_window
-    if cfg.parallel_block:
-        h = Lyr.apply_norm(cfg, p["norm1"], x)
-        a, kvl = _attn_mix(cfg, p["attn"], h, positions, mode, kvl,
-                           page_table, seq_lens, window)
-        f, aux = _ffn(cfg, p["ffn"], h, mode)
-        return x + a + f, kvl, aux
     h = Lyr.apply_norm(cfg, p["norm1"], x)
-    a, kvl = _attn_mix(cfg, p["attn"], h, positions, mode, kvl,
-                       page_table, seq_lens, window)
+    a, heap = _attn_mix(cfg, p["attn"], h, positions, mode, heap, i,
+                        page_table, seq_lens, window)
+    if cfg.parallel_block:
+        f, aux = _ffn(cfg, p["ffn"], h, mode)
+        return x + a + f, heap, aux
     x = x + a
     h = Lyr.apply_norm(cfg, p["norm2"], x)
     f, aux = _ffn(cfg, p["ffn"], h, mode)
-    return x + f, kvl, aux
+    return x + f, heap, aux
 
 
 def ssm_block(cfg, p, x, mode, cache):
@@ -230,22 +239,23 @@ def uniform_stack(cfg, params, x, positions, mode, caches: Caches,
         new = Caches(kv=None, ssm_h=st[0], ssm_conv=st[1])
         return x, aux, new
 
+    heap = None if kv is None else kv.layers
     page_table = None if kv is None else kv.page_table
     seq_lens = None if kv is None else kv.seq_lens
 
     def body(carry, inp):
-        x, aux = carry
+        x, heap, aux = carry
         x = constrain(x, "batch", "seq", "act_embed")
-        p_l, kv_l = inp
-        x, kv_l, a = dense_block(cfg, p_l, x, positions, mode, kv_l,
+        p_l, i = inp
+        x, heap, a = dense_block(cfg, p_l, x, positions, mode, heap, i,
                                  page_table, seq_lens)
-        return (x, aux + a), kv_l
+        return (x, heap, aux + a), None
 
-    kv_xs = None if kv is None else kv.layers
-    (x, aux), kv_layers = jax.lax.scan(
-        _remat(body, remat_policy), (x, jnp.float32(0.0)),
-        (params["blocks"], kv_xs), unroll=Lyr.scan_unroll())
-    new_kv = None if kv is None else kv._replace(layers=kv_layers)
+    (x, heap, aux), _ = jax.lax.scan(
+        _remat(body, remat_policy), (x, heap, jnp.float32(0.0)),
+        (params["blocks"], jnp.arange(cfg.num_layers, dtype=jnp.int32)),
+        unroll=Lyr.scan_unroll())
+    new_kv = None if kv is None else kv._replace(layers=heap)
     return x, aux, Caches(kv=new_kv)
 
 
@@ -255,24 +265,26 @@ def hybrid_stack(cfg, params, x, positions, mode, caches: Caches,
     kv = caches.kv
     ntri = cfg.num_layers // cfg.attn_period
     tail = cfg.num_layers - ntri * cfg.attn_period
+    heap = None if kv is None else kv.layers
     page_table = None if kv is None else kv.page_table
     seq_lens = None if kv is None else kv.seq_lens
 
     def triple_body(carry, inp):
-        x = carry
+        x, heap = carry
         x = constrain(x, "batch", "seq", "act_embed")
-        p_t, kv_l, (h1, c1), (h2, c2) = inp
+        p_t, i, (h1, c1), (h2, c2) = inp
         cache1 = (h1, c1) if mode == "decode" else None
         cache2 = (h2, c2) if mode == "decode" else None
         x, nc1 = rec_block(cfg, p_t["rec1"], x, mode, cache1)
         x, nc2 = rec_block(cfg, p_t["rec2"], x, mode, cache2)
         h = Lyr.apply_norm(cfg, p_t["attn"]["norm1"], x)
-        a, kv_l = _attn_mix(cfg, p_t["attn"]["attn"], h, positions, mode,
-                            kv_l, page_table, seq_lens, cfg.local_window)
+        a, heap = _attn_mix(cfg, p_t["attn"]["attn"], h, positions, mode,
+                            heap, i, page_table, seq_lens,
+                            cfg.local_window)
         x = x + a
         h = Lyr.apply_norm(cfg, p_t["attn"]["norm2"], x)
         x = x + Lyr.apply_mlp(cfg, p_t["attn"]["ffn"], h)
-        return x, (kv_l, nc1, nc2)
+        return (x, heap), (nc1, nc2)
 
     def _dummy_rec(n):
         return (jnp.zeros((n, 1)), jnp.zeros((n, 1)))
@@ -284,14 +296,12 @@ def hybrid_stack(cfg, params, x, positions, mode, caches: Caches,
                    caches.ssm_conv[ntri:2 * ntri])
                   ) if mode == "decode" else (_dummy_rec(ntri),
                                               _dummy_rec(ntri))
-        kv_xs = None if kv is None else kv.layers
-        x, (kv_layers, nc1, nc2) = jax.lax.scan(
-            _remat(triple_body, remat_policy), x,
-            (params["triples"], kv_xs, rec_xs[0], rec_xs[1]),
+        (x, heap), (nc1, nc2) = jax.lax.scan(
+            _remat(triple_body, remat_policy), (x, heap),
+            (params["triples"], jnp.arange(ntri, dtype=jnp.int32),
+             rec_xs[0], rec_xs[1]),
             unroll=Lyr.scan_unroll())
         hs, cs = [nc1[0], nc2[0]], [nc1[1], nc2[1]]
-    else:  # probe configs: tail-only stacks (no attention layers)
-        kv_layers = None if kv is None else kv.layers
     if tail:
         def tail_body(carry, inp):
             x = carry
@@ -307,7 +317,7 @@ def hybrid_stack(cfg, params, x, positions, mode, caches: Caches,
         hs.append(nct[0])
         cs.append(nct[1])
 
-    new_kv = None if kv is None else kv._replace(layers=kv_layers)
+    new_kv = None if kv is None else kv._replace(layers=heap)
     new = Caches(kv=new_kv, ssm_h=jnp.concatenate(hs, 0),
                  ssm_conv=jnp.concatenate(cs, 0))
     return x, jnp.float32(0.0), new
@@ -371,18 +381,8 @@ def pattern_stack(cfg, params, x, positions, mode, caches: Caches,
                 heap = KV.write_state(cfg, heap, ids, hf, conv,
                                       holes=mode == "decode")
         else:
-            q, k, v = Lyr.qkv_project(cfg, p["attn"], h, positions)
-            if mode == "decode":
-                heap = KV.append1_at(heap, i, page_table, seq_lens, k, v)
-                o = KV.paged_attend1(KV.layer_of(heap, i), page_table,
-                                     seq_lens + 1, q, scale=cfg.attn_scale_,
-                                     precision=jax.lax.Precision.HIGHEST)
-            else:
-                o = Lyr.flash_attention(q, k, v, causal=True,
-                                        scale=cfg.attn_scale_)
-                if mode == "prefill":
-                    heap = KV.prefill_write_at(heap, i, page_table, k, v)
-            y = Lyr.attn_out(p["attn"], o, x.dtype)
+            y, heap = _attn_mix(cfg, p["attn"], h, positions, mode, heap,
+                                i, page_table, seq_lens, None)
         x, a, load = moe(p, x + rm * y)
         return (x, heap, aux + a), load
 

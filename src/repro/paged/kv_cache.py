@@ -9,16 +9,21 @@ bulk transactions.
 
 Layout: page heaps are stacked over attention layers — one page id
 backs all layers' K/V slots for its 16-token span (page tables are
-layer-invariant, as in vLLM).  Optional int8 quantization stores a per
-(slot, head) scale — this is what makes qwen1.5-32b's decode_32k cell
-fit v5e HBM (DESIGN.md §Arch-applicability).
+layer-invariant, as in vLLM).  A page stores each token's ``Hkv·hd``
+values in rows of 128 lanes, the TPU tile's width (``kv_row``): one row
+``(page, Hkv·hd)`` where they fit it (qwen2's 2 × 64 heads fill it
+exactly), else ``(page, Hkv·hd / 128, 128)`` (granite's 8 × 128).  The
+heap is then stored as it is gathered and scattered, with no relayout.
+Optional int8 quantization stores a per (slot, head) scale — this is
+what makes qwen1.5-32b's decode_32k cell fit v5e HBM (DESIGN.md
+§Arch-applicability).
 
-Single-layer cores (``append1`` / ``prefill_write1`` / ``paged_attend1``)
-are what the model's scan-over-layers consumes; the ``PagedKV``
-container stacks them for the serving engine.  ``paged_attend1`` is the
-GSPMD-shardable jnp decode attention (blockwise online softmax over
-page-table gathers); kernels/paged_attention.py is the single-chip TPU
-Pallas fast path validated against the same oracle.
+The model's layer scan carries the stacked heap and the layer index:
+``append1_at`` / ``prefill_write_at`` write one layer's tokens in place
+and ``layer_of`` hands ``paged_attend1`` a layer without slicing it out.
+``paged_attend1`` is the GSPMD-shardable jnp decode attention (blockwise
+online softmax over page-table gathers); kernels/paged_attention.py is
+the single-chip TPU Pallas fast path validated against the same oracle.
 """
 from __future__ import annotations
 
@@ -31,6 +36,7 @@ import numpy as np
 from repro.core import HeapConfig, Ouroboros
 
 PAGE_SIZE = 16  # tokens per KV page
+LANES = 128     # a TPU tile's minor dimension
 _NEG = -1e30
 
 # Analysis override: the dry-run sets this to the full table width so
@@ -39,36 +45,40 @@ _NEG = -1e30
 # the normal blocked path (override None).
 _PB_OVERRIDE = None
 
-# Dense-prefill fast path: when the page table is the canonical layout
-# (page id = b·P + j, the engine's bulk-reservation order) a prefill KV
-# write is a pure reshape — no scatter.  GSPMD cannot partition the
-# general scatter into a fully-sharded heap and replicates it (observed
-# ~46 GiB/chip extra on qwen1.5-32b×prefill_32k).  Enabled by the
-# dry-run/serving launcher; the engine's arbitrary-id path keeps the
-# scatter.
-_DENSE_PREFILL = False
-
 
 def set_page_block_override(v):
     global _PB_OVERRIDE
     _PB_OVERRIDE = v
 
 
-def set_dense_prefill(v: bool):
-    global _DENSE_PREFILL
-    _DENSE_PREFILL = bool(v)
-
-
 class KVLayer(NamedTuple):
-    """One attention layer's page heap (the scan-over-layers unit)."""
-    k: jnp.ndarray                  # (NP, page, Hkv, hd) kv_dtype
+    """Page heap arrays: stacked over attention layers in a
+    :class:`PagedKV`, one layer's as :func:`layer_of` gives it."""
+    k: jnp.ndarray                  # (L, NP, page, *kv_row) kv_dtype
     v: jnp.ndarray
-    k_scale: Optional[jnp.ndarray]  # (NP, page, Hkv) f32 — int8 KV only
+    k_scale: Optional[jnp.ndarray]  # (L, NP, page, Hkv) f32 — int8 only
     v_scale: Optional[jnp.ndarray]
 
 
+def kv_row(num_kv_heads: int, head_dim: int) -> tuple:
+    """How the heap stores one token's K (or V): its ``Hkv·hd`` values
+    in rows of ``LANES`` where they fill more than one, else as one row.
+
+    >>> kv_row(2, 64), kv_row(8, 128), kv_row(2, 16)
+    ((128,), (8, 128), (32,))
+    """
+    n = num_kv_heads * head_dim
+    return (n // LANES, LANES) if n > LANES and n % LANES == 0 else (n,)
+
+
+def page_rows(x):
+    """K or V ``(..., Hkv, hd)`` as the heap stores a token
+    (:func:`kv_row`)."""
+    return x.reshape(x.shape[:-2] + kv_row(*x.shape[-2:]))
+
+
 class PagedKV(NamedTuple):
-    layers: KVLayer                 # arrays stacked: (L, NP, page, Hkv, hd)
+    layers: KVLayer
     page_table: jnp.ndarray         # (B, P) int32, -1 = hole
     seq_lens: jnp.ndarray           # (B,) int32 — tokens already cached
 
@@ -80,14 +90,15 @@ class PagedKV(NamedTuple):
 def init_paged_kv(num_layers: int, num_pages: int, batch: int,
                   max_pages_per_seq: int, num_kv_heads: int, head_dim: int,
                   kv_dtype=jnp.bfloat16, page: int = PAGE_SIZE) -> PagedKV:
-    shape = (num_layers, num_pages, page, num_kv_heads, head_dim)
+    shape = (num_layers, num_pages, page) + kv_row(num_kv_heads, head_dim)
+    scales = (num_layers, num_pages, page, num_kv_heads)
     quant = kv_dtype == jnp.int8
     return PagedKV(
         layers=KVLayer(
             k=jnp.zeros(shape, kv_dtype),
             v=jnp.zeros(shape, kv_dtype),
-            k_scale=jnp.zeros(shape[:4], jnp.float32) if quant else None,
-            v_scale=jnp.zeros(shape[:4], jnp.float32) if quant else None),
+            k_scale=jnp.zeros(scales, jnp.float32) if quant else None,
+            v_scale=jnp.zeros(scales, jnp.float32) if quant else None),
         page_table=jnp.full((batch, max_pages_per_seq), -1, jnp.int32),
         seq_lens=jnp.zeros(batch, jnp.int32),
     )
@@ -97,14 +108,15 @@ def abstract_paged_kv(num_layers, num_pages, batch, max_pages_per_seq,
                       num_kv_heads, head_dim, kv_dtype=jnp.bfloat16,
                       page: int = PAGE_SIZE) -> PagedKV:
     """ShapeDtypeStruct twin of ``init_paged_kv`` for the dry-run."""
-    shape = (num_layers, num_pages, page, num_kv_heads, head_dim)
+    shape = (num_layers, num_pages, page) + kv_row(num_kv_heads, head_dim)
+    scales = (num_layers, num_pages, page, num_kv_heads)
     quant = kv_dtype == jnp.int8
     sds = jax.ShapeDtypeStruct
     return PagedKV(
         layers=KVLayer(
             k=sds(shape, kv_dtype), v=sds(shape, kv_dtype),
-            k_scale=sds(shape[:4], jnp.float32) if quant else None,
-            v_scale=sds(shape[:4], jnp.float32) if quant else None),
+            k_scale=sds(scales, jnp.float32) if quant else None,
+            v_scale=sds(scales, jnp.float32) if quant else None),
         page_table=sds((batch, max_pages_per_seq), jnp.int32),
         seq_lens=sds((batch,), jnp.int32),
     )
@@ -300,37 +312,43 @@ class _LayerOf:
 
 
 def layer_of(layers: KVLayer, a) -> KVLayer:
-    """Attention layer ``a`` (may be traced) of a stacked float32 heap,
-    for :func:`paged_attend1`."""
-    return KVLayer(_LayerOf(layers.k, a), _LayerOf(layers.v, a), None,
-                   None)
+    """Attention layer ``a`` (may be traced) of a stacked heap, for
+    :func:`paged_attend1`."""
+    return KVLayer(*(None if x is None else _LayerOf(x, a)
+                     for x in layers))
 
 
-def append1_at(layers: KVLayer, a, page_table, seq_lens, k_t,
-               v_t) -> KVLayer:
-    """:func:`append1` into attention layer ``a`` of the stacked heap,
-    written in place in the stacked arrays."""
+def _token_rows(layers: KVLayer, a, page_table, pos, ring: bool):
+    """Heap index of tokens at positions ``pos`` (B, S) in attention
+    layer ``a``: ``(a, page id, slot)``.  A hole (-1) in the table
+    points one past the last page, where :func:`_store` drops the
+    write.  ``ring``: windowed attention — table slot = page index mod
+    P, so a window-sized table serves unbounded sequences."""
     page, np_ = layers.k.shape[2], layers.k.shape[1]
-    pidx, slot = seq_lens // page, seq_lens % page
-    ids = jnp.take_along_axis(page_table, pidx[:, None], axis=1)[:, 0]
-    idx = (a, jnp.where(ids >= 0, ids, np_), slot)
-    return _store(layers, idx, k_t[:, 0], v_t[:, 0])
+    pidx = pos // page
+    if ring:
+        pidx = pidx % page_table.shape[1]
+    ids = jnp.take_along_axis(page_table, pidx, axis=1)
+    return a, jnp.where(ids >= 0, ids, np_), pos % page
 
 
-def prefill_write_at(layers: KVLayer, a, page_table, k, v) -> KVLayer:
-    """:func:`prefill_write1` of a prompt's K/V (B, S, Hkv, hd) into
-    attention layer ``a`` of the stacked heap, in place.  Every
-    position's page must be mapped (an admission maps the prompt's)."""
+def append1_at(layers: KVLayer, a, page_table, seq_lens, k_t, v_t,
+               ring: bool = False) -> KVLayer:
+    """Write one new token's K/V (B, 1, Hkv, hd) at position
+    ``seq_lens`` of each sequence into attention layer ``a`` of the
+    stacked heap, in place.  Pages must already be mapped."""
+    idx = _token_rows(layers, a, page_table, seq_lens[:, None], ring)
+    return _store(layers, idx, k_t, v_t)
+
+
+def prefill_write_at(layers: KVLayer, a, page_table, k, v,
+                     ring: bool = False) -> KVLayer:
+    """Write a prompt's K/V (B, S, Hkv, hd), positions 0 … S-1, into
+    attention layer ``a`` of the stacked heap, in place."""
     B, S = k.shape[:2]
-    page = layers.k.shape[2]
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    ids = jnp.take_along_axis(page_table, pos // page, axis=1)
-    idx = (a, ids, pos % page)
-    return layers._replace(
-        k=layers.k.at[idx].set(k.astype(layers.k.dtype),
-                               mode="promise_in_bounds"),
-        v=layers.v.at[idx].set(v.astype(layers.v.dtype),
-                               mode="promise_in_bounds"))
+    return _store(layers, _token_rows(layers, a, page_table, pos, ring),
+                  k, v)
 
 
 def scatter_grant_words(page_table, page_counts, lane_slot, lane_rank,
@@ -401,7 +419,7 @@ def apply_forwarding(kv: PagedKV, fwd, wpp: int,
     ...                  dst=jnp.array([0], jnp.int32),
     ...                  sizes=jnp.array([256], jnp.int32))
     >>> kv2 = apply_forwarding(kv, fwd, wpp=64)
-    >>> int(kv2.page_table[0, 0]), float(kv2.layers.k[0, 0, 0, 0, 0])
+    >>> int(kv2.page_table[0, 0]), float(kv2.layers.k[0, 0, 0, 0])
     (0, 7.0)
     """
     if max_span is None:
@@ -437,84 +455,39 @@ def _quant(x):
     return jnp.round(x / scale).astype(jnp.int8), scale[..., 0]
 
 
-def _store(layer: KVLayer, idx, k_new, v_new) -> KVLayer:
-    if layer.k_scale is not None:
+def _store(layers: KVLayer, idx, k_new, v_new) -> KVLayer:
+    """Set the heap rows ``idx`` names to tokens of K/V ``(..., Hkv,
+    hd)``, quantized per (token, head) on an int8 heap."""
+    if layers.k_scale is not None:
         kq, ks = _quant(k_new.astype(jnp.float32))
         vq, vs = _quant(v_new.astype(jnp.float32))
         return KVLayer(
-            k=layer.k.at[idx].set(kq, mode="drop"),
-            v=layer.v.at[idx].set(vq, mode="drop"),
-            k_scale=layer.k_scale.at[idx].set(ks, mode="drop"),
-            v_scale=layer.v_scale.at[idx].set(vs, mode="drop"))
-    return layer._replace(
-        k=layer.k.at[idx].set(k_new.astype(layer.k.dtype), mode="drop"),
-        v=layer.v.at[idx].set(v_new.astype(layer.v.dtype), mode="drop"))
-
-
-def append1(layer: KVLayer, page_table, seq_lens, k_t, v_t,
-            ring: bool = False) -> KVLayer:
-    """Write one new token's K/V at position ``seq_lens`` per sequence.
-    k_t, v_t: (B, 1, Hkv, hd).  Pages must already be mapped.
-    ``ring``: windowed attention — table slot = page_index mod P, so a
-    window-sized table serves unbounded sequences (page reuse)."""
-    page = layer.k.shape[1]
-    np_ = layer.k.shape[0]
-    P = page_table.shape[1]
-    pidx, slot = seq_lens // page, seq_lens % page
-    if ring:
-        pidx = pidx % P
-    ids = jnp.take_along_axis(page_table, pidx[:, None], axis=1)[:, 0]
-    idx = (jnp.where(ids >= 0, ids, np_), slot)
-    return _store(layer, idx, k_t[:, 0], v_t[:, 0])
-
-
-def prefill_write1(layer: KVLayer, page_table, k, v, pos0=0,
-                   ring: bool = False) -> KVLayer:
-    """Bulk-write a prefill segment (S tokens).  k, v: (B, S, Hkv, hd)."""
-    B, S = k.shape[:2]
-    page = layer.k.shape[1]
-    np_ = layer.k.shape[0]
-    P = page_table.shape[1]
-    if (_DENSE_PREFILL and not ring and pos0 == 0 and np_ == B * P
-            and S <= P * page):
-        # canonical layout: page id = b·P + j  →  the heap IS the
-        # reshaped K/V tensor (zero-scatter path).
-        pad = P * page - S
-        kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        kp = kp.reshape(np_, page, *k.shape[2:])
-        vp = vp.reshape(np_, page, *v.shape[2:])
-        if layer.k_scale is not None:
-            kq, ks = _quant(kp.astype(jnp.float32))
-            vq, vs = _quant(vp.astype(jnp.float32))
-            return KVLayer(k=kq, v=vq, k_scale=ks, v_scale=vs)
-        return KVLayer(k=kp.astype(layer.k.dtype),
-                       v=vp.astype(layer.v.dtype),
-                       k_scale=None, v_scale=None)
-    pos = pos0 + jnp.arange(S, dtype=jnp.int32)[None, :]
-    pos = jnp.broadcast_to(pos, (B, S))
-    pidx, slot = pos // page, pos % page
-    if ring:
-        pidx = pidx % P
-    ids = jnp.take_along_axis(page_table, pidx, axis=1)
-    idx = (jnp.where(ids >= 0, ids, np_), slot)
-    return _store(layer, idx, k, v)
+            k=layers.k.at[idx].set(page_rows(kq), mode="drop"),
+            v=layers.v.at[idx].set(page_rows(vq), mode="drop"),
+            k_scale=layers.k_scale.at[idx].set(ks, mode="drop"),
+            v_scale=layers.v_scale.at[idx].set(vs, mode="drop"))
+    return layers._replace(
+        k=layers.k.at[idx].set(page_rows(k_new).astype(layers.k.dtype),
+                               mode="drop"),
+        v=layers.v.at[idx].set(page_rows(v_new).astype(layers.v.dtype),
+                               mode="drop"))
 
 
 def paged_attend1(layer: KVLayer, page_table, kv_len, q, *,
                   window: Optional[int] = None, page_block: int = 16,
-                  ring: bool = False, scale: Optional[float] = None,
-                  precision=None):
+                  ring: bool = False, scale: Optional[float] = None):
     """Decode attention for one layer over the paged heap.
 
+    layer: one layer's heap, (NP, page, *kv_row) (:func:`layer_of`);
     q: (B, 1, Hq, hd); kv_len: (B,) valid tokens (incl. current);
-    ``scale``: score scale (None = hd ** -0.5); ``precision``: of both
-    products (a float32 heap read at ``HIGHEST``: at the default, XLA
-    on a TPU lowers the heap to bfloat16 whole, ahead of the gathers).
+    ``scale``: score scale (None = hd ** -0.5).  A float32 heap is read
+    at ``HIGHEST`` precision: at the default, XLA on a TPU lowers the
+    heap to bfloat16 whole, ahead of the gathers.
     Blockwise online softmax over page-table gathers — O(page_block)
     live memory, GSPMD-shardable (heads on 'model', batch on 'data')."""
     B, _, Hq, D = q.shape
-    NP, page, Hkv, _ = layer.k.shape
+    page = layer.k.shape[1]
+    Hkv = int(np.prod(layer.k.shape[2:])) // D
     P = page_table.shape[1]
     G = Hq // Hkv
     pb = min(_PB_OVERRIDE or page_block, P)
@@ -528,6 +501,8 @@ def paged_attend1(layer: KVLayer, page_table, kv_len, q, *,
     # f32 accumulation via preferred_element_type below.
     stage_dt = (jnp.float32 if layer.k.dtype == jnp.float32
                 else jnp.bfloat16)
+    precision = (jax.lax.Precision.HIGHEST if stage_dt == jnp.float32
+                 else None)
     qg = (q[:, 0].reshape(B, Hkv, G, D)
           * (D ** -0.5 if scale is None else scale)).astype(stage_dt)
 
@@ -535,13 +510,14 @@ def paged_attend1(layer: KVLayer, page_table, kv_len, q, *,
         m, l, acc = carry
         i, ids = inp                                   # ids: (B, pb)
         safe = jnp.maximum(ids, 0)
-        k = layer.k[safe].astype(stage_dt)             # (B, pb, page, Hkv, D)
-        v = layer.v[safe].astype(stage_dt)
+        # (B, pb, page, *kv_row) rows, split into heads once gathered
+        k = layer.k[safe].astype(stage_dt).reshape(B, pb * page, Hkv, D)
+        v = layer.v[safe].astype(stage_dt).reshape(B, pb * page, Hkv, D)
         if layer.k_scale is not None:
-            k = k * layer.k_scale[safe][..., None].astype(stage_dt)
-            v = v * layer.v_scale[safe][..., None].astype(stage_dt)
-        k = k.reshape(B, pb * page, Hkv, D)
-        v = v.reshape(B, pb * page, Hkv, D)
+            ks = layer.k_scale[safe].reshape(B, pb * page, Hkv, 1)
+            vs = layer.v_scale[safe].reshape(B, pb * page, Hkv, 1)
+            k = k * ks.astype(stage_dt)
+            v = v * vs.astype(stage_dt)
         j = i * pb + jax.lax.broadcasted_iota(jnp.int32, (pb, page), 0)
         slot_of = jax.lax.broadcasted_iota(jnp.int32, (pb, page), 1)
         if ring:

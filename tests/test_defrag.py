@@ -399,7 +399,7 @@ def test_kv_apply_forwarding_preserves_reads():
 
     def gather(kv):
         pt = jnp.maximum(kv.page_table, 0)
-        ok = (kv.page_table >= 0)[None, :, :, None, None, None]
+        ok = (kv.page_table >= 0)[None, :, :, None, None]
         return np.asarray(jnp.where(ok, kv.layers.k[:, pt], 0.0))
 
     before = gather(kv)

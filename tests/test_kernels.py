@@ -259,6 +259,7 @@ def test_paged_attention_kernel_matches_serving_path(rng):
     sl = jnp.asarray([37, 61], jnp.int32)
 
     kernel = ops.paged_attention(q[:, 0], kp, vp, pt, sl)
-    lay = KV.KVLayer(k=kp, v=vp, k_scale=None, v_scale=None)
+    lay = KV.KVLayer(k=KV.page_rows(kp), v=KV.page_rows(vp), k_scale=None,
+                     v_scale=None)
     serving = KV.paged_attend1(lay, pt, sl, q)[:, 0]
     np.testing.assert_allclose(kernel, serving, atol=2e-5, rtol=2e-5)

@@ -17,6 +17,9 @@ chip:
   published widths with the Pallas allocator, built from shapes only;
 - the engine's admission prefill of one 2048-token prompt at the same
   widths (XLA alone: it holds no kernel);
+- the same tick and a 512-token admission prefill, donated as the
+  engine donates them: neither copies its bf16 page heap (a heap whose
+  rows tile needs no relayout, and the layer scan carries it);
 - the fused tick and a 1024-token admission prefill of granite-4.0-h's
   stage 0 (64 slots × 5120 tokens), whose page heap holds the KV and
   the recurrent state: neither program copies or converts the heap.
@@ -212,17 +215,50 @@ def granite_engine(monkeypatch):
                          max_new_cap=4096)
 
 
+_HLO_DTYPES = {"float32": "f32", "bfloat16": "bf16", "int8": "s8"}
+
+
 def _heap_copies(text, heap):
-    """Lines of a compiled program that copy the whole page heap, or
-    make it again in another dtype (XLA on a TPU lowers a float32 heap
-    that a default-precision product reads to bfloat16 whole)."""
+    """Lines of a compiled program that copy or convert the page heap,
+    whole or one layer of it, or make it in a dtype other than its own
+    (XLA on a TPU lowers a float32 heap that a default-precision
+    product reads to bfloat16 whole; a heap whose rows do not tile is
+    relaid out layer by layer)."""
     import re
-    dims = ",".join(map(str, heap.shape))
-    made = re.compile(r"= (\w+)\[%s\]\{[^}]*\} ([\w-]+)\(" % dims)
+    shapes = (heap.shape, heap.shape[1:])
+    dims = "|".join(",".join(map(str, d)) for d in shapes)
+    made = re.compile(r"= (\w+)\[(?:1,)?(?:%s)\]\{[^}]*\} ([\w-]+)\(" % dims)
+    own = _HLO_DTYPES[jnp.dtype(heap.dtype).name]
     return [ln for ln in text.splitlines()
             for m in [made.search(ln)]
-            if m and (m.group(2) in ("copy", "convert")
-                      or m.group(1) != "f32")]
+            if m and (m.group(2).startswith(("copy", "convert"))
+                      or m.group(1) != own)]
+
+
+def test_mega_tick_never_copies_the_heap(compile_for, full_width_engine,
+                                         monkeypatch):
+    """qwen2-0.5b's tick, donated as the engine donates it, appends and
+    reads each layer's K/V in the stacked bf16 heap it was given and
+    copies none of it."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    eng = full_width_engine
+    eng._build_mega()
+    carry = jax.eval_shape(lambda *a: a, eng.params, eng.alloc_state,
+                           eng.caches, eng.mega_state)
+    text = compile_for(eng._mega_fn, *carry, donate=(1, 2, 3))
+    assert not _heap_copies(text, eng.caches.kv.layers.k)
+
+
+def test_admission_prefill_never_copies_the_heap(compile_for,
+                                                 full_width_engine):
+    """A 512-token qwen2-0.5b admission writes the row's K/V pages in
+    place."""
+    eng = full_width_engine
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 512), jnp.int32)}
+    slot = jax.ShapeDtypeStruct((), jnp.int32)
+    text = compile_for(eng._prefill, eng.params, batch, eng.caches, slot,
+                       donate=(2,))
+    assert not _heap_copies(text, eng.caches.kv.layers.k)
 
 
 def test_granite_tick_compiles_and_never_copies_the_heap(
