@@ -159,14 +159,13 @@ def pallas_calls_per_defrag_wave(variant: str, backend: str = "pallas",
 
 def launches_per_tick(engine) -> int:
     """pallas_call launch count of ONE decode tick, read off the
-    engine's own jaxprs (the fused mega-step program, or — host mode —
-    the jitted decode plus its bulk-grow transaction).  A thin
+    engine's fused tick program.  A thin
     delegate to ``ServingEngine.launches_per_tick`` — the SAME counter
     feeds ``engine.stats["launches_per_tick"]`` and the fig8 serving
     records, so the two can never disagree.  Constant in ``max_batch``
     (each tick is a fixed set of jitted programs; the grow transaction
     is a single kernel): 1 with ``alloc_backend="pallas"``, 0 with the
-    jnp oracle in mega mode."""
+    jnp oracle."""
     return engine.launches_per_tick()
 
 
@@ -198,7 +197,7 @@ def alloc_comparison_cell(variant: str, *, quick: bool = False,
 #
 # The file is append-only: {"runs": [record, ...]}, one record per
 # benchmark invocation.  Two record kinds share the envelope —
-# ``serve`` (fig8: host/mega tokens-per-second cells) and ``replay``
+# ``serve`` (fig8: tokens-per-second cells) and ``replay``
 # (fig9: per-scenario traffic-replay telemetry cells).  Records written
 # before the ``record`` key existed are ``serve`` records; the
 # validator grandfathers them in rather than rewriting history.

@@ -3,27 +3,23 @@
 The paper's figures stop at allocator microbenchmarks; this figure
 closes the loop the ROADMAP's north star asks for — the allocator
 inside a decode hot path.  One cell = the serving engine generating a
-fixed request batch to completion on the reduced qwen2 config, reported
-as tokens/second, for the host-loop decode and the fused mega-step
-(serve/engine.py, DESIGN.md §11) side by side.
+fixed request batch to completion on the reduced qwen2 config through
+the fused decode tick (serve/engine.py, DESIGN.md §11), reported as
+tokens/second.
 
 Methodology mirrors benchmarks/common.py: round 1 includes every jit
 compile (the paper's avg-all column), round 2 replays the identical
 request batch on the warm engine (avg-subsequent — the serving number
 that matters).  CPU caveat as everywhere in this repo: pallas cells run
 in interpret mode, so on CPU the jnp column is the perf signal and the
-pallas column is a correctness/trajectory record; mega-vs-host on the
-SAME backend is meaningful on both platforms.
+pallas column is a correctness/trajectory record.
 
 ``launches_per_tick`` rides along on EVERY cell — the launch count of
-one decode tick read off the jaxprs (benchmarks/common.py delegates to
-the engine, so stats and records always agree).  Mega cells count the
-fused tick program; host cells count the jitted decode plus the
-bulk-grow transaction dispatched around it, so host-vs-mega launch
-records are directly comparable: 1 with ``alloc_backend="pallas"``
-(the bulk grow transaction; attention is the jnp paged path on the
-decode hot loop), 0 with the jnp oracle in mega mode, and constant in
-``max_batch`` either way.
+one fused tick read off its jaxpr (benchmarks/common.py delegates to
+the engine, so stats and records always agree): 1 with
+``alloc_backend="pallas"`` (the bulk grow transaction; attention is
+the jnp paged path on the decode hot loop), 0 with the jnp oracle,
+and constant in ``max_batch`` either way.
 """
 from __future__ import annotations
 
@@ -35,8 +31,7 @@ import jax
 import jax.numpy as jnp
 
 
-def _engine(mega: bool, backend: str, lowering: str, num_shards: int,
-            quick: bool):
+def _engine(backend: str, lowering: str, num_shards: int, quick: bool):
     from repro.configs import get_arch
     from repro.models.model import build_model
     from repro.serve.engine import ServingEngine
@@ -46,7 +41,7 @@ def _engine(mega: bool, backend: str, lowering: str, num_shards: int,
     params = m.init(jax.random.PRNGKey(0))
     eng = ServingEngine(m, params, max_batch=2 if quick else 4,
                         max_seq=96, kv_dtype=jnp.float32,
-                        compute_dtype=jnp.float32, mega_step=mega,
+                        compute_dtype=jnp.float32,
                         alloc_backend=backend, alloc_lowering=lowering,
                         num_shards=num_shards)
     return cfg, eng
@@ -59,11 +54,10 @@ def _requests(cfg, quick: bool):
              8 if quick else 16) for _ in range(n)]
 
 
-def serve_cell(*, mega: bool, backend: str = "jnp",
-               lowering: str = "auto", num_shards: int = 1,
-               quick: bool = False):
+def serve_cell(*, backend: str = "jnp", lowering: str = "auto",
+               num_shards: int = 1, quick: bool = False):
     """One serving-throughput measurement cell (see module doc)."""
-    cfg, eng = _engine(mega, backend, lowering, num_shards, quick)
+    cfg, eng = _engine(backend, lowering, num_shards, quick)
     reqs = _requests(cfg, quick)
 
     def one_round():
@@ -79,8 +73,8 @@ def serve_cell(*, mega: bool, backend: str = "jnp",
     toks1, dt1 = one_round()   # includes every jit compile
     toks2, dt2 = one_round()   # warm replay: the serving number
     row = {
-        "variant": "serve/" + ("mega" if mega else "host"),
-        "mode": "mega" if mega else "host",
+        "variant": "serve/mega",
+        "mode": "mega",
         "backend": backend,
         "lowering": eng.stats["alloc_lowering"],
         "num_shards": num_shards,
@@ -96,22 +90,18 @@ def serve_cell(*, mega: bool, backend: str = "jnp",
 
 def run(quick: bool = False, backend: str = "jnp",
         lowering: str = "auto", num_shards: int = 1):
-    """Figure rows: host-loop vs mega-step on the requested backend."""
-    return [serve_cell(mega=False, backend=backend, lowering=lowering,
-                       num_shards=num_shards, quick=quick),
-            serve_cell(mega=True, backend=backend, lowering=lowering,
+    """Figure row: the fused tick on the requested backend."""
+    return [serve_cell(backend=backend, lowering=lowering,
                        num_shards=num_shards, quick=quick)]
 
 
 def serve_record(quick: bool = False):
-    """The BENCH_serve.json cell block: host/mega on the jnp oracle
+    """The BENCH_serve.json cell block: the tick on the jnp oracle
     (the CPU perf signal) plus a mega/pallas cell for the fused-kernel
     trajectory and its launches-per-tick proof."""
     cells = {
-        "host/jnp": serve_cell(mega=False, backend="jnp", quick=quick),
-        "mega/jnp": serve_cell(mega=True, backend="jnp", quick=quick),
-        "mega/pallas": serve_cell(mega=True, backend="pallas",
-                                  quick=quick),
+        "mega/jnp": serve_cell(backend="jnp", quick=quick),
+        "mega/pallas": serve_cell(backend="pallas", quick=quick),
     }
     return {k: {f: v[f] for f in ("tokens", "tokens_per_s_all",
                                   "tokens_per_s", "alloc_txns",

@@ -10,11 +10,11 @@ hybrid-recurrent and vision-language — with the per-modality page
 policy routing SSM-state and MoE expert-buffer pages through the SAME
 Ouroboros arena as KV pages (paged/kv_cache.modality_page_quota).
 
-Every cell is a *pair*: the identical trace replays on the host decode
-loop and the fused mega-step, token-for-token parity and end-state
-allocator conservation are asserted inside (serve/replay.replay_pair),
-and BOTH modes' telemetry is reported — p50/p99 tick latency, queue
-wait, evictions, and the fragmentation/defrag trajectory.  A benchmark
+Every cell is a *pair*: the identical trace replays on a one-shard and
+a four-shard allocator, token-for-token parity and end-state allocator
+conservation are asserted inside (serve/replay.replay_pair), and BOTH
+engines' telemetry is reported — p50/p99 tick latency, queue wait,
+evictions, and the fragmentation/defrag trajectory.  A benchmark
 row that prints has therefore already passed the engine's hardest
 correctness check.
 
@@ -55,23 +55,22 @@ _CELL_CACHE = {}        # the CSV rows and the --serve-json record
 
 
 def replay_cells(quick: bool = False, backend: str = "jnp",
-                 lowering: str = "auto", num_shards: int = 1):
-    """cell name ``family/arch/scenario/mode`` → telemetry summary
-    (serve/replay.ReplayResult.summary), for every (family, scenario)
-    in the grid, both decode modes.  Parity + conservation asserted
-    per pair before its cells are admitted."""
+                 lowering: str = "auto"):
+    """cell name ``family/arch/scenario/shards<S>`` → telemetry
+    summary (serve/replay.ReplayResult.summary), for every (family,
+    scenario) in the grid, on 1 and 4 shards.  Parity + conservation
+    asserted per pair before its cells are admitted."""
     from repro.serve.replay import (SCENARIOS, engine_factory,
                                     generate_trace, replay_pair)
 
-    key = (quick, backend, lowering, num_shards)
+    key = (quick, backend, lowering)
     if key in _CELL_CACHE:
         return _CELL_CACHE[key]
     fams, scs = _grid(quick)
     cells = {}
     for fi, (family, arch) in enumerate(fams):
         cfg, make = engine_factory(arch)
-        kw = dict(alloc_backend=backend, alloc_lowering=lowering,
-                  num_shards=num_shards)
+        kw = dict(alloc_backend=backend, alloc_lowering=lowering)
         for si, name in enumerate(scs):
             sc = SCENARIOS[name]
             if quick:
@@ -79,13 +78,13 @@ def replay_cells(quick: bool = False, backend: str = "jnp",
                     sc.n_requests, 8))
             trace = generate_trace(sc, seed=101 * fi + si,
                                    vocab_size=cfg.vocab_size)
-            host, mega = replay_pair(make(mega=False, **kw),
-                                     make(mega=True, **kw),
-                                     trace, scenario=name)
-            for r in (host, mega):
+            pair = replay_pair(make(num_shards=1, **kw),
+                               make(num_shards=4, **kw),
+                               trace, scenario=name)
+            for shards, r in zip((1, 4), pair):
                 s = r.summary()
-                s["family"] = family
-                cells[f"{family}/{arch}/{name}/{r.mode}"] = s
+                s["family"], s["num_shards"] = family, shards
+                cells[f"{family}/{arch}/{name}/shards{shards}"] = s
     _CELL_CACHE[key] = cells
     return cells
 
@@ -93,17 +92,17 @@ def replay_cells(quick: bool = False, backend: str = "jnp",
 def run(quick: bool = False, backend: str = "jnp",
         lowering: str = "auto", num_shards: int = 1):
     """Figure rows for benchmarks/run.py's CSV printer: one row per
-    (family, scenario, mode) cell, ``us_per_call`` column = p99 tick
-    latency in ms (the tail is the serving headline, not the mean)."""
+    (family, scenario, shard count) cell, ``us_per_call`` column = p99
+    tick latency in ms (the tail is the serving headline, not the
+    mean).  ``num_shards`` is not used: every pair replays on 1 and 4
+    shards."""
     rows = []
     for name, cell in replay_cells(quick=quick, backend=backend,
-                                   lowering=lowering,
-                                   num_shards=num_shards).items():
+                                   lowering=lowering).items():
         rows.append({
             "variant": f"replay/{name}",
             "backend": backend,
             "lowering": lowering,
-            "num_shards": num_shards,
             "n": cell["requests"],
             "size": cell["tokens"],
             **cell,

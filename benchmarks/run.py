@@ -26,16 +26,16 @@ record now also appends a ``shard_sweep`` — throughput vs num_shards
 scaling alongside the jnp-vs-pallas trajectory.
 
 ``--serve-json PATH`` appends a serving-throughput record (benchmarks/
-fig8_serve.py): tokens/sec for the host-loop and fused mega-step decode
-paths plus the launches-per-tick proof, accumulating in
+fig8_serve.py): tokens/sec for the fused decode tick on the jnp and
+Pallas allocators plus the launches-per-tick proof, accumulating in
 ``BENCH_serve.json`` with the same append-only trajectory format.
 ``fig8_serve`` is not in the default figure list (it builds a model);
 run it with ``--fig fig8_serve`` or via ``--serve-json``.
 
 ``--fig fig9_replay`` runs the traffic-replay figure (benchmarks/
 fig9_replay.py): deterministic Poisson/bursty/abandonment traces
-through the serving engine for one config per model family, host and
-mega decode modes parity-checked per pair; with ``--serve-json`` the
+through the serving engine for one config per model family, one and
+four allocator shards parity-checked per pair; with ``--serve-json`` the
 per-scenario p50/p99 + fragmentation cells append as a ``replay``
 record (the ``serve``-kind fig8 record only appends when fig8 actually
 ran, i.e. with no ``--fig`` filter or with ``--fig fig8_serve``).
@@ -92,7 +92,7 @@ def main(argv=None) -> None:
                          "avg_all/avg_subsequent to PATH")
     ap.add_argument("--serve-json", default=None, metavar="PATH",
                     help="append a serving-throughput record "
-                         "(fig8_serve: host vs mega tokens/sec + "
+                         "(fig8_serve: fused-tick tokens/sec + "
                          "launches-per-tick) to PATH")
     args = ap.parse_args(argv)
     from repro import compile_cache

@@ -15,10 +15,11 @@ calls:
 2. Serving: qwen2-0.5b at its published widths with random parameters
    from ``--seed``, ``max_batch=16``, ``max_seq=4096``, 16 requests of
    256- or 1024-token prompts and 64 new tokens, served twice: the
-   fused decode tick with the Pallas allocator, and the CLI default
-   (host loop, jnp allocator) as the reference.  Token streams must be
-   identical per request, the allocator balanced and failure-free, and
-   the compiled tick must hold a Mosaic kernel (``tpu_custom_call``).
+   fused decode tick with the Pallas allocator, and the same tick on
+   the jnp allocator (the CLI default) as the reference.  Token streams
+   must be identical per request, the allocator balanced and
+   failure-free, and the compiled Pallas tick must hold a Mosaic kernel
+   (``tpu_custom_call``).
 
 Earlier lines print set-up facts (device, bytes, compile and wall
 seconds); they are not measurements.  The last line is one JSON object:
@@ -132,9 +133,8 @@ def _serve(model, params, prompts, **engine_kw):
     facts = {"kv_bytes": sum(x.nbytes for x in jax.tree.leaves(eng.caches)),
              "arena_bytes": sum(x.nbytes for x in
                                 jax.tree.leaves(eng.alloc_state))}
-    if eng.mega_step:
-        facts["launches_per_tick"] = eng.launches_per_tick()
-        eng._build_mega()
+    facts["launches_per_tick"] = eng.launches_per_tick()
+    if eng.stats["alloc_backend"] == "pallas":
         tc = time.perf_counter()
         _mosaic(eng._mega_fn, eng.params, eng.alloc_state, eng.caches,
                 eng.mega_state)
@@ -173,19 +173,18 @@ def serving_phase(seed: int):
     prompts = [rng.integers(2, cfg.vocab_size, int(n)) for n in lens]
 
     results = {}
-    for name, kw in (("pallas-mega", dict(alloc_backend="pallas",
-                                          mega_step=True)),
-                     ("jnp-host", {})):
+    for name, kw in (("pallas", dict(alloc_backend="pallas")),
+                     ("jnp", {})):
         streams, stats, facts = _serve(model, params, prompts, **kw)
         print(f"serve {name}: {facts}", flush=True)
         assert len(streams) == len(prompts), f"{name}: lost requests"
         assert stats["alloc_failures"] == 0, f"{name}: {stats}"
         assert stats["allocs"] == stats["frees"], f"{name}: {stats}"
         results[name] = (streams, stats, facts)
-    assert results["pallas-mega"][2]["launches_per_tick"] == 1
-    if results["pallas-mega"][0] != results["jnp-host"][0]:
-        raise AssertionError("token streams differ between the Pallas "
-                             "mega tick and the jnp host loop")
+    assert results["pallas"][2]["launches_per_tick"] == 1
+    if results["pallas"][0] != results["jnp"][0]:
+        raise AssertionError("token streams differ between the tick on "
+                             "the Pallas allocator and on the jnp one")
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
     print(f"serve: token streams identical for {len(prompts)} requests; "
           f"peak_bytes_in_use {peak}", flush=True)

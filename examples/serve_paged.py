@@ -41,32 +41,25 @@ print(f"\nallocator: {eng.stats['allocs']} pages allocated, "
 assert eng.stats["allocs"] == eng.stats["frees"], "page leak!"
 print("no page leaks — every allocation returned to the heap")
 
-# ---- the fused decode mega-step (DESIGN.md §11) ---------------------------
-# Same engine, same requests, but the whole decode tick — page growth,
-# grant scatter, paged attention, greedy sampling, sequence advance —
-# runs as ONE jitted device-resident function; the host syncs a (B,)
-# finished/failed flag vector per token.  Token streams match the
-# host loop exactly.
+# ---- the fused decode tick (DESIGN.md §11) --------------------------------
+# Every decode step above ran as ONE jitted device-resident function —
+# page growth, grant scatter, paged attention, greedy sampling,
+# sequence advance — and the host synced a (B,) finished/failed flag
+# vector per token.  Its kernel-launch count, read off the jaxpr:
+print(f"launches per fused tick = {eng.launches_per_tick()} "
+      f"(constant in max_batch)")
+
 import jax.numpy as jnp
 
-mega = ServingEngine(model, params, max_batch=4, max_seq=256,
-                     kv_dtype=jnp.float32, compute_dtype=jnp.float32,
-                     mega_step=True)
+# the uninterrupted reference streams for the crash-safe run below
 ref = ServingEngine(model, params, max_batch=4, max_seq=256,
                     kv_dtype=jnp.float32, compute_dtype=jnp.float32)
 rng = np.random.default_rng(1)
 prompts = [(rng.integers(2, cfg.vocab_size, int(rng.integers(4, 40))),
             int(rng.integers(4, 12))) for _ in range(6)]
-for eng2 in (ref, mega):
-    for p, mx in prompts:
-        eng2.submit(p, max_new_tokens=mx)
+for p, mx in prompts:
+    ref.submit(p, max_new_tokens=mx)
 want = {r.uid: r.out_tokens for r in ref.run_until_done()}
-got = {r.uid: r.out_tokens for r in mega.run_until_done()}
-assert want == got, "mega-step diverged from the host loop!"
-print(f"\nmega-step: {sum(len(t) for t in got.values())} tokens, "
-      f"token-for-token identical to the host loop; "
-      f"launches per fused tick = {mega.launches_per_tick()} "
-      f"(constant in max_batch)")
 
 # ---- crash-safe serving (DESIGN.md §12) -----------------------------------
 # Snapshot the COMPLETE serving state mid-stream — arena word image +
@@ -79,7 +72,6 @@ import tempfile
 snapdir = tempfile.mkdtemp(prefix="serve_snap_")
 eng = ServingEngine(model, params, max_batch=4, max_seq=256,
                     kv_dtype=jnp.float32, compute_dtype=jnp.float32)
-rng = np.random.default_rng(1)
 for p, mx in prompts:
     eng.submit(p, max_new_tokens=mx)
 early = []

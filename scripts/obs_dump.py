@@ -14,7 +14,7 @@ replay and dump everything from it.
 
     # stand up a tiny engine, replay a scenario, dump everything
     PYTHONPATH=src python scripts/obs_dump.py --live \
-        [--arch qwen2-0.5b] [--scenario steady] [--mega]
+        [--arch qwen2-0.5b] [--scenario steady]
 
 Every path validates before printing (obs.metrics.validate_exposition
 / obs.trace.validate_trace), so this doubles as the CI artifact
@@ -113,19 +113,18 @@ def dump_snapshot(directory: str, step=None) -> None:
             print(f"  {name:<12} {ctl[:, a:b].squeeze().tolist()}")
 
 
-def live(arch: str, scenario: str, mega: bool) -> None:
+def live(arch: str, scenario: str) -> None:
     from repro.obs.metrics import validate_exposition
     from repro.obs.trace import Tracer, validate_trace
     from repro.serve.replay import (SCENARIOS, engine_factory,
                                     generate_trace, replay)
 
     cfg, make = engine_factory(arch)
-    eng = make(mega=mega, tracer=Tracer())
+    eng = make(tracer=Tracer())
     trace = generate_trace(SCENARIOS[scenario], seed=0,
                            vocab_size=cfg.vocab_size)
     result = replay(eng, trace, scenario=scenario)
-    print(f"replay summary ({arch}/{scenario}/"
-          f"{'mega' if mega else 'host'}):")
+    print(f"replay summary ({arch}/{scenario}):")
     print(json.dumps(result.summary(), indent=2, sort_keys=True))
     print("\nin-kernel telemetry (drained ctl words):")
     for k, v in eng.drain_telemetry().items():
@@ -160,7 +159,6 @@ def main(argv=None):
                          "dump metrics + telemetry + trace from it")
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--scenario", default="steady")
-    ap.add_argument("--mega", action="store_true")
     args = ap.parse_args(argv)
     if not (args.metrics or args.trace or args.snapshot or args.live):
         ap.error("nothing to do: pass --metrics, --trace, "
@@ -172,7 +170,7 @@ def main(argv=None):
     if args.snapshot:
         dump_snapshot(args.snapshot, step=args.step)
     if args.live:
-        live(args.arch, args.scenario, args.mega)
+        live(args.arch, args.scenario)
     return 0
 
 

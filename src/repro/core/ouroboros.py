@@ -213,10 +213,10 @@ class Ouroboros:
         many new ``size_bytes`` regions each slot must be granted) —
         no host slot list, so the whole call is jit-traceable inside a
         fused decode tick.  Lane routing is
-        :func:`transactions.grow_lanes` (slot-major, the same order
-        the host loop issued); the bulk grant itself is the ordinary
-        single transaction — still ONE ``pallas_call`` under
-        ``backend="pallas"`` with either lowering, sharded or not.
+        :func:`transactions.grow_lanes` (slot-major); the bulk grant
+        itself is the ordinary single transaction — still ONE
+        ``pallas_call`` under ``backend="pallas"`` with either
+        lowering, sharded or not.
         ``home`` (sharded only) gives per-SLOT home shards ``(B,)``;
         ``None`` homes slot ``b`` on ``b % num_shards``, the KV
         cache's routing.

@@ -124,7 +124,7 @@ def grow_lanes(need, lanes: int):
     lengths — no host slot list exists.  This routine turns that vector
     into the lane layout every alloc transaction consumes: lane ``j``
     carries slot ``slot[j]``'s ``rank[j]``-th new page, slots packed in
-    slot order (the same order the engine's host loop used), and
+    slot order, and
     ``mask[j]`` marks live lanes.  Pure jnp, shared verbatim by both
     backends and both Pallas lowerings, so lane routing can never
     diverge between them.  Lanes beyond ``sum(need)`` are masked;

@@ -49,11 +49,6 @@ def main(argv=None):
                          "independent arenas with overflow routing "
                          "(core/shards.py, DESIGN.md §9); per-shard "
                          "occupancy lands in the engine stats")
-    ap.add_argument("--mega", action="store_true",
-                    help="fused decode mega-step: grow + forward + "
-                         "sample as ONE jitted tick with device-"
-                         "resident slot state (DESIGN.md §11); "
-                         "launches_per_tick lands in the engine stats")
     ap.add_argument("--defrag-threshold", type=float, default=None,
                     metavar="RATIO",
                     help="fire a proactive defrag wave when frag_ratio "
@@ -114,12 +109,10 @@ def main(argv=None):
                         alloc_backend=args.alloc_backend,
                         alloc_lowering=args.alloc_lowering,
                         num_shards=args.num_shards,
-                        mega_step=args.mega,
                         max_new_cap=max(args.max_new, 16),
                         defrag_threshold=args.defrag_threshold,
                         tracer=tracer)
-    if args.mega:
-        eng.launches_per_tick()  # record into stats before serving
+    eng.launches_per_tick()  # record into stats before serving
 
     def write_metrics():
         if not args.metrics_file:

@@ -9,28 +9,20 @@ completion.  Page churn across requests of different lengths is exactly
 the fragmentation workload Ouroboros was built for; the default
 ``vl_chunk`` variant claims heap chunks lazily and reuses freed pages.
 
-Two decode loops share the admission/retirement machinery:
-
-``mega_step=False`` (host loop)  one jitted decode per tick with host
-    glue around it: the host computes page need per slot, issues the
-    bulk grow, scatters the grants, and reads back this tick's token
-    ids (the decode jit argmaxes on device, so only ``(B,)`` int32 —
-    never ``(B, vocab)`` logits — crosses the boundary).
-
-``mega_step=True`` (fused decode mega-step, DESIGN.md §11)  ONE jitted
-    function per tick that (a) computes per-slot page need from
-    device-resident ``lens``/``active`` state, (b) runs the bulk grow
-    as the existing single-``pallas_call`` arena transaction
-    (``Ouroboros.grow``), (c) scatters granted pages into the device
-    page table straight from the grant words
-    (``kv_cache.scatter_grant_words`` — no host-materialized table),
-    (d) runs the model forward with paged attention, and (e) greedily
-    samples + advances ``seq_lens``/last-token on device.  A decode
-    tick is a fixed small number of launches regardless of
-    ``max_batch``; the host syncs one tiny ``(B,)`` finished/failed
-    flag vector per tick and touches only control-plane decisions
-    (admission, retirement, and the defrag-retry on allocation
-    failure, which stays host-side).
+The decode loop is the fused tick (DESIGN.md §11): ONE jitted function
+per tick that (a) computes per-slot page need from device-resident
+``lens``/``active`` state, (b) runs the bulk grow as the single-
+``pallas_call`` arena transaction (``Ouroboros.grow``), (c) scatters
+granted pages into the device page table straight from the grant
+words (``kv_cache.scatter_grant_words`` — no host-materialized table),
+(d) runs the model forward with paged attention, and (e) greedily
+samples + advances ``seq_lens``/last-token on device.  A decode tick
+is a fixed small number of launches regardless of ``max_batch``; the
+host syncs one tiny ``(B,)`` finished/failed flag vector per tick and
+touches only control-plane decisions (admission, retirement, and the
+defrag-retry on allocation failure, which stays host-side).  The
+device page-table row is the one holder of a slot's KV page ids, from
+admission to retirement.
 
 Single-host reference implementation (the dry-run serve_step covers the
 multi-pod path); everything device-side is jitted.
@@ -62,7 +54,9 @@ class Request:
     done: bool = False
 
 
-SNAPSHOT_VERSION = 1
+# 2: a slot's KV page ids live in the device page table alone (version
+# 1 snapshots also carried host page lists, and the host decode loop)
+SNAPSHOT_VERSION = 2
 
 
 def _req_to_json(r: Request) -> dict:
@@ -194,7 +188,7 @@ class ServingEngine:
                  sample: str = "greedy", alloc_backend: str = "jnp",
                  alloc_lowering: str = "auto", num_shards: int = 1,
                  rebalance_threshold: Optional[int] = None,
-                 mega_step: bool = False, max_new_cap: int = 256,
+                 mega_step: bool = True, max_new_cap: int = 256,
                  defrag_threshold: Optional[float] = None,
                  defrag_check_interval: int = 1,
                  tracer: Optional[obs_trace.Tracer] = None):
@@ -235,6 +229,11 @@ class ServingEngine:
                 f"defrag_check_interval must be a positive int (steps "
                 f"between frag_ratio checks), got "
                 f"{defrag_check_interval!r}")
+        if not mega_step:
+            raise ValueError(
+                "mega_step=False asks for the host decode loop, which "
+                "was removed: the fused tick is the engine's only "
+                "decode loop")
         if not isinstance(max_new_cap, int) or max_new_cap < 1:
             raise ValueError(
                 f"max_new_cap must be a positive int, got "
@@ -247,7 +246,6 @@ class ServingEngine:
         self.num_pages = num_pages or max_batch * self.pps
         assert sample == "greedy"
         self.compute_dtype = compute_dtype
-        self.mega_step = bool(mega_step)
         self.max_new_cap = max_new_cap
         self.defrag_threshold = (None if defrag_threshold is None
                                  else float(defrag_threshold))
@@ -286,7 +284,6 @@ class ServingEngine:
         self._shard_words = (self.ouro.layout.shard_words
                              if num_shards > 1
                              else self.ouro.cfg.total_words)
-        self._shard_pages = np.zeros(num_shards, np.int64)  # live/shard
 
         # the page array is sized by the heap's PHYSICAL page space:
         # segment-occupied chunks make granted ids sparse in it.
@@ -303,9 +300,9 @@ class ServingEngine:
                 cross_v=jnp.zeros(xkv, compute_dtype),
                 enc_valid=jnp.zeros(max_batch, jnp.int32))
         self.slot_req: List[Optional[Request]] = [None] * max_batch
-        self.slot_pages: List[List[int]] = [[] for _ in range(max_batch)]
-        # the state pages each admitted slot holds — host-side in BOTH
-        # decode modes; the device's state-page table is their mirror
+        # the state pages each admitted slot holds; the device's
+        # state-page table is their mirror (KV page ids live in the
+        # device page table alone)
         self.slot_aux: List[List[int]] = [[] for _ in range(max_batch)]
         # a slot's state and KV pages are granted and freed in one
         # transaction of a fixed lane count, so every admission and
@@ -319,13 +316,13 @@ class ServingEngine:
         # eviction victim under exhaustion — it loses the least work)
         self._admit_ord = np.zeros(max_batch, np.int64)
         self._admit_counter = 0
-        # both entry points argmax ON DEVICE: only (B,) int32 token ids
-        # ever cross the host boundary, never (B, vocab) logits.
-        # named functions: the programs show up in a profile as
-        # ``jit_prefill`` and ``jit_decode``.  The prefill computes the
-        # admitted row alone, through a view holding the shared heap
-        # and the slot's page-table row, and writes it back at the
-        # traced ``slot`` (one program per prompt length, any slot)
+        # the prefill argmaxes ON DEVICE: only the first token id
+        # crosses the host boundary, never logits.  A named function:
+        # the program shows up in a profile as ``jit_prefill``.  It
+        # computes the admitted row alone, through a view holding the
+        # shared heap and the slot's page-table row, and writes it back
+        # at the traced ``slot`` (one program per prompt length, any
+        # slot)
         def prefill(p, b, c, slot):
             kv = c.self_kv if cfg.is_encdec else c.kv
             view = c
@@ -344,30 +341,24 @@ class ServingEngine:
                 p, b, view, remat_policy="none", dtype=compute_dtype))
             return tok, put_row(cfg, row, c, slot)
 
-        def decode(p, t, c):
-            return _tokens_of(model.decode_step(p, t, c,
-                                                dtype=compute_dtype))
-
         self._prefill = jax.jit(prefill, donate_argnums=(2,))
-        self._decode = jax.jit(decode)
 
-        # --- device-resident slot state (mega-step mode) -----------------
-        if self.mega_step:
-            B = max_batch
-            self.mega_state = MegaState(
-                last_tok=jnp.zeros(B, jnp.int32),
-                lens=jnp.zeros(B, jnp.int32),
-                page_counts=jnp.zeros(B, jnp.int32),
-                active=jnp.zeros(B, bool),
-                budget=jnp.zeros(B, jnp.int32),
-                eos=jnp.full(B, -1, jnp.int32),
-                out_buf=jnp.zeros((B, max_new_cap), jnp.int32),
-                n_out=jnp.zeros(B, jnp.int32))
-            # host mirrors, advanced from the per-tick flag vector —
-            # never synced from device mid-flight
-            self._pages_host = np.zeros(B, np.int64)
-            self._nout_host = np.zeros(B, np.int64)
-            self._fail_streak = np.zeros(B, np.int64)
+        # --- device-resident slot state: the tick's carry ----------------
+        B = max_batch
+        self.mega_state = MegaState(
+            last_tok=jnp.zeros(B, jnp.int32),
+            lens=jnp.zeros(B, jnp.int32),
+            page_counts=jnp.zeros(B, jnp.int32),
+            active=jnp.zeros(B, bool),
+            budget=jnp.zeros(B, jnp.int32),
+            eos=jnp.full(B, -1, jnp.int32),
+            out_buf=jnp.zeros((B, max_new_cap), jnp.int32),
+            n_out=jnp.zeros(B, jnp.int32))
+        # host mirrors, advanced from the per-tick flag vector — never
+        # synced from device mid-flight
+        self._pages_host = np.zeros(B, np.int64)
+        self._nout_host = np.zeros(B, np.int64)
+        self._fail_streak = np.zeros(B, np.int64)
         self._mega_fn = None
         self._mega = None
 
@@ -384,9 +375,10 @@ class ServingEngine:
                       "alloc_lowering": (resolve_lowering(alloc_lowering)
                                          if alloc_backend == "pallas"
                                          else "none"),
-                      # sharding observability: live pages per shard and
-                      # how many grants landed off their home shard
-                      # (the overflow walk at work)
+                      # sharding observability: live pages per shard
+                      # (shard_pages_live(), filled by
+                      # refresh_frag_stats) and how many grants landed
+                      # off their home shard (the overflow walk at work)
                       "num_shards": num_shards,
                       "shard_pages_live": [0] * num_shards,
                       "alloc_overflows": 0,
@@ -411,7 +403,6 @@ class ServingEngine:
                       "auto_defrag_waves": 0,
                       "pages_migrated": 0,
                       # decode-loop observability (DESIGN.md §11)
-                      "mega_step": self.mega_step,
                       "launches_per_tick": None,
                       # jit first-call events observed by step(): how
                       # many of this process's ticks paid a compile
@@ -422,7 +413,7 @@ class ServingEngine:
                       # the prefill programs computed, padding included
                       "prefill_rows": 0,
                       "prefill_tokens": 0}
-        self.refresh_frag_stats()
+        self._read_frag_stats()  # no slot holds a page yet
 
     def _compile_count(self) -> int:
         """Total jit-cache entries across the jitted callables a tick
@@ -433,7 +424,7 @@ class ServingEngine:
         process can mark one of our ticks "compile" — a conservative
         misclassification: it only withholds that tick from the steady
         percentiles.)"""
-        fns = [self._prefill, self._decode, self._mega]
+        fns = [self._prefill, self._mega]
         fns += [getattr(self.ouro, nm, None) for nm in
                 ("_alloc", "_free", "_alloc_sharded", "_free_sharded",
                  "_alloc_pinned", "_free_pinned")]
@@ -442,9 +433,9 @@ class ServingEngine:
 
     # ---- request lifecycle -------------------------------------------------
     def submit(self, prompt, max_new_tokens=32, eos_id=None) -> int:
-        if self.mega_step and max_new_tokens > self.max_new_cap:
+        if max_new_tokens > self.max_new_cap:
             raise ValueError(
-                f"max_new_tokens={max_new_tokens} exceeds the mega-step "
+                f"max_new_tokens={max_new_tokens} exceeds the tick's "
                 f"device token buffer (max_new_cap={self.max_new_cap}); "
                 f"raise max_new_cap at engine construction")
         self._uid += 1
@@ -490,7 +481,7 @@ class ServingEngine:
         ok = offs >= 0
         self.stats["allocs"] += int(ok.sum())
         self.stats["alloc_failures"] += int((~ok).sum())
-        shard = self._note_shard_pages(offs[ok], +1)
+        shard = offs[ok] // self._shard_words
         self.stats["alloc_overflows"] += int((shard != home[:n_pages][ok])
                                              .sum())
         return [int(o) // self.wpp if o >= 0 else -1 for o in offs]
@@ -509,36 +500,20 @@ class ServingEngine:
         self.defrag()
         return self._bulk_alloc(homes, lanes)
 
-    def _note_shard_pages(self, offs, delta: int):
-        """Update per-shard live-page occupancy for granted/freed word
-        offsets; returns their owning shards.  In mega-step mode the
-        incremental count is skipped (mega grants never surface their
-        offsets to the host) — occupancy is recomputed from the device
-        page table instead (:meth:`_sync_shard_pages_from_table`)."""
-        shard = offs // self._shard_words
-        if not self.mega_step:
-            np.add.at(self._shard_pages, shard, delta)
-            self.stats["shard_pages_live"] = [int(x) for x in
-                                              self._shard_pages]
-        return shard
-
-    def _sync_shard_pages_from_table(self):
-        """Recompute per-shard live-page occupancy from the device page
-        table (mega-step mode: the table is the only place the granted
-        ids live).  One small (B, P) device→host read — called on
-        demand (rebalance checks, stat refreshes), never per tick."""
+    def shard_pages_live(self) -> List[int]:
+        """Live pages per shard, derived from where the page ids live:
+        the device page table (KV pages) and ``slot_aux`` (state
+        pages).  One small (B, P) device→host read — called on demand
+        (rebalance checks, stat refreshes), never per tick."""
+        pages = [p for aux in self.slot_aux for p in aux]
         kv = self._kv()
-        self._shard_pages[:] = 0
         if kv is not None:
             pt = np.asarray(kv.page_table)
-            pages = pt[pt >= 0]
-            shard = pages * self.wpp // self._shard_words
-            np.add.at(self._shard_pages, shard, 1)
-        for aux in self.slot_aux:  # aux pages never enter the table
-            for p in aux:
-                self._shard_pages[p * self.wpp // self._shard_words] += 1
-        self.stats["shard_pages_live"] = [int(x) for x in
-                                          self._shard_pages]
+            pages = np.concatenate([pt[pt >= 0], pages])
+        live = np.zeros(self.num_shards, np.int64)
+        np.add.at(live, np.asarray(pages, np.int64) * self.wpp
+                  // self._shard_words, 1)
+        return [int(x) for x in live]
 
     def _bulk_free(self, pages: List[int], count_stats: bool = True,
                    lanes: Optional[int] = None):
@@ -554,18 +529,17 @@ class ServingEngine:
                 self.alloc_state, jnp.asarray(offs), sizes, mask)
         if count_stats:
             self.stats["frees"] += len(pages)
-        self._note_shard_pages(offs[offs >= 0], -1)
 
     def _grant_slot(self, slot: int, upto_tokens: int) -> bool:
         """Admission's ONE allocator transaction: the slot's state
         pages (``aux_pages``: a layer-list model's recurrent state,
         DESIGN.md §13) and the page-table pages covering
-        ``upto_tokens`` positions.  On failure the partial grants go
-        back, so allocs and frees stay balanced."""
+        ``upto_tokens`` positions (an admitted slot holds none yet).
+        On failure the partial grants go back, so allocs and frees
+        stay balanced."""
         need = 0
         if self._kv() is not None:  # attention-free: O(1) state only
-            need = max(-(-upto_tokens // self.page)
-                       - len(self.slot_pages[slot]), 0)
+            need = -(-upto_tokens // self.page)
         n = self.aux_pages + need
         if n == 0:
             return True
@@ -583,7 +557,7 @@ class ServingEngine:
             self._set_slot_aux(slot, got[:self.aux_pages])
             self.stats["state_pages_granted"] += self.aux_pages
         if need:
-            self._map_granted([slot] * need, got[self.aux_pages:])
+            self._map_granted(slot, got[self.aux_pages:])
         return True
 
     def _set_slot_aux(self, slot: int, pages: List[int]):
@@ -608,20 +582,14 @@ class ServingEngine:
         self.stats["state_pages_freed"] += len(aux)
         self._set_slot_aux(slot, [])
 
-    def _map_granted(self, slots: List[int], pages: List[int]):
-        """Extend the slots' page tables with freshly granted page ids
-        (one scatter covers every growing slot)."""
+    def _map_granted(self, slot: int, pages: List[int]):
+        """Write freshly granted page ids into columns ``0..n-1`` of
+        the slot's page-table row (one scatter)."""
         kv = self._kv()
-        cols = []
-        grown: Dict[int, int] = {}
-        for s in slots:
-            cols.append(len(self.slot_pages[s]) + grown.get(s, 0))
-            grown[s] = grown.get(s, 0) + 1
-        pt = kv.page_table.at[jnp.asarray(slots, jnp.int32),
-                              jnp.asarray(cols, jnp.int32)].set(
+        n = len(pages)
+        pt = kv.page_table.at[jnp.asarray([slot] * n, jnp.int32),
+                              jnp.asarray(list(range(n)), jnp.int32)].set(
             jnp.asarray(pages, jnp.int32))
-        for s, g in zip(slots, pages):
-            self.slot_pages[s].append(g)
         self._set_kv(kv._replace(page_table=pt))
 
     # ---- defragmentation (core/defrag.py, DESIGN.md §10) -------------------
@@ -662,10 +630,8 @@ class ServingEngine:
         diverge beyond ``rebalance_threshold`` (pages, max − min)."""
         if self.num_shards == 1 or self.rebalance_threshold is None:
             return
-        if self.mega_step:
-            self._sync_shard_pages_from_table()
-        live = self._shard_pages
-        if int(live.max() - live.min()) <= self.rebalance_threshold:
+        live = self.shard_pages_live()
+        if max(live) - min(live) <= self.rebalance_threshold:
             return
         with self.tracer.span("rebalance_wave"):
             self.alloc_state, fwd = self.ouro.rebalance(self.alloc_state)
@@ -677,11 +643,8 @@ class ServingEngine:
     def _apply_forwarding(self, fwd) -> int:
         """Remap every page reference the engine holds through a defrag
         forwarding table: KV page heaps move rows old→new, page tables
-        and ``slot_pages`` rewrite ids, per-shard occupancy follows
-        pages that changed shards.  Returns pages migrated.  (In
-        mega-step mode the device page table is the only id holder —
-        ``slot_pages`` are empty mid-flight — so the KV remap alone
-        covers everything.)"""
+        rewrite ids, and ``slot_aux`` (with the state table) follows.
+        Returns pages migrated."""
         if not (np.asarray(fwd.src) >= 0).any():
             return 0
         max_span = self.ouro.cfg.words_per_chunk // self.wpp
@@ -695,30 +658,23 @@ class ServingEngine:
                   KV.forwarding_page_map(fwd, self.wpp, max_span))
         mapping: Dict[int, int] = {int(s): int(d)
                                    for s, d in zip(sp, dp) if s >= 0}
-        total = len(mapping)
-        for pages in self.slot_pages + self.slot_aux:
-            for i, p in enumerate(pages):
-                if p in mapping:
-                    old_sh = p * self.wpp // self._shard_words
-                    new_sh = mapping[p] * self.wpp // self._shard_words
-                    if old_sh != new_sh:
-                        self._shard_pages[old_sh] -= 1
-                        self._shard_pages[new_sh] += 1
-                    pages[i] = mapping[p]
-        if not self.mega_step:
-            self.stats["shard_pages_live"] = [int(x) for x in
-                                              self._shard_pages]
         if self.aux_pages:
             # the state pages moved with their heap rows above; their
-            # table follows the host lists
+            # host lists and table follow
             for slot, pages in enumerate(self.slot_aux):
-                self._set_slot_aux(slot, pages)
-        return total
+                self._set_slot_aux(slot, [mapping.get(p, p)
+                                          for p in pages])
+        return len(mapping)
 
     def refresh_frag_stats(self):
         """Recompute fragmentation observability into ``stats``:
         ``free_words``, ``largest_free_extent``, and ``frag_ratio``
-        (1 − largest/total) — per shard when ``num_shards > 1``."""
+        (1 − largest/total) — per shard when ``num_shards > 1`` — and
+        ``shard_pages_live`` (:meth:`shard_pages_live`)."""
+        self.stats["shard_pages_live"] = self.shard_pages_live()
+        return self._read_frag_stats()
+
+    def _read_frag_stats(self):
         fs = self.ouro.frag_stats(self.alloc_state)
         if self.num_shards > 1:
             self.stats["free_words"] = [
@@ -869,9 +825,8 @@ class ServingEngine:
             self.slot_len[slot] = lp + 1
             self._admit_counter += 1
             self._admit_ord[slot] = self._admit_counter
-            if self.mega_step:
-                with self.tracer.span("slot_push", uid=req.uid, slot=slot):
-                    self._mega_admit(slot, req, first)
+            with self.tracer.span("slot_push", uid=req.uid, slot=slot):
+                self._mega_admit(slot, req, first)
 
     # ---- fused decode mega-step (DESIGN.md §11) ----------------------------
 
@@ -879,12 +834,11 @@ class ServingEngine:
         """Push an admitted slot's control state to the device arrays.
 
         Page ids granted at admission already live in the device page
-        table; hand ownership over entirely (``slot_pages`` is cleared
-        — from here on the table row is the only id holder, pulled
-        back once at retirement)."""
-        npages = len(self.slot_pages[slot])
+        table (the only id holder, pulled back once at retirement):
+        ``_grant_slot`` mapped the pages covering ``slot_len``."""
+        npages = (-(-int(self.slot_len[slot]) // self.page)
+                  if self._kv() is not None else 0)
         self._pages_host[slot] = npages
-        self.slot_pages[slot] = []
         self._nout_host[slot] = 1
         self._fail_streak[slot] = 0
         ms = self.mega_state
@@ -988,44 +942,17 @@ class ServingEngine:
     def launches_per_tick(self) -> int:
         """``pallas_call`` launch count of ONE decode tick, read off
         the jaxprs (kernels/ops.count_pallas_calls — the same counter
-        as the per-transaction and per-wave proofs).  Mega-step mode
-        counts the single fused tick program; host mode counts the
-        jitted decode plus the bulk-grow transaction issued around it
-        (the same two programs ``_step_host`` dispatches).  Constant
-        in ``max_batch`` by construction either way.  Recorded into
-        ``stats["launches_per_tick"]``; benchmarks/
+        as the per-transaction and per-wave proofs) of the single fused
+        tick program — constant in ``max_batch`` by construction.
+        Recorded into ``stats["launches_per_tick"]``; benchmarks/
         common.launches_per_tick delegates here so fig8 records and
         engine stats can never disagree."""
         from repro.kernels.ops import count_pallas_calls
-        if self.mega_step:
-            if self._mega is None:
-                self._build_mega()
-            jx = jax.make_jaxpr(self._mega_fn)(
-                self.params, self.alloc_state, self.caches,
-                self.mega_state)
-            n = count_pallas_calls(jx)
-        else:
-            toks = jnp.zeros((self.max_batch, 1), jnp.int32)
-            jx = jax.make_jaxpr(
-                lambda p, t, c: self.model.decode_step(
-                    p, t, c, dtype=self.compute_dtype))(
-                self.params, toks, self.caches)
-            n = count_pallas_calls(jx)
-            # the per-tick bulk grow (_bulk_alloc lane shapes)
-            lanes = self.max_batch * 2
-            sizes = jnp.full(lanes, self.page_bytes, jnp.int32)
-            mask = jnp.arange(lanes) < 1
-            if self.num_shards > 1:
-                jx2 = jax.make_jaxpr(
-                    lambda st, sz, m, h: self.ouro.alloc(
-                        st, sz, m, shard_hint=h))(
-                    self.alloc_state, sizes, mask,
-                    jnp.zeros(lanes, jnp.int32))
-            else:
-                jx2 = jax.make_jaxpr(
-                    lambda st, sz, m: self.ouro.alloc(st, sz, m))(
-                    self.alloc_state, sizes, mask)
-            n += count_pallas_calls(jx2)
+        if self._mega is None:
+            self._build_mega()
+        jx = jax.make_jaxpr(self._mega_fn)(
+            self.params, self.alloc_state, self.caches, self.mega_state)
+        n = count_pallas_calls(jx)
         self.stats["launches_per_tick"] = n
         return n
 
@@ -1085,7 +1012,7 @@ class ServingEngine:
         return finished
 
     def _recover_failed(self, fail, fin, l_offs, l_slot, l_mask):
-        """Alloc-failure path (host-side, as in the host loop): pull
+        """Alloc-failure path (host-side): pull
         the lane arrays (failure ticks only), return the failed slots'
         partial grants to the heap, run ONE defrag wave, and let the
         next tick retry.  Two consecutive failed retries mean defrag
@@ -1177,28 +1104,24 @@ class ServingEngine:
         Returns the slot's request."""
         req = self.slot_req[slot]
         kv = self._kv()
-        if self.mega_step:
-            # mid-flight the device page-table row is the only page-id
-            # holder (slot_pages was cleared at _mega_admit)
-            row = (np.asarray(kv.page_table[slot]) if kv is not None
-                   else np.zeros(0, np.int32))
-            self._free_slot_pages(slot, [int(p) for p in row[row >= 0]])
-            ms = self.mega_state
-            self.mega_state = MegaState(
-                last_tok=ms.last_tok.at[slot].set(0),
-                lens=ms.lens.at[slot].set(0),
-                page_counts=ms.page_counts.at[slot].set(0),
-                active=ms.active.at[slot].set(False),
-                budget=ms.budget.at[slot].set(0),
-                eos=ms.eos.at[slot].set(-1),
-                out_buf=ms.out_buf,
-                n_out=ms.n_out.at[slot].set(0))
-            self._pages_host[slot] = 0
-            self._nout_host[slot] = 0
-            self._fail_streak[slot] = 0
-        else:
-            self._free_slot_pages(slot, self.slot_pages[slot])
-            self.slot_pages[slot] = []
+        # mid-flight the device page-table row is the only page-id
+        # holder
+        row = (np.asarray(kv.page_table[slot]) if kv is not None
+               else np.zeros(0, np.int32))
+        self._free_slot_pages(slot, [int(p) for p in row[row >= 0]])
+        ms = self.mega_state
+        self.mega_state = MegaState(
+            last_tok=ms.last_tok.at[slot].set(0),
+            lens=ms.lens.at[slot].set(0),
+            page_counts=ms.page_counts.at[slot].set(0),
+            active=ms.active.at[slot].set(False),
+            budget=ms.budget.at[slot].set(0),
+            eos=ms.eos.at[slot].set(-1),
+            out_buf=ms.out_buf,
+            n_out=ms.n_out.at[slot].set(0))
+        self._pages_host[slot] = 0
+        self._nout_host[slot] = 0
+        self._fail_streak[slot] = 0
         kv = self._kv()
         if kv is not None:
             self._set_kv(kv._replace(
@@ -1256,74 +1179,10 @@ class ServingEngine:
         return False
 
     # ---- main loop -----------------------------------------------------------
-    def _grow_active(self, active: List[int]) -> List[int]:
-        """Decode-step page growth for ALL active slots as ONE bulk
-        alloc transaction (previously ``_map_pages`` ran per slot — up
-        to ``max_batch`` kernel launches per decode step).  When a
-        defragmentation wave fails to reclaim enough pages, evicts the
-        youngest slot (freeing its pages, requeueing its request) and
-        retries — never raises.  Returns the slots still active."""
-        if self._kv() is None:  # attention-free family: O(1) state
-            return list(active)
-        active = list(active)
-        while True:
-            slots = []
-            for s in active:
-                need = -(-(int(self.slot_len[s]) + 1) // self.page)
-                slots.extend([s] * (need - len(self.slot_pages[s])))
-            if not slots:
-                return active
-            got = self._alloc_pages([s % self.num_shards for s in slots])
-            if all(g >= 0 for g in got):
-                self._map_granted(slots, got)
-                return active
-            self._bulk_free([g for g in got if g >= 0])
-            victim = self._youngest_active()
-            if victim is None:
-                return active
-            self._evict_slot(victim)
-            if victim in active:
-                active.remove(victim)
-
-    def _step_host(self) -> List[Request]:
-        """Host-loop decode tick: grow pages (host computes need),
-        decode one token for all active slots (token ids — not logits
-        — cross the device boundary), retire finished requests."""
-        active = [s for s in range(self.max_batch)
-                  if self.slot_req[s] is not None]
-        finished = []
-        if active:
-            # growth may evict slots (exhaustion degradation) — decode
-            # only the survivors
-            active = self._grow_active(active)
-        if active:
-            toks = np.zeros((self.max_batch, 1), np.int32)
-            for s in active:
-                toks[s, 0] = self.slot_req[s].out_tokens[-1]
-            with self.tracer.span("decode", slots=len(active)):
-                tok_ids, self.caches = self._decode(
-                    self.params, jnp.asarray(toks), self.caches)
-            with self.tracer.span("flag_sync"):
-                nxt = np.asarray(tok_ids)
-            for s in active:
-                req = self.slot_req[s]
-                req.out_tokens.append(int(nxt[s]))
-                self.slot_len[s] += 1
-                ln = len(req.out_tokens)
-                if (ln >= req.max_new_tokens
-                        or (req.eos_id is not None
-                            and int(nxt[s]) == req.eos_id)):
-                    req.done = True
-                    finished.append(req)
-                    with self.tracer.span("retire", uid=req.uid, slot=s,
-                                          tokens=ln):
-                        self._release(s)
-        return finished
-
     def step(self) -> List[Request]:
-        """Admit, decode one token for all active slots (fused
-        mega-step or host loop), retire finished requests.  Returns
-        requests finished this step.
+        """Admit, decode one token for all active slots (the fused
+        tick), retire finished requests.  Returns requests finished
+        this step.
 
         The whole step is one ``tick`` trace span whose category —
         ``"compile"`` when any engine jit traced this step,
@@ -1335,8 +1194,7 @@ class ServingEngine:
         with self.tracer.span("admission"):
             self._admit()
         self._maybe_rebalance()
-        finished = (self._step_mega() if self.mega_step
-                    else self._step_host())
+        finished = self._step_mega()
         self.stats["steps"] += 1
         self._maybe_auto_defrag()
         grew = self._compile_count() - pre
@@ -1346,18 +1204,6 @@ class ServingEngine:
             "tick", ts, cat="compile" if grew > 0 else "steady",
             step=self.stats["steps"], finished=len(finished))
         return finished
-
-    def _release(self, slot: int):
-        self._free_slot_pages(slot, self.slot_pages[slot])
-        self.slot_pages[slot] = []
-        kv = self._kv()
-        if kv is not None:
-            pt = kv.page_table.at[slot].set(-1)
-            sl = kv.seq_lens.at[slot].set(0)
-            self._set_kv(kv._replace(page_table=pt, seq_lens=sl))
-        self.slot_req[slot] = None
-        self.slot_len[slot] = 0
-        self._admit_ord[slot] = 0
 
     def run_until_done(self, max_steps: int = 10000) -> List[Request]:
         out = []
@@ -1392,27 +1238,23 @@ class ServingEngine:
             "arch": self.cfg.name,
             "max_batch": self.max_batch,
             "max_seq": self.max_seq,
-            "mega_step": self.mega_step,
-            "max_new_cap": (self.max_new_cap if self.mega_step
-                            else None),
+            "max_new_cap": self.max_new_cap,
             "kv_dtype": (None if kv is None
                          else str(kv.layers.k.dtype)),
         }
 
     def _snapshot_tree(self):
         """The array half of a snapshot (also the restore template):
-        arena slabs, KV caches, and — in mega-step mode — the device
-        carry plus its host mirrors."""
-        tree = {"arena_mem": self.alloc_state.mem,
+        arena slabs, KV caches, the tick's device carry and its host
+        mirrors."""
+        return {"arena_mem": self.alloc_state.mem,
                 "arena_ctl": self.alloc_state.ctl,
                 "caches": self.caches,
-                "slot_len": np.asarray(self.slot_len)}
-        if self.mega_step:
-            tree["mega"] = self.mega_state
-            tree["pages_host"] = np.asarray(self._pages_host)
-            tree["nout_host"] = np.asarray(self._nout_host)
-            tree["fail_streak"] = np.asarray(self._fail_streak)
-        return tree
+                "slot_len": np.asarray(self.slot_len),
+                "mega": self.mega_state,
+                "pages_host": np.asarray(self._pages_host),
+                "nout_host": np.asarray(self._nout_host),
+                "fail_streak": np.asarray(self._fail_streak)}
 
     def _snapshot_meta(self) -> dict:
         """The JSON half: fingerprint, request queue, host tables,
@@ -1425,11 +1267,8 @@ class ServingEngine:
             "slot_reqs": [None if r is None else _req_to_json(r)
                           for r in self.slot_req],
             "waiting": [_req_to_json(r) for r in self.waiting],
-            "slot_pages": [[int(p) for p in ps]
-                           for ps in self.slot_pages],
             "slot_aux": [[int(p) for p in ps]
                          for ps in self.slot_aux],
-            "shard_pages": [int(x) for x in self._shard_pages],
             "stats": {k: v for k, v in self.stats.items()},
         }
         # round-trip now: catches an unserializable field at snapshot
@@ -1525,31 +1364,25 @@ class ServingEngine:
             mem=mapped["arena_mem"], ctl=mapped["arena_ctl"])
         self.caches = mapped["caches"]
         self.slot_len = np.asarray(mapped["slot_len"], np.int64).copy()
-        if self.mega_step:
-            self.mega_state = mapped["mega"]
-            self._pages_host = np.asarray(mapped["pages_host"],
-                                          np.int64).copy()
-            self._nout_host = np.asarray(mapped["nout_host"],
-                                         np.int64).copy()
-            self._fail_streak = np.asarray(mapped["fail_streak"],
-                                           np.int64).copy()
+        self.mega_state = mapped["mega"]
+        self._pages_host = np.asarray(mapped["pages_host"],
+                                      np.int64).copy()
+        self._nout_host = np.asarray(mapped["nout_host"],
+                                     np.int64).copy()
+        self._fail_streak = np.asarray(mapped["fail_streak"],
+                                       np.int64).copy()
         self.slot_req = [None if d is None else _req_from_json(d)
                          for d in meta["slot_reqs"]]
         self.waiting = [_req_from_json(d) for d in meta["waiting"]]
-        self.slot_pages = [[int(p) for p in ps]
-                           for ps in meta["slot_pages"]]
-        self.slot_aux = [[int(p) for p in ps]
-                         for ps in meta.get(
-                             "slot_aux", [[]] * self.max_batch)]
+        self.slot_aux = [[int(p) for p in ps] for ps in meta["slot_aux"]]
         self._uid = int(meta["uid"])
         self._admit_counter = int(meta["admit_counter"])
         self._admit_ord = np.asarray(meta["admit_ord"], np.int64)
-        self._shard_pages = np.asarray(meta["shard_pages"], np.int64)
         # counters restore; engine-identity fields (which backend /
         # lowering / launch count THIS process runs) stay fresh
         identity = {"arena_mem_words", "arena_ctl_words",
                     "alloc_backend", "alloc_lowering", "num_shards",
-                    "mega_step", "launches_per_tick",
+                    "launches_per_tick",
                     "aux_pages_per_slot", "jit_first_calls"}
         for k, v in meta["stats"].items():
             if k in self.stats and k not in identity:

@@ -14,13 +14,12 @@ defrag-wave counts).
 Determinism is the contract everything else leans on:
 ``generate_trace(scenario, seed=s, ...)`` is a pure function of its
 arguments, and a trace replays **identically** (token-for-token per
-uid) on the host decode loop and the fused mega-step, on any allocator
-backend/lowering, and at any shard count — so the harness doubles as
-the engine's hardest correctness test (:func:`replay_pair` +
-:func:`assert_conserved`).  Abandonment is expressed in absolute
-engine-step time (cancel at step ``t``), which both decode modes reach
-through the identical host-side admission machinery, keeping cancels
-parity-safe.
+uid) on any allocator backend/lowering and at any shard count — so
+the harness doubles as the engine's hardest correctness test
+(:func:`replay_pair` + :func:`assert_conserved`).  Abandonment is
+expressed in absolute engine-step time (cancel at step ``t``), which
+every engine configuration reaches through the identical host-side
+admission machinery, keeping cancels parity-safe.
 
 Per-modality page policy rides underneath (DESIGN.md §13): a
 layer-list model's recurrent-state pages (granite-4.0-h) are granted
@@ -33,8 +32,8 @@ beside its KV.
     cfg, make = engine_factory("mamba2-780m")
     trace = generate_trace(SCENARIOS["bursty"], seed=0,
                            vocab_size=cfg.vocab_size)
-    host, mega = replay_pair(make(mega=False), make(mega=True), trace)
-    print(host.summary())
+    one, four = replay_pair(make(num_shards=1), make(num_shards=4), trace)
+    print(one.summary())
 """
 from __future__ import annotations
 
@@ -60,7 +59,7 @@ class Scenario:
     ranges); output budgets come from ``out_lens``.  A client
     abandons with probability ``abandon_frac``, hanging up
     ``abandon_after``-many steps after arrival (absolute engine-step
-    time — parity-safe across decode modes)."""
+    time — parity-safe across engine configurations)."""
     name: str
     n_requests: int = 12
     arrival: str = "poisson"            # poisson | burst
@@ -118,8 +117,7 @@ def generate_trace(scenario: Scenario, *, seed: int, vocab_size: int,
     :class:`TraceItem` sorted by arrival step.  Prompt + budget are
     clipped so every request fits ``max_seq`` (the harness stresses
     the allocator via churn and concurrency, not via over-long
-    sequences) and budgets respect the engine's mega-step
-    ``max_new_cap``.
+    sequences) and budgets respect the engine's ``max_new_cap``.
 
     >>> from repro.serve.replay import SCENARIOS, generate_trace
     >>> a = generate_trace(SCENARIOS["steady"], seed=7, vocab_size=64)
@@ -166,7 +164,7 @@ class ReplayResult:
     """What one replay of one trace through one engine produced."""
     scenario: str
     arch: str
-    mode: str                       # host | mega
+    mode: str                       # "mega": the fused tick
     tokens: Dict[int, List[int]]    # uid → emitted tokens (completed)
     cancelled: List[int]            # uids actually cancelled
     steps: int
@@ -282,7 +280,7 @@ def replay(engine, trace: List[TraceItem], *, scenario: str = "",
     return ReplayResult(
         scenario=scenario,
         arch=engine.cfg.name,
-        mode="mega" if engine.mega_step else "host",
+        mode="mega",
         tokens=tokens,
         cancelled=sorted(cancelled),
         steps=t,
@@ -295,15 +293,15 @@ def replay(engine, trace: List[TraceItem], *, scenario: str = "",
 def assert_conserved(engine):
     """End-state allocator conservation after a drained replay: every
     page ever granted — KV and state pages alike — went back
-    through the allocator (``allocs == frees``), no slot holds page
-    ids, and the device page table is all holes.  Abandonment and
+    through the allocator (``allocs == frees``), no slot holds state
+    pages, the device page table is all holes, and no shard counts a
+    live page.  Abandonment and
     eviction paths free through the same counters, so a leak anywhere
     in the lifecycle trips this."""
     s = engine.stats
     assert s["allocs"] == s["frees"], (
         f"page leak: {s['allocs']} allocs vs {s['frees']} frees "
         f"({s['allocs'] - s['frees']} pages stranded)")
-    assert all(not p for p in engine.slot_pages), engine.slot_pages
     assert all(not p for p in engine.slot_aux), engine.slot_aux
     kv = engine._kv()
     if kv is not None:
@@ -312,31 +310,28 @@ def assert_conserved(engine):
     table = getattr(engine.caches, "state_table", None)
     if table is not None:
         assert (np.asarray(table) < 0).all(), "state table still maps pages"
-    if engine.mega_step:
-        engine._sync_shard_pages_from_table()
-    assert sum(engine.stats["shard_pages_live"]) == 0, (
-        engine.stats["shard_pages_live"])
+    live = engine.shard_pages_live()
+    assert sum(live) == 0, live
 
 
 def replay_pair(engine_a, engine_b, trace, *, scenario: str = "",
                 max_steps: int = 2000):
     """The parity harness: replay the SAME trace through two engine
-    configurations (canonically host loop vs fused mega-step, or
-    shards 1 vs 4) and assert token-for-token agreement per uid, the
+    configurations (canonically shards 1 vs 4, or two allocator
+    backends) and assert token-for-token agreement per uid, the
     same cancelled-uid set, and end-state conservation on both.
     Returns the two :class:`ReplayResult`."""
     ra = replay(engine_a, trace, scenario=scenario, max_steps=max_steps)
     rb = replay(engine_b, trace, scenario=scenario, max_steps=max_steps)
     assert ra.cancelled == rb.cancelled, (
-        f"cancelled sets diverge: {ra.mode}={ra.cancelled} vs "
-        f"{rb.mode}={rb.cancelled}")
+        f"cancelled sets diverge: {ra.cancelled} vs {rb.cancelled}")
     assert set(ra.tokens) == set(rb.tokens), (
         f"completed sets diverge: {sorted(ra.tokens)} vs "
         f"{sorted(rb.tokens)}")
     for uid in ra.tokens:
         assert ra.tokens[uid] == rb.tokens[uid], (
-            f"uid {uid} token streams diverge between {ra.mode} and "
-            f"{rb.mode}: {ra.tokens[uid]} vs {rb.tokens[uid]}")
+            f"uid {uid} token streams diverge: {ra.tokens[uid]} vs "
+            f"{rb.tokens[uid]}")
     assert_conserved(engine_a)
     assert_conserved(engine_b)
     return ra, rb
@@ -345,10 +340,10 @@ def replay_pair(engine_a, engine_b, trace, *, scenario: str = "",
 def engine_factory(arch: str, *, max_batch: int = 3, max_seq: int = 96,
                    max_new_cap: int = 32, seed: int = 0):
     """Build the reduced (smoke) config + params for ``arch`` ONCE and
-    return ``(cfg, make)`` where ``make(mega=..., **engine_kw)``
-    constructs a fresh float32 :class:`ServingEngine` over the shared
-    params — the cheap way to stand up host/mega (or shard-count)
-    pairs for parity replays."""
+    return ``(cfg, make)`` where ``make(**engine_kw)`` constructs a
+    fresh float32 :class:`ServingEngine` over the shared params — the
+    cheap way to stand up engine pairs (shard counts, allocator
+    backends) for parity replays."""
     from repro.configs import get_arch
     from repro.models.model import build_model
     from repro.serve.engine import ServingEngine
@@ -357,12 +352,11 @@ def engine_factory(arch: str, *, max_batch: int = 3, max_seq: int = 96,
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(seed))
 
-    def make(mega: bool = False, **kw):
+    def make(**kw):
         kw.setdefault("max_batch", max_batch)
         kw.setdefault("max_seq", max_seq)
         kw.setdefault("max_new_cap", max_new_cap)
         return ServingEngine(m, params, kv_dtype=jnp.float32,
-                             compute_dtype=jnp.float32,
-                             mega_step=mega, **kw)
+                             compute_dtype=jnp.float32, **kw)
 
     return cfg, make

@@ -34,9 +34,10 @@ def pytest_configure(config):
         "into the forced-blocked and nightly CI jobs)")
     config.addinivalue_line(
         "markers",
-        "serve: exercises the serving engine's fused decode mega-step "
+        "serve: exercises the serving engine's fused decode tick "
         "(serve/engine.py, DESIGN.md §11; the forced-blocked CI job "
-        "runs the mega-vs-host parity suite under this marker)")
+        "runs its served-alone stream parity suite under this "
+        "marker)")
     config.addinivalue_line(
         "markers",
         "ft: exercises crash-safe serving — engine snapshot/restore, "
@@ -47,8 +48,9 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "replay: exercises the traffic-replay harness — seeded trace "
-        "generation, client abandonment/cancellation, mega-vs-host "
-        "parity, and the all-archs serving smoke (serve/replay.py, "
+        "generation, client abandonment/cancellation, served-alone "
+        "and shard parity, and the all-archs serving smoke "
+        "(serve/replay.py, "
         "DESIGN.md §13; the forced-blocked CI job runs this marker, "
         "and the nightly job adds the two-scenario fig9 benchmark "
         "smoke)")

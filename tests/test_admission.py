@@ -5,75 +5,27 @@ The contract under test: admitting a request computes ONE row — a
 page heap and the admitted slot's page-table row — and writes that row
 back at the slot, which the prefill program takes as a traced argument.
 So every family's streams equal a standalone unpadded prefill of the
-prompt followed by greedy decode, in any slot and in both decode loops;
-an admission leaves every other slot's heap words, page-table row,
-``seq_lens`` and state rows as they were; one program serves every slot
-of a prompt length; and the engine counts the rows and token-rows its
-prefills computed.
+prompt followed by greedy decode (``serve_ref``), in any slot and on
+one or four allocator shards; an admission leaves every other slot's
+heap words, page-table row, ``seq_lens`` and state rows as they were;
+one program serves every slot of a prompt length; and the engine
+counts the rows and token-rows its prefills computed.
 
 Everything runs float32 (kv + compute): greedy streams must be exact.
 """
-import functools
-
 import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
 from repro.obs import trace as obs_trace
-from repro.serve.replay import engine_factory
+from serve_ref import factory as _factory
+from serve_ref import reference_stream
 
 pytestmark = pytest.mark.serve
 
 FAMILIES = ["qwen2-0.5b", "mixtral-8x7b", "mamba2-780m",
             "recurrentgemma-9b", "seamless-m4t-large-v2"]
-MAX_SEQ = 96
-
-
-@functools.lru_cache(maxsize=None)
-def _factory(arch):
-    return engine_factory(arch, max_seq=MAX_SEQ)
-
-
-@functools.lru_cache(maxsize=None)
-def _reference_programs(arch):
-    """The engine's model and weights, with a jitted prefill and decode."""
-    eng = _factory(arch)[1]()
-    m = eng.model
-    return (m, eng.params,
-            jax.jit(functools.partial(m.prefill, remat_policy="none",
-                                      dtype=jnp.float32)),
-            jax.jit(functools.partial(m.decode_step, dtype=jnp.float32)))
-
-
-@functools.lru_cache(maxsize=None)
-def _reference_stream(arch, prompt, n):
-    """One request alone: an unpadded ``(1, lp)`` prefill on a fresh
-    one-row cache with the canonical page table, then greedy
-    ``decode_step`` until ``n`` tokens."""
-    m, params, prefill, decode = _reference_programs(arch)
-    cfg = m.cfg
-    prompt = np.asarray(prompt, np.int32)
-    caches = m.make_decode_caches(1, max_seq=MAX_SEQ, kv_dtype=jnp.float32)
-    kv = caches.self_kv if cfg.is_encdec else caches.kv
-    if kv is not None:
-        kv = kv._replace(page_table=jnp.arange(
-            kv.page_table.shape[1], dtype=jnp.int32)[None])
-        caches = (caches._replace(self_kv=kv) if cfg.is_encdec
-                  else caches._replace(kv=kv))
-    batch = {"tokens": jnp.asarray(prompt[None])}
-    if cfg.modality == "audio":
-        batch["src_embeds"] = jnp.zeros((1, MAX_SEQ, cfg.d_model),
-                                        jnp.float32)
-        batch["src_valid"] = jnp.asarray([len(prompt)], jnp.int32)
-    logits, caches = prefill(params, batch, caches)
-    out = [int(jnp.argmax(logits[0]))]
-    while len(out) < n:
-        logits, caches = decode(params, jnp.asarray([[out[-1]]], jnp.int32),
-                                caches)
-        out.append(int(jnp.argmax(logits[0])))
-    return out
 
 
 def _rows_equal(before, after, keep, axis):
@@ -119,15 +71,15 @@ def _check_isolated(eng, before, admitted):
                                    keep, 1)
 
 
-@pytest.mark.parametrize("mega", [False, True], ids=["host", "mega"])
+@pytest.mark.parametrize("shards", [1, 4], ids=["shards1", "shards4"])
 @pytest.mark.parametrize("arch", FAMILIES)
-def test_admission_prefills_one_row(arch, mega):
+def test_admission_prefills_one_row(arch, shards):
     """Five requests through three slots, so later ones land in slots
     freed at different ticks: each stream equals the request served
     alone by an unpadded prefill, and each admission leaves the other
     slots' caches as they were."""
     cfg, make = _factory(arch)
-    eng = make(mega=mega)
+    eng = make(num_shards=shards)
     rng = np.random.default_rng(7)
     want = {}
     for _ in range(5):
@@ -151,7 +103,7 @@ def test_admission_prefills_one_row(arch, mega):
     assert eng.stats["frees"] == eng.stats["allocs"]
     for r in done:
         prompt, n = want[r.uid]
-        assert r.out_tokens == _reference_stream(arch, prompt, n), r.uid
+        assert r.out_tokens == reference_stream(arch, prompt, n), r.uid
 
 
 def test_prefill_program_is_shared_by_slots():
@@ -159,7 +111,7 @@ def test_prefill_program_is_shared_by_slots():
     into three slots compile one prefill program; a new length one
     more."""
     cfg, make = _factory("qwen2-0.5b")
-    eng = make(mega=False)
+    eng = make()
     rng = np.random.default_rng(1)
     for _ in range(3):
         eng.submit(rng.integers(2, cfg.vocab_size, 12), max_new_tokens=2)
@@ -180,7 +132,7 @@ def test_prefill_counters_span_and_metrics():
     span says ``rows=1``."""
     cfg, make = _factory("qwen2-0.5b")
     tracer = obs_trace.Tracer(enabled=True)
-    eng = make(mega=True, tracer=tracer)
+    eng = make(tracer=tracer)
     rng = np.random.default_rng(2)
     lens = [5, 17, 9, 26]
     for lp in lens:
@@ -189,7 +141,7 @@ def test_prefill_counters_span_and_metrics():
     assert eng.stats["prefill_rows"] == 3
     assert eng.stats["prefill_tokens"] == sum(lens[:3])
     snap = eng.snapshot()
-    twin = make(mega=True)
+    twin = make()
     twin.restore(snap)
     for k in ("prefill_rows", "prefill_tokens"):
         assert twin.stats[k] == eng.stats[k]

@@ -123,14 +123,15 @@ def test_design_s10_defrag_documented():
         assert needle in sec, f"DESIGN.md §10 lost {needle!r}"
 
 
-# ---- DESIGN.md §11: the fused decode mega-step ----------------------------
+# ---- DESIGN.md §11: the fused decode tick ---------------------------------
 
 def test_design_s11_mega_step_documented():
     """The §11 contract keywords tests/test_serve_mega.py relies on
-    stay documented: the five fused stages, the word-offset page
-    table, the flag-vector host sync, and the launch-count proof."""
+    stay documented: the tick as the one decode loop, the five fused
+    stages, the word-offset page table, the flag-vector host sync, and
+    the launch-count proof."""
     sec = DOC.read_text().split("## §11")[1].split("\n## §")[0]
-    for needle in ("mega_step=True", "Ouroboros.grow", "grow_lanes",
+    for needle in ("only decode loop", "Ouroboros.grow", "grow_lanes",
                    "scatter_grant_words", "donate_argnums",
                    "launches_per_tick", "flag vector",
                    "merge_rows", "BENCH_serve.json",

@@ -76,12 +76,25 @@ def small():
     return cfg, m, params, ref
 
 
-def _engine(small, mega=False, **kw):
+def _engine(small, **kw):
     cfg, m, params, _ = small
     kw.setdefault("max_batch", 2)
     kw.setdefault("max_seq", 96)
     return ServingEngine(m, params, kv_dtype=jnp.float32,
-                         compute_dtype=jnp.float32, mega_step=mega, **kw)
+                         compute_dtype=jnp.float32, **kw)
+
+
+def _grow(eng, slot):
+    """Map the pages the slot's next token needs into its page-table
+    row, from the engine's allocator."""
+    kv = eng._kv()
+    have = int((np.asarray(kv.page_table[slot]) >= 0).sum())
+    need = -(-(int(eng.slot_len[slot]) + 1) // eng.page) - have
+    if need > 0:
+        got = eng._alloc_pages([slot % eng.num_shards] * need)
+        assert min(got) >= 0
+        eng._set_kv(kv._replace(page_table=kv.page_table.at[
+            slot, have:have + need].set(jnp.asarray(got, jnp.int32))))
 
 
 def _reference_logits(small, seq, quant=None):
@@ -118,7 +131,7 @@ def test_prefill_and_paged_decode_match_reference(small, lp):
                                                    dtype=jnp.float32))
     toks = [eng.slot_req[slot].out_tokens[0]]
     for _ in range(12):
-        eng._grow_active([slot])
+        _grow(eng, slot)
         t = np.zeros((eng.max_batch, 1), np.int32)
         t[slot, 0] = toks[-1]
         lg, eng.caches = decode(params, jnp.asarray(t), eng.caches)
@@ -201,15 +214,15 @@ def _live_pages(eng):
         [p for aux in eng.slot_aux for p in aux], np.int64)])
 
 
-@pytest.mark.parametrize("mega", [False, True], ids=["host", "mega"])
-def test_state_pages_granted_with_kv_and_freed_with_them(small, mega):
+@pytest.mark.parametrize("shards", [1, 4], ids=["shards1", "shards4"])
+def test_state_pages_granted_with_kv_and_freed_with_them(small, shards):
     """Each admission is one transaction of the slot's state pages and
     its prompt's KV pages; retirement, eviction and cancel each return
     both in one; the page balance is 0 throughout, and a drained engine
     holds no page and no state-table entry."""
     cfg, *_ = small
     tracer = obs_trace.Tracer(enabled=True)
-    eng = _engine(small, mega, max_batch=3, tracer=tracer)
+    eng = _engine(small, max_batch=3, num_shards=shards, tracer=tracer)
     aux = KV.modality_page_quota(cfg)
     assert eng.aux_pages == aux == 2 * KV.state_pages_per_layer(cfg)
     assert eng.caches.ssm_h is None and eng.caches.ssm_conv is None
@@ -256,7 +269,7 @@ def test_defrag_moves_state_pages_with_their_rows(small):
     cfg, *_ = small
 
     def serve(defrag):
-        eng = _engine(small, True, max_batch=3)
+        eng = _engine(small, max_batch=3)
         rng = np.random.default_rng(4)
         for n in (2, 9, 3, 9, 4, 9):
             eng.submit(rng.integers(2, cfg.vocab_size, 18),

@@ -412,8 +412,7 @@ def smoke_model():
     return cfg, m, m.init(jax.random.PRNGKey(0))
 
 
-@pytest.mark.parametrize("mega", [True, False])
-def test_engine_spans_on_the_profiler_clock(smoke_model, mega):
+def test_engine_spans_on_the_profiler_clock(smoke_model):
     """Every engine span is a ``serve.<phase>`` profiler annotation,
     nested tick ⊃ admission ⊃ prefill and retire ⊃ bulk_free, one
     prefill per admission and one retire per retirement, each with its
@@ -424,7 +423,7 @@ def test_engine_spans_on_the_profiler_clock(smoke_model, mega):
     tracer = Tracer()
     eng = ServingEngine(m, params, max_batch=2, max_seq=64,
                         kv_dtype=jnp.float32, compute_dtype=jnp.float32,
-                        mega_step=mega, tracer=tracer)
+                        tracer=tracer)
     rng = np.random.default_rng(0)
     uids = [eng.submit(rng.integers(2, cfg.vocab_size, 8),
                        max_new_tokens=3) for _ in range(3)]
@@ -453,12 +452,8 @@ def test_engine_spans_on_the_profiler_clock(smoke_model, mega):
     for d in by["serve.decode"]:
         assert len(_inside(d, ticks)) == 1 and 1 <= d[3]["slots"] <= 2
     assert len(by["serve.flag_sync"]) == len(by["serve.decode"])
-    if mega:
-        assert sorted(p[3]["uid"] for p in by["serve.slot_push"]) == uids
-        assert {"prefill", "mega"} <= programs
-    else:
-        assert "serve.slot_push" not in by
-        assert {"prefill", "decode"} <= programs
+    assert sorted(p[3]["uid"] for p in by["serve.slot_push"]) == uids
+    assert {"prefill", "mega"} <= programs
     assert "<lambda>" not in programs
 
     doc = tracer.to_json()

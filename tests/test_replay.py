@@ -8,9 +8,10 @@ correctness net, so this file is where the zoo-wide guarantees live:
 - **cancellation** (the client-abandonment path) handles all three
   uid states — waiting, active, retired — and frees every page the
   request ever held (KV + modality aux) back through the allocator;
-- **parity**: the same trace replays token-for-token identically on
-  the host decode loop and the fused mega-step, including under bursty
-  load and mid-stream abandonment, with end-state conservation;
+- **parity**: every stream a replay completes equals its request
+  served alone (``serve_ref``), including under bursty load and
+  mid-stream abandonment, and one and four allocator shards agree
+  token-for-token, with end-state conservation;
 - **no family untested**: a replay smoke runs over every arch in the
   zoo (tiny geometries), exercising the per-modality page policy —
   recurrent-state pages through the same arena as KV.
@@ -28,6 +29,7 @@ from repro.configs import ALL_ARCHS
 from repro.serve.replay import (SCENARIOS, Scenario, engine_factory,
                                 assert_conserved, generate_trace,
                                 replay, replay_pair)
+from serve_ref import expected_stream
 
 pytestmark = pytest.mark.replay
 
@@ -86,7 +88,7 @@ def test_cancel_waiting_active_retired():
     pages; a retired (or never-submitted) uid is a no-op returning
     False — never a KeyError."""
     cfg, make = engine_factory("qwen2-0.5b", max_batch=2)
-    eng = make(mega=False)
+    eng = make()
     prompts, max_new = _mini_trace(cfg.vocab_size, n=4)
     uids = [eng.submit(p, max_new_tokens=max_new) for p in prompts]
 
@@ -115,14 +117,14 @@ def test_cancel_waiting_active_retired():
     assert eng.stats["cancels"] == 2
 
 
-@pytest.mark.parametrize("mega", [False, True], ids=["host", "mega"])
-def test_abandonment_frees_all_pages(mega):
+@pytest.mark.parametrize("shards", [1, 4], ids=["shards1", "shards4"])
+def test_abandonment_frees_all_pages(shards):
     """The headline conservation property: after an abandonment-heavy
     replay drains, every page ever granted — KV and modality aux alike
     — went back through the allocator (allocs == frees), no slot holds
     pages, and the device page table is all holes."""
     cfg, make = engine_factory("granite-4.0-h-small")   # aux pages > 0
-    eng = make(mega=mega)
+    eng = make(num_shards=shards)
     assert eng.aux_pages > 0, "a paged-state config carries state pages"
     trace = generate_trace(SCENARIOS["abandon"], seed=5,
                            vocab_size=cfg.vocab_size)
@@ -132,42 +134,55 @@ def test_abandonment_frees_all_pages(mega):
     assert eng.stats["cancels"] == len(r.cancelled)
 
 
+def _assert_served_alone(arch, trace, result):
+    """Every completed stream equals its request served alone (uids
+    follow arrival order)."""
+    items = sorted(trace, key=lambda it: it.step)
+    assert result.tokens
+    for uid, toks in result.tokens.items():
+        it = items[uid - 1]
+        assert toks == expected_stream(arch, it.prompt, it.max_new), uid
+
+
 def test_bursty_parity_mega_vs_host():
-    """Token-for-token parity between the host decode loop and the
-    fused mega-step under a bursty trace that overruns max_batch (so
-    the waiting queue and the allocator churn together)."""
+    """Under a bursty trace that overruns max_batch (so the waiting
+    queue and the allocator churn together), every stream equals its
+    request served alone."""
     cfg, make = engine_factory("qwen2-0.5b")
     trace = generate_trace(SCENARIOS["bursty"], seed=11,
                            vocab_size=cfg.vocab_size)
     assert len(trace) > 3 * 2, "burst should overrun the batch"
-    host, mega = replay_pair(make(mega=False), make(mega=True), trace,
-                             scenario="bursty")
-    assert host.tokens == mega.tokens and host.tokens
-    assert host.queue_wait == mega.queue_wait
+    eng = make()
+    r = replay(eng, trace, scenario="bursty")
+    assert max(r.queue_wait.values()) > 0, "nothing ever queued"
+    _assert_served_alone("qwen2-0.5b", trace, r)
+    assert_conserved(eng)
 
 
 def test_abandon_parity_with_aux_pages():
-    """Parity holds through mid-stream cancels on a config whose slots
-    hold state pages (the Mamba-2 state of a layer-list model)."""
+    """Through mid-stream cancels on a config whose slots hold state
+    pages (the Mamba-2 state of a layer-list model), every completed
+    stream equals its request served alone."""
     cfg, make = engine_factory("granite-4.0-h-small")
-    eng_h, eng_m = make(mega=False), make(mega=True)
-    assert eng_h.aux_pages > 0
+    eng = make()
+    assert eng.aux_pages > 0
     trace = generate_trace(SCENARIOS["abandon"], seed=7,
                            vocab_size=cfg.vocab_size)
-    host, mega = replay_pair(eng_h, eng_m, trace, scenario="abandon")
-    assert host.cancelled == mega.cancelled and host.cancelled
+    r = replay(eng, trace, scenario="abandon")
+    assert r.cancelled
+    _assert_served_alone("granite-4.0-h-small", trace, r)
+    assert_conserved(eng)
 
 
 def test_shard_parity():
-    """The other parity axis: shards 1 vs 4 on the same trace and
-    decode mode must agree token-for-token (hashed home-shard routing
-    is an allocator-internal concern — DESIGN.md §9)."""
+    """Shards 1 vs 4 on the same trace must agree token-for-token
+    (hashed home-shard routing is an allocator-internal concern —
+    DESIGN.md §9)."""
     cfg, make = engine_factory("qwen2-0.5b")
     trace = generate_trace(SCENARIOS["steady"], seed=2,
                            vocab_size=cfg.vocab_size)
-    one, four = replay_pair(make(mega=False, num_shards=1),
-                            make(mega=False, num_shards=4), trace,
-                            scenario="steady")
+    one, four = replay_pair(make(num_shards=1), make(num_shards=4),
+                            trace, scenario="steady")
     assert one.tokens == four.tokens and one.tokens
 
 
@@ -179,12 +194,12 @@ _SMOKE = dataclasses.replace(SCENARIOS["abandon"], n_requests=4,
 
 @pytest.mark.parametrize("arch", ALL_ARCHS)
 def test_replay_smoke_every_config(arch):
-    """Every arch in the zoo replays a short abandonment trace on the
-    host loop with conservation asserted — state pages and KV pages
-    all route through the same Ouroboros arena, and no family is ever
+    """Every arch in the zoo replays a short abandonment trace through
+    the tick with conservation asserted — state pages and KV pages all
+    route through the same Ouroboros arena, and no family is ever
     untested again."""
     cfg, make = engine_factory(arch, max_batch=2)
-    eng = make(mega=False)
+    eng = make()
     trace = generate_trace(_SMOKE, seed=3, vocab_size=cfg.vocab_size)
     r = replay(eng, trace, scenario="smoke")
     assert len(r.tokens) + len(r.cancelled) == len(trace)
@@ -233,7 +248,7 @@ def test_replay_summary_is_schema_complete():
     cfg, make = engine_factory("qwen2-0.5b")
     trace = generate_trace(SCENARIOS["steady"], seed=0,
                            vocab_size=cfg.vocab_size)
-    s = replay(make(mega=False), trace, scenario="steady").summary()
+    s = replay(make(), trace, scenario="steady").summary()
     assert all(k in s for k in REPLAY_CELL_KEYS)
     assert s["tick_ms_p99"] >= s["tick_ms_p50"] >= 0.0
     assert s["queue_wait_p99"] >= s["queue_wait_p50"] >= 0.0

@@ -1,11 +1,13 @@
-"""Fused decode mega-step tests (serve/engine.py, DESIGN.md §11).
+"""Fused decode tick tests (serve/engine.py, DESIGN.md §11).
 
-The contract under test: ``mega_step=True`` produces token streams
-IDENTICAL to the host-loop decode — across allocator backends,
-lowerings, and shard counts — while executing grow + forward + sample
-as ONE jitted tick whose kernel-launch count is independent of
-``max_batch``.  Failure recovery (defrag-retry on page exhaustion) and
-the proactive ``defrag_threshold`` trigger ride the same suite.
+The contract under test: the tick — the engine's only decode loop —
+produces token streams IDENTICAL to each request served alone
+(``serve_ref``) — across allocator backends, lowerings, and shard
+counts — while executing grow + forward + sample as ONE jitted tick
+whose kernel-launch count is independent of ``max_batch``.  Failure
+recovery (defrag-retry on page exhaustion, eviction) gives the streams
+the same tick produces with the heap unpressured, and the proactive
+``defrag_threshold`` trigger rides the same suite.
 
 Everything runs float32 (kv + compute): greedy argmax parity must be
 bit-exact, not merely close.
@@ -18,40 +20,43 @@ import jax.numpy as jnp
 
 from repro.configs import get_arch
 from repro.models.model import build_model
+from serve_ref import expected_stream
 
 pytestmark = pytest.mark.serve
+ARCH = "qwen2-0.5b"
 
 
 @pytest.fixture(scope="module")
 def tiny_model():
-    cfg = get_arch("qwen2-0.5b").smoke()
+    cfg = get_arch(ARCH).smoke()
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(0))
     return cfg, m, params
 
 
-def _run(tiny_model, mega, *, n_req=4, max_new=5, seed=0, **kw):
+def _prompts(cfg, n_req, seed):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(2, cfg.vocab_size, int(rng.integers(4, 30)))
+            for _ in range(n_req)]
+
+
+def _run(tiny_model, *, n_req=4, max_new=5, seed=0, eos_id=None, **kw):
     from repro.serve.engine import ServingEngine
     cfg, m, params = tiny_model
     eng = ServingEngine(m, params, max_batch=3, max_seq=96,
                         kv_dtype=jnp.float32, compute_dtype=jnp.float32,
-                        mega_step=mega, **kw)
-    rng = np.random.default_rng(seed)
-    for _ in range(n_req):
-        eng.submit(rng.integers(2, cfg.vocab_size,
-                                int(rng.integers(4, 30))),
-                   max_new_tokens=max_new)
+                        **kw)
+    for p in _prompts(cfg, n_req, seed):
+        eng.submit(p, max_new_tokens=max_new, eos_id=eos_id)
     done = eng.run_until_done(300)
     assert len(done) == n_req
     return {r.uid: r.out_tokens for r in done}, eng
 
 
-@pytest.fixture(scope="module")
-def host_tokens(tiny_model):
-    """Host-loop reference streams (jnp backend — the oracle)."""
-    toks, eng = _run(tiny_model, False)
-    assert eng.stats["frees"] == eng.stats["allocs"]
-    return toks
+def _expected(cfg, *, n_req=4, max_new=5, seed=0, eos_id=None):
+    """uid → the stream of that request served alone."""
+    return {uid: expected_stream(ARCH, p, max_new, eos_id)
+            for uid, p in enumerate(_prompts(cfg, n_req, seed), 1)}
 
 
 @pytest.mark.parametrize("backend,lowering,shards", [
@@ -61,51 +66,34 @@ def host_tokens(tiny_model):
     ("pallas", "blocked", 1),
     ("pallas", "auto", 4),
 ])
-def test_mega_matches_host_loop(tiny_model, host_tokens, backend,
-                                lowering, shards):
-    """Token-for-token: the fused tick replays the host loop exactly,
-    whatever transaction backend/lowering/shard count grows the KV
-    heap underneath it."""
-    toks, eng = _run(tiny_model, True, alloc_backend=backend,
+def test_mega_matches_host_loop(tiny_model, backend, lowering, shards):
+    """Token-for-token: the fused tick gives every request the stream
+    it gets served alone, whatever transaction backend/lowering/shard
+    count grows the KV heap underneath it."""
+    toks, eng = _run(tiny_model, alloc_backend=backend,
                      alloc_lowering=lowering, num_shards=shards)
-    assert toks == host_tokens
+    assert toks == _expected(tiny_model[0])
     assert eng.stats["frees"] == eng.stats["allocs"]
     assert eng.stats["alloc_failures"] == 0
-    assert eng.stats["mega_step"] is True
 
 
 def test_mega_handles_max_new_one(tiny_model):
-    """Finish-semantics edge: ``max_new_tokens=1`` yields TWO tokens on
-    the host path (prefill token + the decode append that detects the
-    budget); the mega budget accounting must reproduce that, not
-    truncate at one."""
-    h, _ = _run(tiny_model, False, n_req=2, max_new=1)
-    g, _ = _run(tiny_model, True, n_req=2, max_new=1)
-    assert h == g
+    """Finish-semantics edge: ``max_new_tokens=1`` yields TWO tokens
+    (prefill token + the decode append that detects the budget); the
+    tick's budget accounting must reproduce that, not truncate at
+    one."""
+    g, _ = _run(tiny_model, n_req=2, max_new=1)
+    assert g == _expected(tiny_model[0], n_req=2, max_new=1)
     assert all(len(t) == 2 for t in g.values())
 
 
 def test_mega_eos_parity(tiny_model):
-    """EOS early-exit fires on the same tick in both modes: pick the
+    """EOS early-exit fires on the tick that decodes it: pick the
     token the reference emits mid-stream as eos_id and rerun."""
-    from repro.serve.engine import ServingEngine
-    cfg, m, params = tiny_model
-    h, _ = _run(tiny_model, False, n_req=2, max_new=6, seed=3)
-    eos = h[1][2]  # third emitted token of request 1
-
-    def gen(mega):
-        eng = ServingEngine(m, params, max_batch=3, max_seq=96,
-                            kv_dtype=jnp.float32,
-                            compute_dtype=jnp.float32, mega_step=mega)
-        rng = np.random.default_rng(3)
-        for _ in range(2):
-            eng.submit(rng.integers(2, cfg.vocab_size,
-                                    int(rng.integers(4, 30))),
-                       max_new_tokens=6, eos_id=eos)
-        done = eng.run_until_done(300)
-        return {r.uid: r.out_tokens for r in done}
-
-    a, b = gen(False), gen(True)
+    cfg = tiny_model[0]
+    eos = _expected(cfg, n_req=2, max_new=6, seed=3)[1][2]
+    a = _expected(cfg, n_req=2, max_new=6, seed=3, eos_id=eos)
+    b, _ = _run(tiny_model, n_req=2, max_new=6, seed=3, eos_id=eos)
     assert a == b
     assert len(a[1]) < 6  # the eos actually cut request 1 short
 
@@ -125,7 +113,7 @@ def test_mega_launch_count_constant_in_batch(tiny_model):
             eng = ServingEngine(m, params, max_batch=mb, max_seq=96,
                                 kv_dtype=jnp.float32,
                                 compute_dtype=jnp.float32,
-                                mega_step=True, alloc_backend=backend)
+                                alloc_backend=backend)
             n = launches_per_tick(eng)
             assert eng.stats["launches_per_tick"] == n
             per_batch.append(n)
@@ -134,74 +122,63 @@ def test_mega_launch_count_constant_in_batch(tiny_model):
     assert counts == {"jnp": 0, "pallas": 1}
 
 
-def test_launches_per_tick_works_without_mega(tiny_model):
-    """Host-mode engines report a launch count too (obs/, DESIGN.md
-    §14): the jitted decode program plus the bulk-grow transaction
-    dispatched around it, read off the jaxprs like the mega count —
-    so BENCH_serve host cells and mega cells are directly comparable."""
-    from repro.serve.engine import ServingEngine
-    cfg, m, params = tiny_model
-    eng = ServingEngine(m, params, max_batch=2, max_seq=64,
-                        kv_dtype=jnp.float32)
-    n = eng.launches_per_tick()
-    assert isinstance(n, int) and n >= 0
-    assert eng.stats["launches_per_tick"] == n
-    # the pallas allocator contributes exactly the one fused grow
-    # kernel on top of the jnp count, and the count is constant in
-    # max_batch, like the mega-path proof above
-    engp = ServingEngine(m, params, max_batch=4, max_seq=64,
-                         kv_dtype=jnp.float32, alloc_backend="pallas")
-    assert engp.launches_per_tick() == n + 1
-
-
 def test_mega_rejects_overlong_request(tiny_model):
     """The device token buffer is sized at construction; a submit past
     it must fail loudly at submit time, not corrupt out_buf later."""
     from repro.serve.engine import ServingEngine
     cfg, m, params = tiny_model
     eng = ServingEngine(m, params, max_batch=2, max_seq=64,
-                        kv_dtype=jnp.float32, mega_step=True,
-                        max_new_cap=8)
+                        kv_dtype=jnp.float32, max_new_cap=8)
     with pytest.raises(ValueError, match="max_new_cap"):
         eng.submit(np.arange(2, 10), max_new_tokens=9)
 
 
 def test_decode_syncs_token_ids_not_logits(tiny_model):
-    """Legacy-path fix: the jitted decode/prefill entries argmax ON
-    DEVICE — the host fetch is (B,) int32 ids, never (B, vocab)
-    logits."""
+    """The tick and the prefill argmax ON DEVICE: the tick hands the
+    host a (B,) uint8 flag vector and keeps (B,) int32 token ids (its
+    token buffer is read at retirement), the prefill returns one int32
+    id — no output carries a vocab axis."""
     from repro.serve.engine import ServingEngine
     cfg, m, params = tiny_model
     eng = ServingEngine(m, params, max_batch=3, max_seq=96,
-                        kv_dtype=jnp.float32)
-    toks = jnp.zeros((3, 1), jnp.int32)
-    ids, _ = jax.eval_shape(eng._decode, params, toks, eng.caches)
-    assert ids.shape == (3,) and ids.dtype == jnp.int32
+                        kv_dtype=jnp.float32, max_new_cap=8)
+    eng._build_mega()
+    _, _, ms, flags, *lanes = jax.eval_shape(
+        eng._mega_fn, params, eng.alloc_state, eng.caches,
+        eng.mega_state)
+    assert flags.shape == (3,) and flags.dtype == jnp.uint8
+    assert ms.last_tok.shape == (3,) and ms.last_tok.dtype == jnp.int32
+    assert ms.out_buf.shape == (3, 8) and ms.out_buf.dtype == jnp.int32
+    ids, _ = jax.eval_shape(eng._prefill, params,
+                            {"tokens": jnp.zeros((1, 6), jnp.int32)},
+                            eng.caches, np.int32(0))
+    assert ids.shape == (1,) and ids.dtype == jnp.int32
+    assert all(cfg.vocab_size not in x.shape
+               for x in jax.tree.leaves((ms, flags, lanes, ids)))
 
 
 @pytest.mark.defrag
 def test_mega_recovers_from_exhaustion(tiny_model):
-    """The exhaustion-recovery trace of test_defrag, replayed through
-    the mega-step: alloc failure surfaces in the per-tick flags, the
-    host reclaims the failed slots' partial grants, runs a defrag
-    wave, and the retried ticks produce the SAME streams the host
-    loop does."""
+    """The exhaustion-recovery trace of test_defrag: alloc failure
+    surfaces in the per-tick flags, the host reclaims the failed
+    slots' partial grants, runs a defrag wave, and the retried ticks
+    produce the SAME streams the tick gives on an unpressured heap."""
     from repro.serve.engine import ServingEngine
     cfg, m, params = tiny_model
 
-    def trace(mega):
+    def trace(pressure):
         eng = ServingEngine(m, params, max_batch=2, max_seq=64,
                             kv_dtype=jnp.float32,
-                            compute_dtype=jnp.float32, num_pages=16,
-                            mega_step=mega)
-        n = 16
-        big = jnp.full(n, 2048, jnp.int32)
-        st, offs = eng.ouro.alloc(eng.alloc_state, big,
-                                  jnp.ones(n, bool))
-        granted = np.asarray(offs) >= 0
-        assert granted.any()
-        eng.alloc_state = eng.ouro.free(st, offs, big,
-                                        jnp.asarray(granted))
+                            compute_dtype=jnp.float32, num_pages=16)
+        if pressure:
+            n = 16
+            big = jnp.full(n, 2048, jnp.int32)
+            st, offs = eng.ouro.alloc(eng.alloc_state, big,
+                                      jnp.ones(n, bool))
+            granted = np.asarray(offs) >= 0
+            assert granted.any()
+            eng.alloc_state = eng.ouro.free(st, offs, big,
+                                            jnp.asarray(granted))
         rng = np.random.default_rng(1)
         for _ in range(2):
             eng.submit(rng.integers(2, cfg.vocab_size, 40),
@@ -210,9 +187,10 @@ def test_mega_recovers_from_exhaustion(tiny_model):
         assert len(done) == 2
         return sorted(tuple(r.out_tokens) for r in done), eng
 
-    h, _ = trace(False)
+    h, plain = trace(False)
     g, eng = trace(True)
     assert h == g
+    assert plain.stats["defrag_waves"] == 0
     assert eng.stats["defrag_waves"] > 0
     assert eng.stats["frees"] == eng.stats["allocs"]
 
@@ -220,19 +198,22 @@ def test_mega_recovers_from_exhaustion(tiny_model):
 @pytest.mark.defrag
 def test_exhaustion_evicts_instead_of_raising(tiny_model):
     """When defrag cannot reclaim (a co-tenant HOLDS the heap live),
-    both decode paths degrade gracefully instead of raising
-    MemoryError: the youngest slot is evicted (its pages freed, its
-    request requeued, ``evictions`` counted) and the engine stays
-    serviceable — once the co-tenant releases its pages, the evicted
-    request replays and completes with the identical greedy stream."""
+    the tick degrades gracefully instead of raising MemoryError: the
+    youngest slot is evicted (its pages freed, its request requeued,
+    ``evictions`` counted) and the engine stays serviceable — once the
+    co-tenant releases its pages, the evicted request replays and
+    completes with the stream an unpressured engine gives it."""
     from repro.serve.engine import ServingEngine
     cfg, m, params = tiny_model
+    prompt = np.random.default_rng(1).integers(2, cfg.vocab_size, 30)
 
-    def run(mega):
-        eng = ServingEngine(m, params, max_batch=2, max_seq=64,
-                            kv_dtype=jnp.float32,
-                            compute_dtype=jnp.float32, num_pages=16,
-                            mega_step=mega)
+    def engine():
+        return ServingEngine(m, params, max_batch=2, max_seq=64,
+                             kv_dtype=jnp.float32,
+                             compute_dtype=jnp.float32, num_pages=16)
+
+    def run():
+        eng = engine()
         # co-tenant takes every 256 B page, hands exactly 2 back:
         # enough to admit a 30-token prompt, not enough to grow.
         sizes = jnp.full(64, 256, jnp.int32)
@@ -245,8 +226,7 @@ def test_exhaustion_evicts_instead_of_raising(tiny_model):
         eng.alloc_state = eng.ouro.free(
             eng.alloc_state, jnp.asarray(back), sizes,
             jnp.asarray(back >= 0))
-        eng.submit(np.random.default_rng(1).integers(
-            2, cfg.vocab_size, 30), max_new_tokens=30)
+        eng.submit(prompt, max_new_tokens=30)
         # serve into the wall: no exception, eviction(s) instead, and
         # the request is parked (requeued or re-admitted), not lost
         for _ in range(30):
@@ -264,7 +244,11 @@ def test_exhaustion_evicts_instead_of_raising(tiny_model):
         assert len(done) == 1 and done[0].out_tokens
         return done[0].out_tokens
 
-    assert run(False) == run(True)
+    plain = engine()
+    plain.submit(prompt, max_new_tokens=30)
+    want = plain.run_until_done(200)[0].out_tokens
+    assert plain.stats["evictions"] == 0
+    assert run() == want
 
 
 @pytest.mark.defrag
@@ -301,3 +285,11 @@ def test_engine_validates_defrag_knobs(tiny_model):
         ServingEngine(None, None, defrag_check_interval=0)
     with pytest.raises(ValueError, match="max_new_cap"):
         ServingEngine(None, None, max_new_cap=0)
+
+
+def test_engine_refuses_the_host_loop():
+    """The fused tick is the only decode loop: asking for the removed
+    host loop fails at construction, before any set-up."""
+    from repro.serve.engine import ServingEngine
+    with pytest.raises(ValueError, match="host decode loop"):
+        ServingEngine(None, None, mega_step=False)

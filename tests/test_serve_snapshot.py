@@ -2,7 +2,7 @@
 
 The contract under test: ``ServingEngine.snapshot()/restore()``
 captures the COMPLETE serving state — arena word image + control block
-(all shards), KV page heaps + page tables + seq_lens, the mega-step
+(all shards), KV page heaps + page tables + seq_lens, the tick's
 carry + host mirrors, the request queue, and the stats block — such
 that a restored engine (a) holds word-for-word identical arena/KV
 state and (b) resumes decoding token-identically, across allocator
@@ -127,20 +127,20 @@ def test_snapshot_restores_across_backend_and_lowering(tiny_model):
 
 # ---- kill-mid-decode → restore → token parity (tmp_path = "disk") ---------
 
-@pytest.mark.parametrize("mega", [False, True])
-def test_kill_mid_decode_restores_token_identically(tiny_model, mega,
+@pytest.mark.parametrize("shards", [1, 4], ids=["shards1", "shards4"])
+def test_kill_mid_decode_restores_token_identically(tiny_model, shards,
                                                     tmp_path):
     """The crash path: decode a few ticks, snapshot to a committed
     on-disk checkpoint, DISCARD the engine (the "kill"), restore in a
     fresh process-equivalent engine, finish — killed-run + resumed-run
     streams concatenate to exactly the uninterrupted run's streams,
-    for both decode loops."""
+    on one allocator shard and on four."""
     cfg = tiny_model[0]
-    ref = _engine(tiny_model, mega_step=mega)
+    ref = _engine(tiny_model, num_shards=shards)
     _submit(ref, cfg)
     want = _toks(ref.run_until_done(300))
 
-    eng = _engine(tiny_model, mega_step=mega)
+    eng = _engine(tiny_model, num_shards=shards)
     _submit(eng, cfg)
     early = []
     for _ in range(4):
@@ -148,7 +148,7 @@ def test_kill_mid_decode_restores_token_identically(tiny_model, mega,
     eng.snapshot(directory=str(tmp_path))
     del eng  # the crash
 
-    resumed = _engine(tiny_model, mega_step=mega)
+    resumed = _engine(tiny_model, num_shards=shards)
     step = resumed.restore(str(tmp_path))
     assert step == 4
     got = _toks(early + resumed.run_until_done(300))
@@ -198,6 +198,30 @@ def test_restore_rejects_mismatched_layout(tiny_model):
     dst = _engine(tiny_model, num_shards=1)
     with pytest.raises(ValueError, match="arena_layout"):
         dst.restore(tampered)
+
+
+def test_restore_rejects_version_1_snapshot(tiny_model):
+    """A version-1 snapshot was taken under another page-holder rule
+    (host page lists beside the page table, and a host decode loop):
+    it is refused loudly, naming the version, before any engine state
+    is mutated."""
+    cfg = tiny_model[0]
+    src = _engine(tiny_model)
+    _submit(src, cfg)
+    src.step()
+    snap = src.snapshot()
+    old = {"tree": snap["tree"],
+           "meta": json.loads(json.dumps(snap["meta"]))}
+    fp = old["meta"]["fingerprint"]
+    fp.update(snapshot_version=1, mega_step=True)
+    old["meta"]["slot_pages"] = [[] for _ in range(3)]
+    dst = _engine(tiny_model)
+    ctl_before = np.asarray(dst.alloc_state.ctl).copy()
+    with pytest.raises(ValueError, match="snapshot_version"):
+        dst.restore(old)
+    np.testing.assert_array_equal(np.asarray(dst.alloc_state.ctl),
+                                  ctl_before)
+    assert dst.waiting == [] and dst.stats["steps"] == 0
 
 
 def test_restore_rejects_non_snapshot_checkpoint(tiny_model, tmp_path):

@@ -198,8 +198,9 @@ def test_engine_roundtrip_pallas_alloc_backend(rng):
 def test_engine_sharded_allocator(rng):
     """num_shards>1: the engine's KV allocator becomes the sharded
     multi-arena (core/shards.py) — each sequence slot homes on
-    slot % num_shards — and stats expose per-shard live-page
-    occupancy that returns to zero when every request retires."""
+    slot % num_shards — and the per-shard live-page occupancy the
+    engine derives (``shard_pages_live``) is right mid-flight and
+    returns to zero when every request retires."""
     from repro.serve.engine import ServingEngine
     cfg = get_arch("qwen2-0.5b").smoke()
     m = build_model(cfg)
@@ -207,13 +208,13 @@ def test_engine_sharded_allocator(rng):
     eng = ServingEngine(m, params, max_batch=3, max_seq=96,
                         kv_dtype=jnp.float32, num_shards=2)
     assert eng.stats["num_shards"] == 2
-    assert len(eng.stats["shard_pages_live"]) == 2
+    assert len(eng.shard_pages_live()) == 2
     for _ in range(4):
         eng.submit(rng.integers(2, cfg.vocab_size,
                                 int(rng.integers(4, 24))),
                    max_new_tokens=4)
     eng.step()  # admit: slots 0..2 prefill → shards 0 and 1 populated
-    live = eng.stats["shard_pages_live"]
+    live = eng.shard_pages_live()
     assert sum(live) == eng.stats["allocs"] - eng.stats["frees"]
     assert all(x > 0 for x in live), \
         "slot % num_shards routing left a shard empty mid-flight"
@@ -221,7 +222,7 @@ def test_engine_sharded_allocator(rng):
     assert len(done) == 4
     assert eng.stats["alloc_failures"] == 0
     assert eng.stats["frees"] == eng.stats["allocs"] > 0
-    assert eng.stats["shard_pages_live"] == [0, 0], \
+    assert eng.shard_pages_live() == [0, 0], \
         "per-shard occupancy must drain with the requests"
 
 
